@@ -14,17 +14,15 @@ import jax as _jax
 
 # Persistent XLA compilation cache: the jitted tree-builder programs are
 # expensive to compile (many bucket-size specializations); cache them across
-# processes.  Opt out with LIGHTGBM_TPU_DISABLE_COMPILE_CACHE=1.
-if _os.environ.get("LIGHTGBM_TPU_DISABLE_COMPILE_CACHE", "0") != "1":
-    _cache_dir = _os.environ.get(
-        "LIGHTGBM_TPU_COMPILE_CACHE",
-        _os.path.join(_os.path.dirname(_os.path.abspath(__file__)),
-                      "..", ".jax_cache"))
-    try:
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # older jax without these flags
-        pass
+# processes.  JAX_COMPILATION_CACHE_DIR (read by jax itself) places the
+# cache; without it the cache lives at the fixed <checkout>/.jax_cache — the
+# path is part of the cache key, so it must not move between runs.
+if "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 from .basic import (Booster, Dataset, LightGBMError, Sequence,
                     TextFileSequence)

@@ -370,7 +370,7 @@ def check_shap_kernel() -> Dict[str, int]:
     grp = pack["per_k"][0]["groups"][0]
     binned = eng._bin(X[:128], pack["has_cat"])
     ncols = pack["num_cols"]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         mask = jnp.asarray((grp["iters"] >= 0).astype("float32"))
         fn = jax.jit(functools.partial(tree_shap_stacked,
                                        num_columns=ncols))

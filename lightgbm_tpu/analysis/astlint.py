@@ -19,7 +19,7 @@ JL002  retrace hazard — ``jax.jit``/``Partial`` constructed inside a
        for a known jitted symbol's static args.
 JL003  dtype-promotion leak — explicit float64 dtypes in ``jnp`` calls
        or ``.astype`` on device values outside a lexical
-       ``jax.experimental.enable_x64()`` block.  Off-TPU this silently
+       ``jax.enable_x64(True)`` block.  Off-TPU this silently
        doubles bandwidth; on TPU it breaks lowering.
 JL004  while-carry growth — ``lax.fori_loop``/``while_loop``/``scan``
        whose carry is built by a comprehension/``[x] * n``/starred

@@ -411,7 +411,6 @@ _PARAMS: List[_Param] = [
     _p("num_gpu", 1, int, (), ">0"),
     # --- TPU-specific (new in this framework) ---
     _p("tpu_hist_dtype", "float32", str),       # float32 | bfloat16_pair
-    _p("tpu_hist_kernel", "xla", str),          # xla | pallas
     # per-leaf histogram state: "auto" = lane-flattened state updated in
     # place by the Pallas RMW kernel (ops/hist_state_pallas.py) when the
     # fast serial path is active; "xla" = (L+1, G, B, 2) dynamic-slice
@@ -425,10 +424,11 @@ _PARAMS: List[_Param] = [
     # split mega-kernel: partition + BOTH children's histograms in one
     # Pallas program per split (ops/split_megakernel_pallas.py) — no
     # parent-histogram read, no subtraction trick, no (L+1)-slot
-    # histogram state in the while-loop carry.  "auto" probes the kernel
-    # on TPU and falls back to the current split path; "pallas" forces
-    # the attempt; "xla" runs the same math as plain XLA ops (the
-    # correctness oracle, any backend); "off" disables
+    # histogram state in the while-loop carry.  "auto" selects the kernel
+    # on TPU for eligible shapes (all-numerical u8 serial fast path) and
+    # the current split path otherwise; "pallas" asks for it explicitly;
+    # "xla" runs the same math as plain XLA ops (the correctness oracle,
+    # any backend); "off" disables
     _p("tpu_megakernel", "auto", str),
     # frontier-batched tree growth: grow the top-K gain leaves of the
     # current frontier per while-loop step instead of 1, amortizing the

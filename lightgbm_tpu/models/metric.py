@@ -293,7 +293,7 @@ class AUCMetric(Metric):
             # cumsums silently truncate to f32 (collapsing distinct
             # scores into ties) and the exactness claim is void
             meta = self.metadata
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 s = network.global_concat(
                     np.asarray(score, dtype=np.float64))
                 y = network.global_concat(np.asarray(meta.label,

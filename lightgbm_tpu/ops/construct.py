@@ -816,14 +816,10 @@ def conflict_matrix(masks: np.ndarray, use_device: bool = False
     Diagonal = per-feature non-default counts."""
     m = np.ascontiguousarray(masks, dtype=np.float32)
     if use_device:
-        try:
-            import jax
-            import jax.numpy as jnp
-            c = jax.device_get(jnp.matmul(jnp.asarray(m), jnp.asarray(m).T))
-            return np.asarray(np.rint(c), dtype=np.int64)
-        except Exception as exc:   # pragma: no cover - device-optional
-            log.warning("device conflict matmul unavailable (%s); "
-                        "using host matmul", str(exc)[:120])
+        import jax
+        import jax.numpy as jnp
+        c = jax.device_get(jnp.matmul(jnp.asarray(m), jnp.asarray(m).T))
+        return np.asarray(np.rint(c), dtype=np.int64)
     c = m @ m.T
     # f32 dot of 0/1 vectors is exact below 2^24 samples (n <= 50000)
     return np.asarray(np.rint(c), dtype=np.int64)

@@ -88,9 +88,9 @@ def hist_rmw_pallas(hist_state, hist_small, idx, *, interpret: bool = False):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)],
         scratch_shapes=[

@@ -18,12 +18,10 @@ loop, ~60% of its wall clock.  (G, N) row-major is the same physical bytes
 as (N, G) column-major, so every consumer now agrees with the layout XLA
 wants and the copies vanish.
 
-Two implementations with identical semantics:
-  * ``leaf_hist_slice``  — pure-XLA chunked einsum (runs everywhere; the
-    oracle for tests and the CPU path).
-  * ``leaf_hist_pallas`` — Pallas TPU kernel that DMAs (G, chunk) tiles
-    straight from HBM with a dynamic trip count and accumulates per-feature
-    (2, B) partial histograms in VMEM.
+``leaf_hist_slice`` is a pure-XLA chunked einsum that runs everywhere (on
+the TPU default path the split mega-kernel accumulates both children's
+histograms itself; a stand-alone Pallas histogram kernel was deleted in PR 21
+— the installed Mosaic refuses its u8 mid-axis DMA slice).
 
 The contraction layout batches ``gblock`` feature groups into the matmul N
 dimension — out[(j),(g,b)] = sum_c gh[j,c] * (bins[g,c]==b) — because the
@@ -34,11 +32,10 @@ N=2 wastes 126/128 lanes.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from . import F32_DOT_PRECISION
 
 
 def linear_moment_planes(feat_hist, rep_vals):
@@ -141,6 +138,7 @@ def leaf_hist_slice(part_bins, part_ghi, start, cnt, *,
             out.append(jax.lax.dot_general(
                 wg, oh_lo,
                 dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+                precision=F32_DOT_PRECISION,
                 preferred_element_type=jnp.float32))  # (gblk, 2*BH, 16)
         # ONE loop-carried array (a tuple of nblk carries costs nblk
         # body-level fusions per split in the outer tree loop)
@@ -194,111 +192,3 @@ def leaf_hist_banded(part_bins, part_ghi, start, cnt, *, num_bins: int,
             cover=trip)
     return out
 
-
-# ----------------------------------------------------------------------
-# Pallas TPU kernel
-# ----------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("num_bins", "row_chunk",
-                                             "use_bf16", "num_groups"))
-def leaf_hist_pallas(part_bins, grad_p, hess_p, start, cnt, *,
-                     num_bins: int, row_chunk: int, use_bf16: bool = False,
-                     num_groups: int = 0):
-    """Same contract as ``leaf_hist_slice`` (transposed (G, N_pad) binned
-    input), as one Pallas kernel.
-
-    A single program (grid=(1,)) walks the leaf's chunks with a dynamic trip
-    count, double-buffered DMA from HBM, and per-feature one-hot matmuls
-    (the bin axis is padded to a lane multiple so the MXU N dimension stays
-    wide) accumulated into a VMEM scratch histogram — the TPU analog of the
-    CUDA shared-memory per-block histograms
-    (cuda_histogram_constructor.cu:18-460).
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    Gbuf, Np = part_bins.shape       # buffer rows (may be sublane-padded)
-    G = num_groups or Gbuf           # real feature groups in the output
-    C = row_chunk
-    B = num_bins
-    B128 = ((B + 127) // 128) * 128
-    dtype = jnp.bfloat16 if use_bf16 else jnp.float32
-
-    def kernel(start_ref, cnt_ref, bins_hbm, grad_hbm, hess_hbm, out_ref,
-               bins_buf, grad_buf, hess_buf, acc_ref, sems):
-        s0 = start_ref[0]
-        total = cnt_ref[0]
-        # chunk-ALIGNED windows covering [s0, s0+total): DMA starts must be
-        # tile-aligned, leaf starts are arbitrary -> mask the partial edges
-        c0 = jax.lax.div(s0, C)
-        n_chunks = pl.cdiv(s0 + total, C) - c0
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        def get_copies(ci, slot):
-            blk = c0 + ci
-            return (
-                pltpu.make_async_copy(
-                    bins_hbm.at[:, blk], bins_buf.at[slot], sems.at[slot, 0]),
-                pltpu.make_async_copy(
-                    grad_hbm.at[blk], grad_buf.at[slot], sems.at[slot, 1]),
-                pltpu.make_async_copy(
-                    hess_hbm.at[blk], hess_buf.at[slot], sems.at[slot, 2]),
-            )
-
-        for c in get_copies(0, 0):
-            c.start()
-
-        def body(ci, _):
-            slot = jax.lax.rem(ci, 2)
-
-            @pl.when(ci + 1 < n_chunks)
-            def _():
-                for c in get_copies(ci + 1, 1 - slot):
-                    c.start()
-
-            for c in get_copies(ci, slot):
-                c.wait()
-
-            gpos = ((c0 + ci) * C +
-                    jax.lax.broadcasted_iota(jnp.int32, (1, C), 1))
-            valid = (gpos >= s0) & (gpos < s0 + total)
-            g = jnp.where(valid, grad_buf[slot][None, :], 0.0)
-            h = jnp.where(valid, hess_buf[slot][None, :], 0.0)
-            gh = jnp.concatenate([g, h], axis=0).astype(dtype)    # (2, C)
-            bins = bins_buf[slot].astype(jnp.int32)               # (G, C)
-            iota_b = jax.lax.broadcasted_iota(jnp.int32, (C, B128), 1)
-            for f in range(G):
-                oh = (bins[f][:, None] == iota_b).astype(dtype)   # (C, B128)
-                part = jax.lax.dot_general(
-                    gh, oh, dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)            # (2, B128)
-                acc_ref[:, f, :] = acc_ref[:, f, :] + part
-            return 0
-
-        jax.lax.fori_loop(0, n_chunks, body, 0)
-        out_ref[:] = acc_ref[:]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * 3,
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, Gbuf, C), part_bins.dtype),
-            pltpu.VMEM((2, C), jnp.float32),
-            pltpu.VMEM((2, C), jnp.float32),
-            pltpu.VMEM((2, G, B128), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 3)),
-        ],
-    )
-    if Np % C:
-        raise ValueError(f"N_pad={Np} must be a multiple of row_chunk={C}")
-    nblocks = Np // C
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((2, G, B128), jnp.float32),
-        grid_spec=grid_spec,
-    )(jnp.asarray([start], jnp.int32), jnp.asarray([cnt], jnp.int32),
-      part_bins.reshape(Gbuf, nblocks, C), grad_p.reshape(nblocks, C),
-      hess_p.reshape(nblocks, C))
-    return jnp.moveaxis(out[:, :, :B], 0, 2)

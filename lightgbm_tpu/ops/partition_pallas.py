@@ -587,8 +587,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3 +
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3 +
                   [pl.BlockSpec(memory_space=pltpu.VMEM)],
         scratch_shapes=[
             pltpu.VMEM((2, G32, C), jnp.uint8),      # rb

@@ -29,6 +29,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import F32_DOT_PRECISION
+
 K_EPSILON = 1e-15
 K_MIN_SCORE = -jnp.inf
 
@@ -357,14 +359,13 @@ def find_best_split_fast(feat_hist: jnp.ndarray, ctx: SplitContext,
         # prefix sums as ONE inclusive lower-triangular matmul on the
         # MXU: XLA's cumsum lowering costs a log-depth pass cascade per
         # operand, and the per-split cost on TPU is op-DISPATCH-bound.
-        # f32 dot keeps integer counts exact below 2^24; g/h sums round
-        # differently from a serial scan by at most the usual f32
-        # dot-product reassociation.
+        # F32_DOT_PRECISION keeps the integer counts exact below 2^24.
         tri = (jax.lax.broadcasted_iota(jnp.int32, (BF, BF), 0) <=
                jax.lax.broadcasted_iota(jnp.int32, (BF, BF), 1)
                ).astype(jnp.float32)
         cs = jax.lax.dot_general(
             stacked, tri, (((2,), (0,)), ((), ())),
+            precision=F32_DOT_PRECISION,
             preferred_element_type=jnp.float32)               # (6, F, BF)
     else:
         # off-TPU the triangular matmul is O(F*BF^2) of REAL work — it
@@ -587,6 +588,7 @@ def find_best_split_linear(feat_hist: jnp.ndarray, ctx: SplitContext,
                ).astype(jnp.float32)
         cs = jax.lax.dot_general(
             stacked, tri, (((2,), (0,)), ((), ())),
+            precision=F32_DOT_PRECISION,
             preferred_element_type=jnp.float32)               # (9, F, BF)
     else:
         cs = jnp.cumsum(stacked, axis=2)                      # (9, F, BF)

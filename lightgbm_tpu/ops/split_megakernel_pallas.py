@@ -55,6 +55,7 @@ from .partition_pallas import (S_A0B, S_REM, S_CNT, S_COL, S_BSTART, S_ISB,
                                S_NB, S_DBIN, S_MTYPE, S_THR, S_DL,
                                _decide_left, _excl_prefix_rights, _cdiv,
                                payload_codecs, pltpu_roll)
+from . import F32_DOT_PRECISION
 from . import partition_pallas as _pp
 
 
@@ -92,6 +93,7 @@ def _chunk_hist_group(bins_row, wl_g, wl_h, wr_g, wr_h, BH, iota_hi,
         axis=0)                                            # (4BH, C)
     return jax.lax.dot_general(
         w4, oh_lo, (((1,), (1,)), ((), ())),
+        precision=F32_DOT_PRECISION,
         preferred_element_type=jnp.float32)                # (4BH, 16)
 
 
@@ -518,8 +520,8 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3 +
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3 +
                   [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2,
         scratch_shapes=[
             pltpu.VMEM((2, G32, C), jnp.uint8),      # rb

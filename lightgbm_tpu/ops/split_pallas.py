@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import F32_DOT_PRECISION
+
 K_EPSILON = 1e-15
 
 # fmeta columns (per stacked child-feature row)
@@ -135,6 +137,7 @@ def best_split_pair_pallas(hist_g, hist_h, fmeta, info,
                ).astype(jnp.float32)
         cs = jax.lax.dot_general(
             stacked, tri, (((1,), (0,)), ((), ())),
+            precision=F32_DOT_PRECISION,
             preferred_element_type=jnp.float32)            # (12F, BF)
 
         lg_f = cs[0:F2]

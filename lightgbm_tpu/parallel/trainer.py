@@ -234,8 +234,7 @@ class ShardedTreeBuilder:
                 return rec, aux_out
             return rec
 
-        from ..utils.compat import shard_map as _compat_shard_map
-        self._build_sharded = jax.jit(_compat_shard_map(
+        self._build_sharded = jax.jit(jax.shard_map(
             wrapper, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs))
 

@@ -170,10 +170,9 @@ class _FakeDistClient(_FakeClient):
             "import os, pickle, sys\n"
             "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "os.environ.pop('XLA_FLAGS', None)\n"
-            "import tempfile\n"
             "os.environ['JAX_COMPILATION_CACHE_DIR'] = "
-            "tempfile.mkdtemp(prefix='jax-dask-')\n"
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
+            f"{_os.path.join(_REPO_ROOT, '.jax_cache', 'tests-multiprocess')!r}\n"
+            "import jax\n"
             f"args = pickle.load(open({str(argfile)!r}, 'rb'))\n"
             # initialize BEFORE the package import can touch the backend
             "jax.distributed.initialize(coordinator_address=args[9],\n"

@@ -68,7 +68,7 @@ def test_exact_auc_equals_single_device(rng, monkeypatch, weighted):
     score, label, weight = _make(rng, weighted=weighted)
     # the f64 single-device reference — the metric's exact path also
     # evaluates under x64 (f32 cumsums would void the 1e-12 claim)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         exact_single = float(_weighted_auc(
             jnp.asarray(score), jnp.asarray(label),
             jnp.asarray(weight) if weight is not None else None))

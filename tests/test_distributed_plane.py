@@ -20,13 +20,10 @@ import pytest
 # tests then fail for a platform reason, not a product one.  Probing
 # turns that into an explicit skip with the backend's own error text.
 PROBE = r"""
-import os, sys, tempfile
+import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)
-os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
-    prefix="jax-cache-probe-")
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(f"localhost:{sys.argv[2]}", num_processes=2,
                            process_id=int(sys.argv[1]))
 import jax.numpy as jnp
@@ -36,6 +33,18 @@ out = multihost_utils.process_allgather(
 assert out.reshape(-1).shape[0] == 4, out
 print("PROBE_OK", flush=True)
 """
+
+
+def _worker_env():
+    """Environment of a spawned rank: the repo on the path and a fixed
+    compilation cache of its own — multi-process executables stay apart
+    from the single-process ones the pytest process caches."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = root
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+        root, ".jax_cache", "tests-multiprocess")
+    return env
 
 
 @functools.lru_cache(maxsize=1)
@@ -49,9 +58,7 @@ def _multiprocess_collectives_supported():
         with open(probe, "w") as fh:
             fh.write(PROBE)
         port = str(13300 + os.getpid() % 400)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
+        env = _worker_env()
         procs = [subprocess.Popen(
             [sys.executable, probe, str(i), port], env=env,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
@@ -78,15 +85,10 @@ def _require_multiprocess_collectives():
 
 
 WORKER = r"""
-import json, os, sys, tempfile
+import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)
-# the parent's compilation cache holds single-process executables whose
-# reuse corrupts multi-process collectives (see conftest note)
-os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
-    prefix="jax-cache-dist-")
 import jax
-jax.config.update("jax_platforms", "cpu")
 pid = int(sys.argv[1])
 port = sys.argv[2]
 data_path = sys.argv[3]
@@ -135,9 +137,7 @@ def test_two_process_binmapper_sync(tmp_path, rng):
     worker.write_text(WORKER)
     outs = [tmp_path / "out0.json", tmp_path / "out1.json"]
     port = str(12500 + os.getpid() % 400)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))
+    env = _worker_env()
     procs = [subprocess.Popen(
         [sys.executable, str(worker), str(i), port, str(data_path),
          str(outs[i])], env=env, stdout=subprocess.PIPE,
@@ -160,13 +160,10 @@ def test_two_process_binmapper_sync(tmp_path, rng):
 
 
 TRAIN_WORKER = r"""
-import json, os, sys, tempfile
+import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)
-os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
-    prefix="jax-cache-dist-")
 import jax
-jax.config.update("jax_platforms", "cpu")
 pid = int(sys.argv[1])
 port = sys.argv[2]
 data_path = sys.argv[3]
@@ -233,9 +230,7 @@ def test_two_process_training_matches_single(tmp_path, rng):
     worker.write_text(TRAIN_WORKER)
     outs = [tmp_path / "t0.json", tmp_path / "t1.json"]
     port = str(12900 + os.getpid() % 400)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))
+    env = _worker_env()
     procs = [subprocess.Popen(
         [sys.executable, str(worker), str(i), port, str(data_path),
          str(outs[i])], env=env, stdout=subprocess.PIPE,

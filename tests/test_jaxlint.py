@@ -154,7 +154,7 @@ def test_jl003_f64_outside_x64_scope():
     assert _rules(src) == ["JL003"]
     scoped = ("import jax, numpy as np, jax.numpy as jnp\n"
               "def f(a):\n"
-              "    with jax.experimental.enable_x64():\n"
+              "    with jax.enable_x64(True):\n"
               "        return jnp.asarray(a, dtype=np.float64)\n")
     assert _rules(scoped) == []
 
