@@ -283,7 +283,7 @@ def check_train_donation() -> Dict[str, int]:
                    "metric": ""},
                   lgb.Dataset(X, label=y), num_boost_round=2)
     fused = [(q, d) for q, d in records
-             if "_setup_fused" in q and q.endswith(".step")]
+             if "_setup_fused" in q and q.endswith(".lgbm_fused_step")]
     undonated = sum(1 for _, d in fused if not d)
     return {"fused_steps_jitted": len(fused),
             "fused_steps_without_donation": undonated,

@@ -20,6 +20,7 @@ import numpy as np
 from ..config import Config
 from ..dataset import BinnedDataset
 from ..obs import memory as obs_memory
+from ..obs import scopes
 from ..obs import telemetry as obs
 from ..ops.predict import predict_leaf_binned, predict_leaf_binned_t
 from ..robustness import faultinject
@@ -101,7 +102,8 @@ def _quant_renew_device(idx, grad, hess, starts, cnts, old_values,
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
-def _scores_from_phys(ghi, num_data):
+@scopes.phase("scores_read")
+def lgbm_scores_read(ghi, num_data):
     """Scatter the physically-ordered score row back to original row
     order (rowid rides as bitcast row 2; pad rows carry the sentinel
     ``num_data`` and drop)."""
@@ -134,7 +136,8 @@ def _scores_from_phys_multiproc(ghi, local_num_data, sb):
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def _scores_from_phys_mc(ghi, num_data, num_class):
+@scopes.phase("scores_read")
+def lgbm_scores_read_mc(ghi, num_data, num_class):
     """Multiclass variant: rows 3..3+K-1 are the per-class score rows."""
     rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
     return jnp.zeros((num_data, num_class), jnp.float32).at[rowid].set(
@@ -351,6 +354,9 @@ class GBDT:
         self._init_phys_adopt = None
         self._init_phys_perm = None
         self._scores_arr = None
+        # programs whose executable obs/scopes.py already holds for this
+        # booster (each is registered at its first call)
+        self._scope_registered = set()
         # model & data health (obs/health.py): the training flight
         # recorder (None when health=off) and the reference data profile
         # persisted with the model; all host-side bookkeeping
@@ -371,21 +377,40 @@ class GBDT:
         if getattr(self, "_phys", None) is not None:
             pb, ghi = self._phys
             self._phys = None
+            obs.counter("train.layout_drops")
             # the bins + rowid row stay resident as the retired carrier:
             # they are the ONLY binned copy (single-copy residency) and
             # the next fused init / traversal / recovery reads them
             self._phys_carrier = (pb, ghi[2])
             K = self.num_tree_per_iteration
             sb = self.sharded_builder
-            if K > 1:
-                self._scores_arr = _scores_from_phys_mc(
-                    ghi, self.num_data, K)
-            elif sb is not None and sb.nproc > 1:
-                self._scores_arr = _scores_from_phys_multiproc(
-                    ghi, self.num_data, sb)
-            else:
-                self._scores_arr = _scores_from_phys(ghi, self.num_data)
+            with obs.span("train.scores_read"):
+                if K > 1:
+                    self._scores_arr = lgbm_scores_read_mc(
+                        ghi, self.num_data, K)
+                    self._register_once("train.scores_read",
+                                        lgbm_scores_read_mc,
+                                        ghi, self.num_data, K)
+                elif sb is not None and sb.nproc > 1:
+                    # folded on the host: no program to register
+                    self._scores_arr = _scores_from_phys_multiproc(
+                        ghi, self.num_data, sb)
+                else:
+                    self._scores_arr = lgbm_scores_read(ghi, self.num_data)
+                    self._register_once("train.scores_read",
+                                        lgbm_scores_read,
+                                        ghi, self.num_data)
         return self._scores_arr
+
+    def _register_once(self, program, jitted, *args):
+        """After the first call of ``jitted`` by this booster, hand
+        obs/scopes.py the executable that call made (scopes.register_call:
+        nothing is traced or compiled a second time).  ``args`` are live
+        arrays of the call's shapes: its outputs where it donated inputs."""
+        key = (program, id(jitted))
+        if key not in self._scope_registered:
+            self._scope_registered.add(key)
+            scopes.register_call(program, jitted, *args)
 
     @scores.setter
     def scores(self, v):
@@ -394,6 +419,7 @@ class GBDT:
             # drop the bins: they may be the only binned copy left
             pb, ghi = self._phys
             self._phys = None
+            obs.counter("train.layout_drops")
             self._phys_carrier = (pb, ghi[2])
         self._scores_arr = v
 
@@ -600,6 +626,7 @@ class GBDT:
         _C = self.learner.row0
         _N = self.num_data
 
+        @scopes.phase("scores_read")
         def _tr_phys(nodes, pb, rowid_bits):
             rowid = jax.lax.bitcast_convert_type(rowid_bits, jnp.int32)
             leaf = predict_leaf_binned_t(pb[:_G], nodes)
@@ -607,13 +634,14 @@ class GBDT:
                 leaf, mode="drop")
 
         self._traverse_phys_fn = jax.jit(_tr_phys)
-        self._traverse_part0_fn = jax.jit(
+        self._traverse_part0_fn = jax.jit(scopes.phase("scores_read")(
             lambda nodes, p0: predict_leaf_binned_t(
-                p0[:_G, _C:_C + _N], nodes))
-        self._traverse_rows_fn = jax.jit(
-            lambda nodes, binned: predict_leaf_binned(binned, nodes))
-        self._unpermute_fn = jax.jit(functools.partial(
-            _unpermute_bins, N=_N, C=_C, Npad=self.learner.N_pad))
+                p0[:_G, _C:_C + _N], nodes)))
+        self._traverse_rows_fn = jax.jit(scopes.phase("scores_read")(
+            lambda nodes, binned: predict_leaf_binned(binned, nodes)))
+        self._unpermute_fn = jax.jit(scopes.phase("scores_read")(
+            functools.partial(_unpermute_bins, N=_N, C=_C,
+                              Npad=self.learner.N_pad)))
 
         # ---- fused training step ----
         # One jitted program per boosting iteration: gradients -> tree build
@@ -742,11 +770,12 @@ class GBDT:
             # renewal and sampling masks live inside that program)
             return
 
-        def step(part_bins, scores, feature_mask, seed, feat_used):
+        def lgbm_fused_step(part_bins, scores, feature_mask, seed, feat_used):
             # trace-time-only host hook: one call == one XLA compile of
             # this program (obs retrace detector; zero HLO)
             obs.compile_event("train.fused_step")
-            grad, hess = obj.get_gradients(scores)
+            with scopes.scope("gradients"):
+                grad, hess = obj.get_gradients(scores)
             rec = lr_._build_impl(part_bins, grad, hess, jnp.int32(N),
                                   feature_mask, seed, feat_used)
             # per-row score delta from the physical leaf ranges: leaves are
@@ -754,21 +783,23 @@ class GBDT:
             # the range boundaries and prefix-sum — the +v/-v pairs of each
             # closed range cancel exactly before the next range opens — then
             # ONE scatter maps physical rows back to original row order
-            d = jnp.zeros((Npad + 1,), jnp.float32)
-            d = d.at[rec["leaf_start"]].add(rec["leaf_value"], mode="drop")
-            d = d.at[rec["leaf_start"] + rec["leaf_cnt"]].add(
-                -rec["leaf_value"], mode="drop")
-            delta_phys = jnp.cumsum(d)[:-1]
-            delta = jnp.zeros((N,), jnp.float32).at[rec["indices"]].set(
-                delta_phys, mode="drop")
-            new_scores = scores + delta * shrink
+            with scopes.scope("score_update"):
+                d = jnp.zeros((Npad + 1,), jnp.float32)
+                d = d.at[rec["leaf_start"]].add(rec["leaf_value"],
+                                                mode="drop")
+                d = d.at[rec["leaf_start"] + rec["leaf_cnt"]].add(
+                    -rec["leaf_value"], mode="drop")
+                delta_phys = jnp.cumsum(d)[:-1]
+                delta = jnp.zeros((N,), jnp.float32).at[rec["indices"]].set(
+                    delta_phys, mode="drop")
+                new_scores = scores + delta * shrink
             small = {k: v for k, v in rec.items()
                      if k.startswith(("node_", "leaf_")) or k in
                      ("s", "feat_used")}
             small["leaf_delta"] = rec["leaf_value"] * shrink
             return new_scores, small
 
-        self._fused = jax.jit(step, donate_argnums=(1,))
+        self._fused = jax.jit(lgbm_fused_step, donate_argnums=(1,))
 
     def _setup_fused_phys(self, names) -> None:
         """Physical-order fused iteration (see _setup_fused_step).
@@ -807,7 +838,8 @@ class GBDT:
                      for _ in range(lr_._ghi_rows - len(rows))]
             return jnp.stack(rows)
 
-        def init_phys(part_bins, scores):
+        @scopes.phase("layout_init")
+        def lgbm_layout_init(part_bins, scores):
             # the bins pass through UNTOUCHED; with the bins argument
             # DONATED, XLA aliases the output onto the input buffer, so
             # the physical carrier ADOPTS the learner's master buffer
@@ -817,7 +849,8 @@ class GBDT:
             # semantics for lowering-only probes (jaxlint).
             return part_bins, ghi0(scores)
 
-        def init_phys_perm(part_bins, rowid_bits, scores):
+        @scopes.phase("layout_init")
+        def lgbm_layout_resume(part_bins, rowid_bits, scores):
             # resume from a RETIRED carrier (scores were read between
             # iterations): unpermute the bins back to the identity
             # layout, so the rebuilt state — and every tree after it —
@@ -825,9 +858,9 @@ class GBDT:
             bins = _unpermute_bins(part_bins, rowid_bits, N, C, Npad)
             return bins, ghi0(scores)
 
-        self._init_phys = jax.jit(init_phys)
-        self._init_phys_adopt = jax.jit(init_phys, donate_argnums=(0,))
-        self._init_phys_perm = jax.jit(init_phys_perm, donate_argnums=(0,))
+        self._init_phys = jax.jit(lgbm_layout_init)
+        self._init_phys_adopt = jax.jit(lgbm_layout_init, donate_argnums=(0,))
+        self._init_phys_perm = jax.jit(lgbm_layout_resume, donate_argnums=(0,))
 
         use_quant = self.use_quant
         cfg = self.config
@@ -863,157 +896,163 @@ class GBDT:
                       if hasattr(type(obj), "renew_weights_from_payload")
                       else None)
 
-        def step(part_bins, ghi, feature_mask, seed, feat_used):
+        def lgbm_fused_step(part_bins, ghi, feature_mask, seed, feat_used):
             obs.compile_event("train.fused_step")   # trace-time only
-            rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
-            vf = (rowid != N).astype(jnp.float32)   # pad rows: grad/hess 0
-            payload = {n: ghi[4 + i] for i, n in enumerate(names)}
-            g, h = obj.gradients_from_payload(ghi[3], **payload)
-            g = g * vf
-            h = h * vf
+            with scopes.scope("gradients"):
+                rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
+                vf = (rowid != N).astype(jnp.float32)   # pad rows: grad/hess 0
+                payload = {n: ghi[4 + i] for i, n in enumerate(names)}
+                g, h = obj.gradients_from_payload(ghi[3], **payload)
+                g = g * vf
+                h = h * vf
             bag_cnt = jnp.int32(N)
-            if use_goss:
-                # in-program GOSS (goss.hpp Helper:116-165): pad rows
-                # carry zero importance and never select
-                imp = jnp.abs(g * h)
-                threshold = jax.lax.top_k(imp, g_top_k)[0][-1]
-                is_top = (imp >= threshold) & (vf > 0)
-                kg = jax.random.fold_in(bag_key, seed)
-                n_top = jnp.sum(is_top.astype(jnp.int32))
-                rest = jnp.maximum(N - n_top, 1)
-                prob = g_other_k / rest.astype(jnp.float32)
-                keep_other = ((~is_top) & (vf > 0) &
-                              (jax.random.uniform(kg, g.shape) < prob))
-                multiply = (N - g_top_k) / g_other_k
-                scale = jnp.where(is_top, 1.0,
-                                  jnp.where(keep_other, multiply, 0.0))
-                g = g * scale
-                h = h * scale
-                bag_cnt = jnp.sum((is_top | keep_other).astype(jnp.int32))
-            elif use_bag:
-                # bag redrawn per bagging_freq period: the key depends on
-                # the PERIOD index, so iterations inside one period see
-                # the identical mask (bagging.hpp semantics).  Draws are
-                # indexed by ORIGINAL row id — the physical permutation
-                # changes every iteration, so a draw over physical
-                # positions would silently re-bag mid-period
-                kb = jax.random.fold_in(bag_key, (seed - 1) // bag_freq)
-                u = jax.random.uniform(kb, (N + 1,))
-                sel = (jnp.take(u, jnp.minimum(rowid, N)) < bag_frac) \
-                    & (vf > 0)
-                sf = sel.astype(jnp.float32)
-                g = g * sf
-                h = h * sf
-                bag_cnt = jnp.sum(sel.astype(jnp.int32))
-            elif use_balanced:
-                # per-class Bernoulli (reference: bagging.hpp
-                # BalancedBaggingHelper:180-200); label signs ride the
-                # payload, draws are indexed by original row id
-                kb = jax.random.fold_in(bag_key, (seed - 1) // bag_freq)
-                u = jnp.take(jax.random.uniform(kb, (N + 1,)),
-                             jnp.minimum(rowid, N))
-                posr = ghi[4 + sign_idx] > 0
-                sel = jnp.where(posr, u < pos_frac, u < neg_frac) \
-                    & (vf > 0)
-                sf = sel.astype(jnp.float32)
-                g = g * sf
-                h = h * sf
-                # the ACTUAL drawn count, not the sizing estimate
-                # (bagging.hpp:46 bag_data_cnt_ = left_cnt)
-                bag_cnt = jnp.sum(sel.astype(jnp.int32))
+            with scopes.scope("sampling"):
+                if use_goss:
+                    # in-program GOSS (goss.hpp Helper:116-165): pad rows
+                    # carry zero importance and never select
+                    imp = jnp.abs(g * h)
+                    threshold = jax.lax.top_k(imp, g_top_k)[0][-1]
+                    is_top = (imp >= threshold) & (vf > 0)
+                    kg = jax.random.fold_in(bag_key, seed)
+                    n_top = jnp.sum(is_top.astype(jnp.int32))
+                    rest = jnp.maximum(N - n_top, 1)
+                    prob = g_other_k / rest.astype(jnp.float32)
+                    keep_other = ((~is_top) & (vf > 0) &
+                                  (jax.random.uniform(kg, g.shape) < prob))
+                    multiply = (N - g_top_k) / g_other_k
+                    scale = jnp.where(is_top, 1.0,
+                                      jnp.where(keep_other, multiply, 0.0))
+                    g = g * scale
+                    h = h * scale
+                    bag_cnt = jnp.sum((is_top | keep_other).astype(jnp.int32))
+                elif use_bag:
+                    # bag redrawn per bagging_freq period: the key depends on
+                    # the PERIOD index, so iterations inside one period see
+                    # the identical mask (bagging.hpp semantics).  Draws are
+                    # indexed by ORIGINAL row id — the physical permutation
+                    # changes every iteration, so a draw over physical
+                    # positions would silently re-bag mid-period
+                    kb = jax.random.fold_in(bag_key, (seed - 1) // bag_freq)
+                    u = jax.random.uniform(kb, (N + 1,))
+                    sel = (jnp.take(u, jnp.minimum(rowid, N)) < bag_frac) \
+                        & (vf > 0)
+                    sf = sel.astype(jnp.float32)
+                    g = g * sf
+                    h = h * sf
+                    bag_cnt = jnp.sum(sel.astype(jnp.int32))
+                elif use_balanced:
+                    # per-class Bernoulli (reference: bagging.hpp
+                    # BalancedBaggingHelper:180-200); label signs ride the
+                    # payload, draws are indexed by original row id
+                    kb = jax.random.fold_in(bag_key, (seed - 1) // bag_freq)
+                    u = jnp.take(jax.random.uniform(kb, (N + 1,)),
+                                 jnp.minimum(rowid, N))
+                    posr = ghi[4 + sign_idx] > 0
+                    sel = jnp.where(posr, u < pos_frac, u < neg_frac) \
+                        & (vf > 0)
+                    sf = sel.astype(jnp.float32)
+                    g = g * sf
+                    h = h * sf
+                    # the ACTUAL drawn count, not the sizing estimate
+                    # (bagging.hpp:46 bag_data_cnt_ = left_cnt)
+                    bag_cnt = jnp.sum(sel.astype(jnp.int32))
             hist_scale = None
-            if use_quant:
-                # in-program discretizer (reference:
-                # GradientDiscretizer::DiscretizeGradients); integer
-                # carriers ride the payload, the scale goes to the
-                # histogram (bf16 int-exact accumulation)
-                gs = jnp.maximum(jnp.max(jnp.abs(g)) / (q_bins / 2.0),
-                                 1e-30)
-                max_h = jnp.max(jnp.abs(h))
-                hs = jnp.maximum(max_h if q_const_h else max_h / q_bins,
-                                 1e-30)
-                if q_stoch:
-                    kg, kh = jax.random.split(
-                        jax.random.fold_in(q_key, seed))
-                    rg = jax.random.uniform(kg, g.shape)
-                    rh = jax.random.uniform(kh, h.shape)
+            with scopes.scope("quantize"):
+                if use_quant:
+                    # in-program discretizer (reference:
+                    # GradientDiscretizer::DiscretizeGradients); integer
+                    # carriers ride the payload, the scale goes to the
+                    # histogram (bf16 int-exact accumulation)
+                    gs = jnp.maximum(jnp.max(jnp.abs(g)) / (q_bins / 2.0),
+                                     1e-30)
+                    max_h = jnp.max(jnp.abs(h))
+                    hs = jnp.maximum(max_h if q_const_h else max_h / q_bins,
+                                     1e-30)
+                    if q_stoch:
+                        kg, kh = jax.random.split(
+                            jax.random.fold_in(q_key, seed))
+                        rg = jax.random.uniform(kg, g.shape)
+                        rh = jax.random.uniform(kh, h.shape)
+                    else:
+                        rg = rh = 0.5
+                    ig = jnp.trunc(g / gs + jnp.where(g >= 0, rg, -rg))
+                    ih = (jnp.ones_like(h) if q_const_h
+                          else jnp.trunc(h / hs + rh))
+                    g_q = ig * vf
+                    h_q = ih * vf
+                    hist_scale = jnp.stack([gs, hs])
                 else:
-                    rg = rh = 0.5
-                ig = jnp.trunc(g / gs + jnp.where(g >= 0, rg, -rg))
-                ih = (jnp.ones_like(h) if q_const_h
-                      else jnp.trunc(h / hs + rh))
-                g_q = ig * vf
-                h_q = ih * vf
-                hist_scale = jnp.stack([gs, hs])
-            else:
-                g_q, h_q = g, h
-            ghi = ghi.at[0].set(g_q).at[1].set(h_q)
-            if use_quant and q_renew:
-                # true grads ride the partition so the renewal reads
-                # them in the record's row order
-                ghi = ghi.at[tg_row].set(g).at[th_row].set(h)
+                    g_q, h_q = g, h
+            with scopes.scope("gradients"):
+                ghi = ghi.at[0].set(g_q).at[1].set(h_q)
+                if use_quant and q_renew:
+                    # true grads ride the partition so the renewal reads
+                    # them in the record's row order
+                    ghi = ghi.at[tg_row].set(g).at[th_row].set(h)
             rec = lr_._build_tree_impl(part_bins, ghi, bag_cnt,
                                        feature_mask, seed, feat_used,
                                        None, hist_scale)
-            if use_quant and q_renew:
-                # leaf renewal from the TRUE gradients in POST-partition
-                # order: per-leaf sums are prefix differences at the
-                # range boundaries (reference: RenewIntGradTreeOutput)
-                from ..ops.split import leaf_output as _leaf_out
-                cg = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                                      jnp.cumsum(rec["part_ghi"][tg_row])])
-                ch = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                                      jnp.cumsum(rec["part_ghi"][th_row])])
-                ls = rec["leaf_start"]
-                lc = rec["leaf_cnt"]
-                sum_g = jnp.take(cg, ls + lc) - jnp.take(cg, ls)
-                sum_h = jnp.take(ch, ls + lc) - jnp.take(ch, ls)
-                renewed = _leaf_out(sum_g, sum_h + 2e-15, l1_, l2_, mds_)
-                rec["leaf_value"] = jnp.where(lc > 0, renewed,
-                                              rec["leaf_value"])
-            if renew_alpha is not None:
-                # L1-family leaf renewal: per-leaf residual percentile in
-                # POST-partition order (RegressionL1loss::RenewTreeOutput)
-                ghi_p = rec["part_ghi"]
-                rowid_p = jax.lax.bitcast_convert_type(ghi_p[2], jnp.int32)
-                valid_p = rowid_p != N
-                if use_bag:
-                    kb = jax.random.fold_in(bag_key,
-                                            (seed - 1) // bag_freq)
-                    u = jax.random.uniform(kb, (N + 1,))
-                    sel_p = (jnp.take(u, jnp.minimum(rowid_p, N))
-                             < bag_frac) & valid_p
-                elif use_balanced:
-                    kb = jax.random.fold_in(bag_key,
-                                            (seed - 1) // bag_freq)
-                    u = jnp.take(jax.random.uniform(kb, (N + 1,)),
-                                 jnp.minimum(rowid_p, N))
-                    posr = ghi_p[4 + sign_idx] > 0
-                    sel_p = jnp.where(posr, u < pos_frac,
-                                      u < neg_frac) & valid_p
-                else:
-                    sel_p = valid_p
-                resid = ghi_p[4 + label_idx] - ghi_p[3]
-                if renew_w_fn is not None:
-                    pw = renew_w_fn(
-                        ghi_p[4 + label_idx],
-                        ghi_p[4 + weight_idx] if weight_idx is not None
-                        else None)
-                elif weight_idx is not None:
-                    pw = ghi_p[4 + weight_idx]
-                else:
-                    pw = None
-                rec["leaf_value"] = _renew_leaves_percentile(
-                    rec, resid, pw, sel_p, renew_alpha, Npad)
-            ghi_out = rec["part_ghi"].at[3].add(
-                shrink * _phys_leaf_delta(rec, Npad))
+            with scopes.scope("leaf_renew"):
+                if use_quant and q_renew:
+                    # leaf renewal from the TRUE gradients in POST-partition
+                    # order: per-leaf sums are prefix differences at the
+                    # range boundaries (reference: RenewIntGradTreeOutput)
+                    from ..ops.split import leaf_output as _leaf_out
+                    cg = jnp.concatenate([jnp.zeros((1,), jnp.float32),
+                                          jnp.cumsum(rec["part_ghi"][tg_row])])
+                    ch = jnp.concatenate([jnp.zeros((1,), jnp.float32),
+                                          jnp.cumsum(rec["part_ghi"][th_row])])
+                    ls = rec["leaf_start"]
+                    lc = rec["leaf_cnt"]
+                    sum_g = jnp.take(cg, ls + lc) - jnp.take(cg, ls)
+                    sum_h = jnp.take(ch, ls + lc) - jnp.take(ch, ls)
+                    renewed = _leaf_out(sum_g, sum_h + 2e-15, l1_, l2_, mds_)
+                    rec["leaf_value"] = jnp.where(lc > 0, renewed,
+                                                  rec["leaf_value"])
+                if renew_alpha is not None:
+                    # L1-family leaf renewal: per-leaf residual percentile in
+                    # POST-partition order (RegressionL1loss::RenewTreeOutput)
+                    ghi_p = rec["part_ghi"]
+                    rowid_p = jax.lax.bitcast_convert_type(ghi_p[2], jnp.int32)
+                    valid_p = rowid_p != N
+                    if use_bag:
+                        kb = jax.random.fold_in(bag_key,
+                                                (seed - 1) // bag_freq)
+                        u = jax.random.uniform(kb, (N + 1,))
+                        sel_p = (jnp.take(u, jnp.minimum(rowid_p, N))
+                                 < bag_frac) & valid_p
+                    elif use_balanced:
+                        kb = jax.random.fold_in(bag_key,
+                                                (seed - 1) // bag_freq)
+                        u = jnp.take(jax.random.uniform(kb, (N + 1,)),
+                                     jnp.minimum(rowid_p, N))
+                        posr = ghi_p[4 + sign_idx] > 0
+                        sel_p = jnp.where(posr, u < pos_frac,
+                                          u < neg_frac) & valid_p
+                    else:
+                        sel_p = valid_p
+                    resid = ghi_p[4 + label_idx] - ghi_p[3]
+                    if renew_w_fn is not None:
+                        pw = renew_w_fn(
+                            ghi_p[4 + label_idx],
+                            ghi_p[4 + weight_idx] if weight_idx is not None
+                            else None)
+                    elif weight_idx is not None:
+                        pw = ghi_p[4 + weight_idx]
+                    else:
+                        pw = None
+                    rec["leaf_value"] = _renew_leaves_percentile(
+                        rec, resid, pw, sel_p, renew_alpha, Npad)
+            with scopes.scope("score_update"):
+                ghi_out = rec["part_ghi"].at[3].add(
+                    shrink * _phys_leaf_delta(rec, Npad))
             small = {k: v for k, v in rec.items()
                      if k.startswith(("node_", "leaf_")) or k in
                      ("s", "feat_used")}
             small["leaf_delta"] = rec["leaf_value"] * shrink
             return rec["part_bins"], ghi_out, small
 
-        self._fused_phys = jax.jit(step, donate_argnums=(0, 1))
+        self._fused_phys = jax.jit(lgbm_fused_step, donate_argnums=(0, 1))
         self._fused = self._fused_phys    # gate for train_one_iter
 
     def _mc_fused_kind(self):
@@ -1071,20 +1110,22 @@ class GBDT:
                     jnp.pad(weight_arr, (C, Npad - C - N)))
             return ghi
 
-        def init_phys(part_bins, scores):
+        @scopes.phase("layout_init")
+        def lgbm_layout_init(part_bins, scores):
             # bins pass through untouched; donated in the _adopt
             # variant so the carrier adopts the master buffer (see
             # _setup_fused_phys / single-copy residency);
             # _adopt_master_buffer retires the other refs
             return part_bins, ghi0(scores)
 
-        def init_phys_perm(part_bins, rowid_bits, scores):
+        @scopes.phase("layout_init")
+        def lgbm_layout_resume(part_bins, rowid_bits, scores):
             bins = _unpermute_bins(part_bins, rowid_bits, N, C, Npad)
             return bins, ghi0(scores)
 
-        self._init_phys = jax.jit(init_phys)
-        self._init_phys_adopt = jax.jit(init_phys, donate_argnums=(0,))
-        self._init_phys_perm = jax.jit(init_phys_perm, donate_argnums=(0,))
+        self._init_phys = jax.jit(lgbm_layout_init)
+        self._init_phys_adopt = jax.jit(lgbm_layout_init, donate_argnums=(0,))
+        self._init_phys_perm = jax.jit(lgbm_layout_resume, donate_argnums=(0,))
 
         use_bag = self.need_bagging and not self.balanced_bagging
         bag_key = jax.random.PRNGKey(cfg.bagging_seed)
@@ -1093,7 +1134,7 @@ class GBDT:
 
         needs_snap = self._mc_fused_kind() == "snapshot"
 
-        def step(part_bins, ghi, feature_mask, seed, feat_used):
+        def lgbm_fused_step(part_bins, ghi, feature_mask, seed, feat_used):
             obs.compile_event("train.fused_step")   # trace-time only
             smalls = []
             P = None
@@ -1103,47 +1144,53 @@ class GBDT:
                 # them before any class tree).  Snapshot the
                 # probabilities by ORIGINAL row id; each class tree
                 # gathers them back through its own permutation.
-                rowid0 = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
-                p0 = obj.fused_prob_snapshot(ghi[3:3 + K])
-                P = jnp.zeros((K, N + 1), jnp.float32).at[
-                    :, jnp.minimum(rowid0, N)].set(p0)
+                with scopes.scope("gradients"):
+                    rowid0 = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
+                    p0 = obj.fused_prob_snapshot(ghi[3:3 + K])
+                    P = jnp.zeros((K, N + 1), jnp.float32).at[
+                        :, jnp.minimum(rowid0, N)].set(p0)
             for k in range(K):
-                rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
-                vf = (rowid != N).astype(jnp.float32)
-                if needs_snap:
-                    p_k = jnp.take(P[k], jnp.minimum(rowid, N))
-                    g, h = obj.fused_class_gradients_from_prob(
-                        k, p_k, ghi[lbl_row],
-                        ghi[w_row] if has_w else None)
-                else:
-                    g, h = obj.fused_class_gradients(
-                        k, ghi[3:3 + K], ghi[lbl_row],
-                        ghi[w_row] if has_w else None)
+                with scopes.scope("gradients"):
+                    rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
+                    vf = (rowid != N).astype(jnp.float32)
+                    if needs_snap:
+                        p_k = jnp.take(P[k], jnp.minimum(rowid, N))
+                        g, h = obj.fused_class_gradients_from_prob(
+                            k, p_k, ghi[lbl_row],
+                            ghi[w_row] if has_w else None)
+                    else:
+                        g, h = obj.fused_class_gradients(
+                            k, ghi[3:3 + K], ghi[lbl_row],
+                            ghi[w_row] if has_w else None)
                 bag_cnt = jnp.int32(N)
                 if use_bag:
                     # one bag per ITERATION shared by all K class trees
                     # (bagging.hpp), drawn by original row id (see the
                     # binary fused step)
-                    kb = jax.random.fold_in(bag_key,
-                                            (seed - 1) // bag_freq)
-                    u = jax.random.uniform(kb, (N + 1,))
-                    sel = (jnp.take(u, jnp.minimum(rowid, N)) < bag_frac) \
-                        & (vf > 0)
-                    sf = sel.astype(jnp.float32)
-                    g = g * sf
-                    h = h * sf
-                    bag_cnt = jnp.sum(sel.astype(jnp.int32))
+                    with scopes.scope("sampling"):
+                        kb = jax.random.fold_in(bag_key,
+                                                (seed - 1) // bag_freq)
+                        u = jax.random.uniform(kb, (N + 1,))
+                        sel = (jnp.take(u, jnp.minimum(rowid, N))
+                               < bag_frac) & (vf > 0)
+                        sf = sel.astype(jnp.float32)
+                        g = g * sf
+                        h = h * sf
+                        bag_cnt = jnp.sum(sel.astype(jnp.int32))
                 else:
-                    g = g * vf
-                    h = h * vf
-                ghi = ghi.at[0].set(g).at[1].set(h)
+                    with scopes.scope("gradients"):
+                        g = g * vf
+                        h = h * vf
+                with scopes.scope("gradients"):
+                    ghi = ghi.at[0].set(g).at[1].set(h)
                 rec = lr_._build_tree_impl(part_bins, ghi, bag_cnt,
                                            feature_mask, seed * K + k,
                                            feat_used)
                 part_bins = rec["part_bins"]
                 ghi = rec["part_ghi"]
-                ghi = ghi.at[3 + k].add(
-                    shrink * _phys_leaf_delta(rec, Npad))
+                with scopes.scope("score_update"):
+                    ghi = ghi.at[3 + k].add(
+                        shrink * _phys_leaf_delta(rec, Npad))
                 feat_used = rec["feat_used"]
                 small = {kk: v for kk, v in rec.items()
                          if kk.startswith(("node_", "leaf_")) or kk in
@@ -1152,7 +1199,7 @@ class GBDT:
                 smalls.append(small)
             return part_bins, ghi, smalls
 
-        self._fused_phys = jax.jit(step, donate_argnums=(0, 1))
+        self._fused_phys = jax.jit(lgbm_fused_step, donate_argnums=(0, 1))
         self._fused = self._fused_phys
 
     def _setup_fused_sharded(self) -> None:
@@ -1219,6 +1266,7 @@ class GBDT:
         row_spec = P() if repl_rows else P(AXIS)
         state_spec = P() if repl_rows else P(None, AXIS)
 
+        @scopes.phase("layout_init")
         def init_shard(binned, scores, counts, *payloads):
             # binned (rows+1, G); scores/payloads (rows,); counts (1,)
             pb = jnp.pad(
@@ -1275,25 +1323,28 @@ class GBDT:
         mode = sb.mode
         F = lr_.F
 
-        def step_shard(pb, ghi, feature_mask, seed, feat_used):
+        def lgbm_fused_step(pb, ghi, feature_mask, seed, feat_used):
             obs.compile_event("train.fused_step")   # trace-time only
-            rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
-            vf = (rowid != SENT).astype(jnp.float32)
-            payload = {n: ghi[4 + i] for i, n in enumerate(names)}
-            g, h = obj.gradients_from_payload(ghi[3], **payload)
-            g = g * vf
-            h = h * vf
+            with scopes.scope("gradients"):
+                rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
+                vf = (rowid != SENT).astype(jnp.float32)
+                payload = {n: ghi[4 + i] for i, n in enumerate(names)}
+                g, h = obj.gradients_from_payload(ghi[3], **payload)
+                g = g * vf
+                h = h * vf
             if use_bag:
                 # draws by GLOBAL row id: every shard layout sees the
                 # same bag for a given period (bagging.hpp semantics)
-                kb = jax.random.fold_in(bag_key, (seed - 1) // bag_freq)
-                u = jax.random.uniform(kb, (SENT + 1,))
-                sel = (jnp.take(u, jnp.minimum(rowid, SENT)) < bag_frac) \
-                    & (vf > 0)
-                sf = sel.astype(jnp.float32)
-                g = g * sf
-                h = h * sf
-                bag_cnt = jnp.sum(sel.astype(jnp.int32))
+                with scopes.scope("sampling"):
+                    kb = jax.random.fold_in(bag_key,
+                                            (seed - 1) // bag_freq)
+                    u = jax.random.uniform(kb, (SENT + 1,))
+                    sel = (jnp.take(u, jnp.minimum(rowid, SENT))
+                           < bag_frac) & (vf > 0)
+                    sf = sel.astype(jnp.float32)
+                    g = g * sf
+                    h = h * sf
+                    bag_cnt = jnp.sum(sel.astype(jnp.int32))
             else:
                 bag_cnt = jnp.sum(vf).astype(jnp.int32)
             if mode == "feature":
@@ -1302,11 +1353,13 @@ class GBDT:
                 fidx = jnp.arange(F)
                 feature_mask = feature_mask & (fidx >= d * per) \
                     & (fidx < (d + 1) * per)
-            ghi = ghi.at[0].set(g).at[1].set(h)
+            with scopes.scope("gradients"):
+                ghi = ghi.at[0].set(g).at[1].set(h)
             rec = lr_._build_tree_impl(pb, ghi, bag_cnt, feature_mask,
                                        seed, feat_used)
-            ghi_out = rec["part_ghi"].at[3].add(
-                shrink * _phys_leaf_delta(rec, Npad))
+            with scopes.scope("score_update"):
+                ghi_out = rec["part_ghi"].at[3].add(
+                    shrink * _phys_leaf_delta(rec, Npad))
             small = {k: v for k, v in rec.items()
                      if k.startswith(("node_", "leaf_")) or k in
                      ("s", "feat_used")}
@@ -1325,13 +1378,40 @@ class GBDT:
             return rec["part_bins"], ghi_out, small
 
         self._fused_phys = jax.jit(smap(
-            step_shard,
+            lgbm_fused_step,
             in_specs=(state_spec, state_spec, P(), P(), P()),
             out_specs=(state_spec, state_spec, P())),
             donate_argnums=(0, 1))
         self._fused = self._fused_phys
         log.info("fused sharded iteration ENABLED (%s-parallel over %d "
                  "devices)", mode, ndev)
+
+    def _init_phys_layout(self) -> None:
+        """(Re)make the physical row layout the fused step runs on: at the
+        first iteration, and again after every scores read or write."""
+        if self._init_phys_fn is not None:   # sharded layout (a closure
+            # over mesh arrays it places itself: not registered)
+            self._phys = tuple(self._init_phys_fn())
+            self._phys_carrier = None
+        elif self._phys_carrier is not None:
+            # resume from the retired carrier: the bins are
+            # unpermuted back to the identity layout in-program,
+            # bit-identical to an init from the master buffer
+            pb, rowid_bits = self._phys_carrier
+            self._phys_carrier = None
+            self._phys = tuple(self._init_phys_perm(
+                pb, rowid_bits, self._scores_arr))
+            self._register_once("train.layout_init", self._init_phys_perm,
+                                self._phys[0], rowid_bits, self._scores_arr)
+        else:
+            self._phys = tuple(self._init_phys_adopt(
+                self.learner._part0, self._scores_arr))
+            self._register_once("train.layout_init", self._init_phys_adopt,
+                                self._phys[0], self._scores_arr)
+            # the donating identity init aliased the master
+            # buffer into the carrier; retire the (now stale)
+            # learner/ingest references
+            self._adopt_master_buffer()
 
     def _train_one_iter_fused(self) -> bool:
         """Fast path: the whole iteration in one device program.
@@ -1341,7 +1421,6 @@ class GBDT:
         and materialized with a one-iteration lag (its transfer overlaps the
         next iteration's device compute).  Consumers of `models` call
         `_flush_pending()` first."""
-        from ..utils.timer import global_timer
         feature_mask = self._feature_mask(self.iter)
         if self._cegb_feat_used is not None:
             feat_used = self._cegb_feat_used
@@ -1351,36 +1430,24 @@ class GBDT:
             feat_used = self._zeros_fused
         if self._fused_phys is not None:
             if self._phys is None:
-                if self._init_phys_fn is not None:   # sharded layout
-                    self._phys = tuple(self._init_phys_fn())
-                    self._phys_carrier = None
-                elif self._phys_carrier is not None:
-                    # resume from the retired carrier: the bins are
-                    # unpermuted back to the identity layout in-program,
-                    # bit-identical to an init from the master buffer
-                    pb, rowid_bits = self._phys_carrier
-                    self._phys_carrier = None
-                    self._phys = tuple(self._init_phys_perm(
-                        pb, rowid_bits, self._scores_arr))
-                else:
-                    self._phys = tuple(self._init_phys_adopt(
-                        self.learner._part0, self._scores_arr))
-                    # the donating identity init aliased the master
-                    # buffer into the carrier; retire the (now stale)
-                    # learner/ingest references
-                    self._adopt_master_buffer()
-            with global_timer.section("GBDT::FusedIter",
-                                      sync=lambda: self._phys[1]):
+                with obs.span("train.layout_init"):
+                    self._init_phys_layout()
+            with obs.span("train.dispatch"):
                 pb, ghi, rec = self._fused_phys(
                     self._phys[0], self._phys[1], feature_mask,
                     self.iter + 1, feat_used)
-                self._phys = (pb, ghi)
+            self._phys = (pb, ghi)
+            self._register_once("train.fused_step", self._fused_phys,
+                                pb, ghi, feature_mask, self.iter + 1,
+                                feat_used)
         else:
-            with global_timer.section("GBDT::FusedIter",
-                                      sync=lambda: self.scores):
+            with obs.span("train.dispatch"):
                 self.scores, rec = self._fused(
                     self.learner._part0, self.scores, feature_mask,
                     self.iter + 1, feat_used)
+            self._register_once("train.fused_step", self._fused,
+                                self.learner._part0, self.scores,
+                                feature_mask, self.iter + 1, feat_used)
         recs = rec if isinstance(rec, list) else [rec]
         if self.learner.has_cegb:
             self._cegb_feat_used = recs[-1]["feat_used"]
@@ -1413,7 +1480,8 @@ class GBDT:
         n = len(self._pending_recs) - lag
         if n <= 0:
             return False
-        batch_host = jax.device_get(self._pending_recs[:n])
+        with obs.span("train.drain_records"):
+            batch_host = jax.device_get(self._pending_recs[:n])
         K = self.num_tree_per_iteration
         for host_record in batch_host:
             if self._materialize_pending(host_record):
@@ -1953,7 +2021,6 @@ class GBDT:
 
         Returns True when training should stop (no further splits possible).
         """
-        from ..utils.timer import global_timer
         self._assert_trainable()
         if grad is None and hess is None and self._fused is not None:
             return self._train_one_iter_fused()
@@ -1964,7 +2031,7 @@ class GBDT:
         # it if the fused carrier adopted it (mixed fused/eager training)
         self._ensure_part0()
         if grad is None or hess is None:
-            with global_timer.section("GBDT::Boosting (gradients)"):
+            with obs.span("train.gradients"):
                 grad, hess = self._compute_gradients()
         else:
             grad = jnp.asarray(grad, dtype=jnp.float32)
@@ -2022,8 +2089,7 @@ class GBDT:
                     hk = hk * qscale[1]
                     qscale = None
             tree_seed = self.iter * K + k + 1
-            with global_timer.section("TreeLearner::Train",
-                                      sync=lambda: record["leaf_value"]):
+            with obs.span("train.tree_build"):
                 if use_sharded:
                     record = self.sharded_builder.build_tree(
                         gk, hk, feature_mask, seed=tree_seed,
@@ -2063,8 +2129,7 @@ class GBDT:
             delta_leaf = leaf_value_dev * self.shrinkage_rate
             use_linear = self.config.linear_tree
             if not use_linear:
-                with global_timer.section("GBDT::UpdateScore",
-                                          sync=lambda: self.scores):
+                with obs.span("train.score_update"):
                     self._apply_score_update(nodes, delta_leaf, k)
             # host tree for the model
             host_record = {key: np.asarray(val) for key, val in record.items()
@@ -2159,10 +2224,8 @@ class GBDT:
     # ------------------------------------------------------------------
     def eval_metrics(self) -> Dict[str, List[Tuple[str, float, bool]]]:
         """Evaluate all metrics; returns {dataset_name: [(metric, value, is_max_better)]}."""
-        from ..utils.timer import global_timer
         with obs.span("train.eval"):
-            with global_timer.section("Metric::Eval"):
-                return self._eval_metrics_impl()
+            return self._eval_metrics_impl()
 
     def _eval_metrics_impl(self):
         out: Dict[str, List[Tuple[str, float, bool]]] = {}

@@ -43,6 +43,7 @@ import numpy as np
 
 from ..config import Config
 from ..dataset import BinnedDataset
+from ..obs import scopes
 from ..ops import split as split_ops
 from ..ops.histogram import leaf_hist_slice
 from ..ops.partition import split_decision
@@ -842,6 +843,7 @@ class SerialTreeLearner:
         return jnp.floor(u * span).astype(jnp.int32)
 
     # ------------------------------------------------------------------
+    @scopes.phase("histogram")
     def _hist_leaf(self, part_bins, part_ghi, start, cnt, scale=None):
         if self._chunk_policy.adaptive:
             # leaf-size-adaptive bands (eligibility guarantees the
@@ -888,6 +890,7 @@ class SerialTreeLearner:
             return h
         return h * scale[None, None, :]
 
+    @scopes.phase("histogram")
     def _hist_leaf_flat(self, part_bins, part_ghi, start, cnt):
         """Smaller-child histogram directly in the lane-flattened (8, WL)
         slot layout of the Pallas hist-state RMW kernel."""
@@ -945,6 +948,7 @@ class SerialTreeLearner:
         cat_left = jnp.any(oh & cat_set[None, :], axis=1)
         return jnp.where(is_cat, cat_left, num_left)
 
+    @scopes.phase("partition")
     def _partition_leaf(self, st, start, cnt, col, decision_scalars):
         """Two-way partition of the contiguous leaf range [start, start+cnt).
 
@@ -1099,6 +1103,7 @@ class SerialTreeLearner:
                 nl = nl + nl_w
         return moved, nl
 
+    @scopes.phase("partition")
     def _partition_leaf_window(self, bufs, start, cnt, col,
                                decision_scalars, width: int, trip):
         """Single-window leaf partition at a smaller menu width: one
@@ -1151,6 +1156,7 @@ class SerialTreeLearner:
             out["part_aux"] = pa
         return out, nl
 
+    @scopes.phase("partition")
     def _partition_leaf_pallas(self, st, start, cnt, col, decision_scalars):
         """Pallas-kernel leaf partition (see ops/partition_pallas.py):
         bit-identical layout to the XLA path above at ~30x lower cost on
@@ -1169,6 +1175,7 @@ class SerialTreeLearner:
         moved = {"part_bins": pb, "part_ghi": pg, "sc_packed": sp}
         return moved, nl[0, 0]
 
+    @scopes.phase("split_mega")
     def _split_leaf_mega(self, st, start, cnt, col, decision_scalars,
                          hist_scale=None):
         """Mega-path split: partition the leaf AND produce BOTH
@@ -1379,6 +1386,7 @@ class SerialTreeLearner:
         kth = jnp.sort(scores)[self.F - k]
         return scores >= kth
 
+    @scopes.phase("search")
     def _leaf_best_split(self, hist_group, sum_g, sum_h, cnt, local_cnt,
                          depth, cmin, cmax, parent_out, feature_mask,
                          feat_used, *rest):
@@ -1432,6 +1440,7 @@ class SerialTreeLearner:
         fix = (jnp.stack([sum_g, sum_h]) - known) * self.fix_mask[:, None]
         return feat_hist.at[jnp.arange(self.F), self.default_pos].add(fix)
 
+    @scopes.phase("search")
     def _find_best(self, feat_hist, sum_g, sum_h, cnt, depth, cmin, cmax,
                    feature_mask, feat_used=None, parent_out=None,
                    with_feature_gains=False, lazy_cnt=None,
@@ -1750,6 +1759,7 @@ class SerialTreeLearner:
                     onec > 0, above_vals_min, neg))
         return cmin_l, cmax_l, cmin_r, cmax_r
 
+    @scopes.phase("search")
     def _leaf_best_split_voting(self, hist_local, sum_g, sum_h, cnt,
                                 local_cnt, depth, cmin, cmax, parent_out,
                                 feature_mask, feat_used=None, lazy_cnt=None,
@@ -1860,12 +1870,19 @@ class SerialTreeLearner:
         winner = jnp.argmax(gathered.gain)
         return jax.tree.map(lambda a: a[winner], gathered)
 
+    @scopes.phase("bookkeeping")
     def _build_tree_impl(self, part_bins, part_ghi0, bag_cnt,
                          feature_mask, seed, feat_used_init=None, aux0=None,
                          hist_scale=None):
         """Core tree loop over a prebuilt (8, N_pad) row payload whose
         rows are (grad, hess, rowid-bits, extras...); the extras ride the
-        partition untouched (physical-order fused step)."""
+        partition untouched (physical-order fused step).
+
+        Scope: whatever the build issues outside a narrower ``lgbm.*``
+        scope (histogram, hist_state, search, partition, split_mega) is
+        ``lgbm.bookkeeping``: leaf election, the packed-scalar gathers,
+        node/leaf matrix writes, the frontier replay, renumbering and the
+        undo of pruned partitions."""
         if self.frontier_k > 1:
             # batched frontier growth (the eligibility gate guarantees
             # feat_used_init/aux0 are absent: no CEGB in batched mode)
@@ -1978,13 +1995,15 @@ class SerialTreeLearner:
             # and consumes them in-register: no per-leaf histogram state
             # rides the while loop at all (and with it go the two
             # contextual full-state copies per split — PERF.md round 4)
-            if use_flat:
-                state["hist"] = jnp.zeros(
-                    (L + 1, 8, self._flat_geom[2]), jnp.float32).at[0].set(
-                    self._flatten_hist(root_hist))
-            else:
-                state["hist"] = jnp.zeros(
-                    (L + 1, G, B, 2), dtype=jnp.float32).at[0].set(root_hist)
+            with scopes.scope("hist_state"):
+                if use_flat:
+                    state["hist"] = jnp.zeros(
+                        (L + 1, 8, self._flat_geom[2]),
+                        jnp.float32).at[0].set(self._flatten_hist(root_hist))
+                else:
+                    state["hist"] = jnp.zeros(
+                        (L + 1, G, B, 2),
+                        dtype=jnp.float32).at[0].set(root_hist)
         if self.has_categorical:
             state["best_cat_set"] = jnp.zeros(
                 (L + 1, self.BF), jnp.bool_).at[0].set(best0.cat_set)
@@ -2241,24 +2260,26 @@ class SerialTreeLearner:
                     small_flat = self._hist_leaf_flat(
                         moved["part_bins"], moved["part_ghi"],
                         sm_start, sm_cnt)
-                    hist, hl_flat, hr_flat = hist_rmw_pallas(
-                        st["hist"], small_flat,
-                        jnp.stack([best_leaf, wr_a, wr_b,
-                                   small_is_left.astype(jnp.int32)]),
-                        interpret=self._interp)
+                    with scopes.scope("hist_state"):
+                        hist, hl_flat, hr_flat = hist_rmw_pallas(
+                            st["hist"], small_flat,
+                            jnp.stack([best_leaf, wr_a, wr_b,
+                                       small_is_left.astype(jnp.int32)]),
+                            interpret=self._interp)
                     hist_left = hist_right = None
                 else:
                     hist_small = self._psum(self._hist_leaf(
                         moved["part_bins"], moved["part_ghi"],
                         sm_start, sm_cnt, scale=hist_scale))
-                    parent_hist = st["hist"][best_leaf]
-                    hist_large = parent_hist - hist_small
-                    hist_left = jnp.where(small_is_left, hist_small,
-                                          hist_large)
-                    hist_right = jnp.where(small_is_left, hist_large,
-                                           hist_small)
-                    hist = st["hist"].at[wr_a].set(hist_left).at[wr_b].set(
-                        hist_right)
+                    with scopes.scope("hist_state"):
+                        parent_hist = st["hist"][best_leaf]
+                        hist_large = parent_hist - hist_small
+                        hist_left = jnp.where(small_is_left, hist_small,
+                                              hist_large)
+                        hist_right = jnp.where(small_is_left, hist_large,
+                                               hist_small)
+                        hist = st["hist"].at[wr_a].set(
+                            hist_left).at[wr_b].set(hist_right)
 
                 lsg = pcol[LM_BLSG]
                 lsh = pcol[LM_BLSH]
@@ -2424,21 +2445,9 @@ class SerialTreeLearner:
                     info = jnp.concatenate(
                         [iblock(lsg, lsh, left_cnt_g, mask_l),
                          iblock(rsg, rsh, right_cnt_g, mask_r)], axis=0)
-                    tile = best_split_pair_pallas(
-                        hg, hh, self._fmeta_pair, info,
-                        l1=self.l1, l2=self.l2,
-                        max_delta_step=self.max_delta_step,
-                        min_gain_to_split=self.min_gain_to_split,
-                        min_data_in_leaf=self.min_data_in_leaf,
-                        min_sum_hessian=self.min_sum_hessian,
-                        max_depth=self.max_depth, interpret=self._interp)
-                    if self._ab_double == "search":
-                        # measurement-only in-context doubling: the
-                        # opaque select blocks CSE; results bit-identical
-                        opq = moved["part_ghi"][0, :1] * 0.0
-                        tile2 = best_split_pair_pallas(
-                            jnp.where(opq[0] < 1.0, hg, hg + 1.0), hh,
-                            self._fmeta_pair, info,
+                    with scopes.scope("search"):
+                        tile = best_split_pair_pallas(
+                            hg, hh, self._fmeta_pair, info,
                             l1=self.l1, l2=self.l2,
                             max_delta_step=self.max_delta_step,
                             min_gain_to_split=self.min_gain_to_split,
@@ -2446,6 +2455,21 @@ class SerialTreeLearner:
                             min_sum_hessian=self.min_sum_hessian,
                             max_depth=self.max_depth,
                             interpret=self._interp)
+                    if self._ab_double == "search":
+                        # measurement-only in-context doubling: the
+                        # opaque select blocks CSE; results bit-identical
+                        opq = moved["part_ghi"][0, :1] * 0.0
+                        with scopes.scope("search"):
+                            tile2 = best_split_pair_pallas(
+                                jnp.where(opq[0] < 1.0, hg, hg + 1.0), hh,
+                                self._fmeta_pair, info,
+                                l1=self.l1, l2=self.l2,
+                                max_delta_step=self.max_delta_step,
+                                min_gain_to_split=self.min_gain_to_split,
+                                min_data_in_leaf=self.min_data_in_leaf,
+                                min_sum_hessian=self.min_sum_hessian,
+                                max_depth=self.max_depth,
+                                interpret=self._interp)
                         tile = jnp.where(opq[0] < 1.0, tile2, tile)
                     col_l = jnp.concatenate(
                         [head_l, tile[0, :13],
@@ -2729,8 +2753,9 @@ class SerialTreeLearner:
             "rslot": jnp.zeros((MS + 1,), jnp.int32),
         }
         if not use_mega:
-            state["hist"] = jnp.zeros((SL, G, B, 2),
-                                      jnp.float32).at[0].set(root_hist)
+            with scopes.scope("hist_state"):
+                state["hist"] = jnp.zeros((SL, G, B, 2),
+                                          jnp.float32).at[0].set(root_hist)
         if self.has_categorical:
             state["best_cat_set"] = jnp.zeros(
                 (SL, self.BF), jnp.bool_).at[0].set(best0.cat_set)
@@ -2811,7 +2836,8 @@ class SerialTreeLearner:
                 # subtraction trick: ONE gather over the K parents
                 # replaces K dynamic-slices of the histogram state (the
                 # round-4 contextual double-copy pathology, PERF.md)
-                parent_hists = jnp.take(st["hist"], sel_slots, axis=0)
+                with scopes.scope("hist_state"):
+                    parent_hists = jnp.take(st["hist"], sel_slots, axis=0)
 
             # ---- per-leaf payload passes: the k-loop runs ONLY the
             # partitions (selected leaves occupy disjoint row ranges, so
@@ -2870,15 +2896,16 @@ class SerialTreeLearner:
                                     lcg[k]),
                              iblock(pcols[LM_BRSG, k], pcols[LM_BRSH, k],
                                     rcg[k])], axis=0)
-                        tile = best_split_pair_pallas(
-                            hg, hh, self._fmeta_pair, info,
-                            l1=self.l1, l2=self.l2,
-                            max_delta_step=self.max_delta_step,
-                            min_gain_to_split=self.min_gain_to_split,
-                            min_data_in_leaf=self.min_data_in_leaf,
-                            min_sum_hessian=self.min_sum_hessian,
-                            max_depth=self.max_depth,
-                            interpret=self._interp)
+                        with scopes.scope("search"):
+                            tile = best_split_pair_pallas(
+                                hg, hh, self._fmeta_pair, info,
+                                l1=self.l1, l2=self.l2,
+                                max_delta_step=self.max_delta_step,
+                                min_gain_to_split=self.min_gain_to_split,
+                                min_data_in_leaf=self.min_data_in_leaf,
+                                min_sum_hessian=self.min_sum_hessian,
+                                max_depth=self.max_depth,
+                                interpret=self._interp)
                         tbits = _f2i(tile)
                         seg = jax.lax.dynamic_update_slice(
                             seg, jnp.transpose(tbits[:1, :13]), (0, k))
@@ -2896,9 +2923,11 @@ class SerialTreeLearner:
                                          start + left_cnt)
                     sm_cnt = jnp.where(small_is_left[k], left_cnt,
                                        cnt - left_cnt)
-                    acc = (acc[0].at[k].set(self._hist_leaf(
+                    h_small = self._hist_leaf(
                         moved["part_bins"], moved["part_ghi"],
-                        sm_start, sm_cnt, scale=hist_scale)),)
+                        sm_start, sm_cnt, scale=hist_scale)
+                    with scopes.scope("hist_state"):
+                        acc = (acc[0].at[k].set(h_small),)
                 return ({**bufs, **moved}, acc, lcnt.at[k].set(left_cnt),
                         seg)
 
@@ -2911,18 +2940,19 @@ class SerialTreeLearner:
             # ---- children histograms -> state / search inputs ----
             ch_slots = jnp.concatenate([sel_slots, wrb_slots])
             upd_hist = {}
-            if use_mega:
-                hist_left = jnp.stack([acc[0], acc[1]], axis=3)
-                hist_right = jnp.stack([acc[2], acc[3]], axis=3)
-            else:
-                small = acc[0]
-                large = parent_hists - small
-                sel_b = small_is_left[:, None, None, None]
-                hist_left = jnp.where(sel_b, small, large)
-                hist_right = jnp.where(sel_b, large, small)
-                # ONE 2K-row scatter replaces 2K per-split state updates
-                upd_hist["hist"] = st["hist"].at[ch_slots].set(
-                    jnp.concatenate([hist_left, hist_right], axis=0))
+            with scopes.scope("hist_state"):
+                if use_mega:
+                    hist_left = jnp.stack([acc[0], acc[1]], axis=3)
+                    hist_right = jnp.stack([acc[2], acc[3]], axis=3)
+                else:
+                    small = acc[0]
+                    large = parent_hists - small
+                    sel_b = small_is_left[:, None, None, None]
+                    hist_left = jnp.where(sel_b, small, large)
+                    hist_right = jnp.where(sel_b, large, small)
+                    # ONE 2K-row scatter replaces 2K per-split state updates
+                    upd_hist["hist"] = st["hist"].at[ch_slots].set(
+                        jnp.concatenate([hist_left, hist_right], axis=0))
 
             def seg13(bs):
                 return _pack_bits([

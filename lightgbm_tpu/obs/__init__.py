@@ -10,6 +10,10 @@ artifact.  Enabled by the ``telemetry=off|counters|trace`` parameter
 (or ``LIGHTGBM_TPU_TELEMETRY``); read at runtime via
 ``Booster.telemetry_report()`` or the CLI's ``telemetry_out=`` export.
 
+Device side: :mod:`lightgbm_tpu.obs.scopes` names the training step's
+work (``lgbm.<phase>`` scopes, the scope table a device trace is read
+with).
+
 Model & data health rides on top: :mod:`lightgbm_tpu.obs.digest`
 (on-device per-feature bin-occupancy digests with a bit-identical
 NumPy oracle, PSI/chi-square skew scoring) and
@@ -23,7 +27,7 @@ new samples against same-fingerprint history (median/MAD, noise-aware)
 — ``tools/perfwatch.py`` is the check/report/drill CLI on top.
 """
 
-from . import digest, health, memory, regress
+from . import digest, health, memory, regress, scopes
 from .exporters import (export_all, export_chrome_trace, export_jsonl,
                         export_prometheus, prometheus_text)
 from .telemetry import (MODES, NULL, Telemetry, compile_event,
@@ -33,7 +37,7 @@ from .telemetry import (MODES, NULL, Telemetry, compile_event,
 __all__ = [
     "MODES", "NULL", "Telemetry", "compile_event",
     "configure_from_config", "counter", "enabled", "gauge", "get",
-    "instant", "span", "digest", "health", "memory", "regress",
+    "instant", "span", "digest", "health", "memory", "regress", "scopes",
     "memory_snapshot",
     "export_all", "export_chrome_trace", "export_jsonl",
     "export_prometheus", "prometheus_text",

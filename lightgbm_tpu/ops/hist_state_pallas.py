@@ -108,4 +108,5 @@ def hist_rmw_pallas(hist_state, hist_small, idx, *, interpret: bool = False):
         grid_spec=grid_spec,
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="lgbm_hist_state",
     )(idx.astype(jnp.int32), hist_state, hist_small)

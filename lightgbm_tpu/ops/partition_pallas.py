@@ -615,6 +615,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         grid_spec=grid_spec,
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="lgbm_partition",
     )(scalars, part_bins, part_ghi, sc_packed)
     return out
 

@@ -550,5 +550,6 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         grid_spec=grid_spec,
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="lgbm_split_mega",
     )(scalars, part_bins, part_ghi, sc_packed)
     return out

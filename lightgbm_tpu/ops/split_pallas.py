@@ -265,4 +265,5 @@ def best_split_pair_pallas(hist_g, hist_h, fmeta, info,
         kernel,
         out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
         interpret=interpret,
+        name="lgbm_split_search",
     )(hist_g, hist_h, fmeta, info)

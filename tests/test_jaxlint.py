@@ -125,7 +125,7 @@ def test_jl001_ignores_host_numpy():
 def test_jl001_scoped_to_hot_modules():
     src = "import jax.numpy as jnp\ndef f(a):\n    return float(jnp.sum(a))\n"
     assert _rules(src, "lightgbm_tpu/models/serving.py") == ["JL001"]
-    assert _rules(src, "lightgbm_tpu/utils/timer.py") == []
+    assert _rules(src, "lightgbm_tpu/utils/log.py") == []
 
 
 def test_jl002_jit_in_loop_and_immediate():

@@ -1,0 +1,401 @@
+"""The ``lgbm.<phase>`` scopes of the training step and the scope table the
+benchmark reads (lightgbm_tpu/obs/scopes.py, benchmark/readers/
+device_phase.py).
+
+* ``phase_of`` on a fixed HLO snippet;
+* scopes add no operation: the toy step lowers to the same text with
+  ``jax.named_scope`` turned off;
+* registering a program frees the booster and traces or compiles nothing a
+  run without registration does not;
+* every kernel plan the CPU reaches yields a table that names the histogram,
+  the search and the partition;
+* the benchmark's reader on a synthetic ``op_seconds``;
+* the Pallas partition kernel compiles for ``v5e:2x2`` under its own name.
+"""
+
+import contextlib
+import gc
+import importlib.util
+import os
+import types
+import weakref
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import obs
+from lightgbm_tpu.obs import scopes
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# what XLA:TPU prints (cut from the step's text compiled for v5e): a while
+# body with a Pallas custom call, a fusion that kept its op_name, one that
+# lost it, an instruction with none, and the fusion bodies they call
+SNIPPET = '''HloModule jit_lgbm_fused_step, is_scheduled=true
+
+%fused_computation.1 (param_0.2: f32[8,128]) -> f32[8,128] {
+  %param_0.2 = f32[8,128]{1,0} parameter(0)
+  %constant.9 = f32[] constant(1), metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/vmap(lgbm.search)/lgbm.search/broadcast_in_dim"}
+  %broadcast.4 = f32[8,128]{1,0} broadcast(%constant.9), dimensions={}
+  ROOT %add.7 = f32[8,128]{1,0} add(%param_0.2, %broadcast.4), metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/lgbm.histogram/add"}
+}
+
+%fused_computation.2 (param_0.3: f32[8,128]) -> f32[1,128] {
+  %param_0.3 = f32[8,128]{1,0} parameter(0)
+  %constant.10 = s32[] constant(0), metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/lgbm.search/iota"}
+  ROOT %slice.5 = f32[1,128]{1,0} slice(%param_0.3), slice={[2:3], [0:128]}, metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/slice"}
+}
+
+%fused_computation.3 (param_0.4: f32[8,128]) -> f32[8,128] {
+  %param_0.4 = f32[8,128]{1,0} parameter(0)
+  %neg.1 = f32[8,128]{1,0} negate(%param_0.4), metadata={op_name="jit(lgbm_fused_step)/lgbm.gradients/neg"}
+  ROOT %exp.1 = f32[8,128]{1,0} exponential(%neg.1), metadata={op_name="jit(lgbm_fused_step)/lgbm.score_update/exp"}
+}
+
+%region_0.3 (lhs: f32[], rhs: f32[]) -> f32[] {
+  %lhs = f32[] parameter(0)
+  %rhs = f32[] parameter(1)
+  ROOT %add.1 = f32[] add(%lhs, %rhs), metadata={op_name="jit(lgbm_fused_step)/lgbm.search/reduce_sum"}
+}
+
+%wide.body.7 (arg: (s32[], f32[8,128])) -> (s32[], f32[8,128]) {
+  %arg = (s32[], f32[8,128]{1,0}) parameter(0)
+  %get-tuple-element.2 = f32[8,128]{1,0} get-tuple-element(%arg), index=1
+  %lgbm_partition.7 = f32[8,128]{1,0} custom-call(%get-tuple-element.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/closed_call/lgbm.partition/lgbm.partition/lgbm_partition/pallas_call" stack_frame_id=6}
+  %fusion.8 = f32[8,128]{1,0} fusion(%lgbm_partition.7), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/lgbm.histogram/add" stack_frame_id=7}
+  %broadcast_select_fusion.15 = f32[1,128]{1,0} fusion(%fusion.8), kind=kLoop, calls=%fused_computation.2
+  %two_phase_fusion = f32[8,128]{1,0} fusion(%fusion.8), kind=kLoop, calls=%fused_computation.3
+  %reduce.3 = f32[] reduce(%fusion.8, %constant.2), dimensions={0,1}, to_apply=%region_0.3, metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while/body/lgbm.search/reduce_sum"}
+  %copy.12 = f32[8,128]{0,1} copy(%fusion.8)
+  ROOT %tuple.2 = (s32[], f32[8,128]{1,0}) tuple(%get-tuple-element.2, %fusion.8)
+}
+
+ENTRY %main.11 (x.1: f32[8,128]) -> f32[8,128] {
+  %x.1 = f32[8,128]{1,0} parameter(0), metadata={op_name="x"}
+  %while.2 = (s32[], f32[8,128]{1,0}) while(%tuple.1), condition=%cond.4, body=%wide.body.7, metadata={op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while"}
+  ROOT %dynamic-update-slice.3 = f32[8,128]{1,0} dynamic-update-slice(%x.1, %y, %c, %c), metadata={op_name="jit(lgbm_fused_step)/lgbm.score_update/scatter-add"}
+}
+'''
+
+
+def test_phase_of_on_a_fixed_snippet():
+    table = scopes.phase_of(SNIPPET)
+    # the kernel's own name reaches the instruction, nested scopes read
+    # innermost
+    assert table["lgbm_partition.7"] == "partition"
+    assert table["fusion.8"] == "histogram"
+    assert table["reduce.3"] == "search"
+    assert table["while.2"] == "bookkeeping"
+    assert table["dynamic-update-slice.3"] == "score_update"
+    # a fusion the compiler left without an op_name takes the one phase of
+    # its body (the shared constant's `lgbm.search` does not vote) ...
+    assert table["broadcast_select_fusion.15"] == "bookkeeping"
+    # ... and none where the body carries two
+    assert table["two_phase_fusion"] is None
+    # no scope, no phase: still listed, so that a name another program
+    # gives a phase to reads as ambiguous
+    assert table["copy.12"] is None and table["x.1"] is None
+    # fusion bodies and scalar regions are no operations of their own
+    for inner in ("add.7", "slice.5", "add.1", "constant.9", "lhs"):
+        assert inner not in table
+
+
+def test_unknown_phase_or_program_is_refused():
+    with pytest.raises(ValueError):
+        scopes.scope("partitoin")
+    with pytest.raises(ValueError):
+        scopes.phase("histograms")
+    with pytest.raises(ValueError):
+        scopes.register("train.step", object())
+
+
+# ---------------------------------------------------------------------------
+# the toy step
+# ---------------------------------------------------------------------------
+def _toy(n=1500, f=8, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.normal(size=(n, f))
+    y = (2 * X[:, 0] + X[:, 1] - X[:, 2] > 0).astype(float)
+    return X, y
+
+
+def _booster(extra=None, seed=0):
+    X, y = _toy(seed=seed)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1}
+    params.update(extra or {})
+    return lgb.Booster(params, lgb.Dataset(X, label=y))
+
+
+def _step_text(bst):
+    """StableHLO of the booster's fused step without locations: named
+    scopes live in locations only."""
+    g = bst._gbdt
+    pb, ghi = g._init_phys(g.learner._part0, g.scores)
+    feat_used = jnp.zeros((g.learner.F,), bool)
+    return g._fused_phys.lower(pb, ghi, g._feature_mask(0), 1,
+                               feat_used).as_text()
+
+
+@pytest.mark.parametrize("extra", [{"tpu_frontier_k": 1},
+                                   {"tpu_frontier_k": 4}],
+                         ids=["k1", "frontier_k4"])
+def test_scopes_add_no_operation(monkeypatch, extra):
+    with_scopes = _step_text(_booster(extra))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = _step_text(_booster(extra))
+    assert "stablehlo" in with_scopes
+    assert with_scopes == without
+
+
+PLANS = {"frontier_k4": {"tpu_frontier_k": 4},
+         "k1": {"tpu_frontier_k": 1},
+         "mega_xla": {"tpu_megakernel": "xla", "tpu_frontier_k": 1}}
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_every_cpu_plan_names_its_phases(plan):
+    scopes._reset()
+    bst = _booster(PLANS[plan])
+    kp = bst._gbdt.kernel_plan()
+    assert kp["frontier_k"] == PLANS[plan]["tpu_frontier_k"]
+    assert kp["mega"] == ("xla" if plan == "mega_xla" else "off")
+    bst.update()
+    scores = np.asarray(bst._gbdt.scores)
+    assert scores.shape == (1500,)
+    table = scopes.scope_table()
+    assert set(table) == set(scopes.PROGRAMS)
+    step = set(table["train.fused_step"].values())
+    assert {"histogram", "search", "gradients", "score_update",
+            "bookkeeping"} <= step
+    assert step & {"partition", "split_mega"}
+    if plan == "mega_xla":
+        assert "split_mega" in step
+    else:
+        assert "hist_state" in step
+    assert set(table["train.scores_read"].values()) == {"scores_read", None}
+    assert "layout_init" in table["train.layout_init"].values()
+
+
+def _run(register, monkeypatch):
+    """(backend compiles or fetches, traces of the fused step, bytes live
+    after the booster is gone, the booster died) of two iterations and a
+    scores read, with or without the registry."""
+    scopes._reset()
+    obs.get().reset(mode="counters")
+    if not register:
+        monkeypatch.setattr(scopes, "register_call",
+                            lambda program, jitted, *args: None)
+    compiles = []
+
+    def listen(name, secs, **kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        bst = _booster(seed=7)
+        for _ in range(2):
+            bst.update()
+        jax.block_until_ready(bst._gbdt.scores)
+        bst.update()
+        jax.block_until_ready(bst._gbdt._phys)
+        traces = obs.get().report()["compiles"]["train.fused_step"]
+        died = weakref.ref(bst._gbdt)
+        learner = weakref.ref(bst._gbdt.learner)
+        del bst
+        gc.collect()
+        live = sum(a.nbytes for a in jax.live_arrays())
+        return (len(compiles), traces, live,
+                died() is None and learner() is None)
+    finally:
+        from jax._src import monitoring
+        monitoring.unregister_event_duration_listener(listen)
+        obs.get().reset(mode="off")
+        monkeypatch.undo()
+
+
+def test_registry_frees_the_booster_and_compiles_nothing(monkeypatch):
+    base = sum(a.nbytes for a in jax.live_arrays())
+    without = _run(False, monkeypatch)
+    with_registry = _run(True, monkeypatch)
+    assert set(scopes.scope_table()) == set(scopes.PROGRAMS)
+    # the same count of backend compilations (or cache fetches), one trace of
+    # the step per booster, and nothing of the booster left alive
+    assert with_registry[0] == without[0]
+    assert with_registry[1] == without[1] == 1
+    assert with_registry[3] and without[3]
+    assert with_registry[2] == without[2] <= base + 4096
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's reader
+# ---------------------------------------------------------------------------
+def _reader():
+    path = os.path.join(ROOT, "benchmark", "readers", "device_phase.py")
+    spec = importlib.util.spec_from_file_location("reader_device_phase",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class _FakeExecutable:
+    """What the registry keeps, as far as it looks: hlo_modules() whose
+    members print their text."""
+
+    def __init__(self, text):
+        self._text = text
+
+    def hlo_modules(self):
+        return [types.SimpleNamespace(to_string=lambda: self._text)]
+
+
+SCORES_READ = '''HloModule jit_lgbm_scores_read
+
+ENTRY %main.3 (ghi.1: f32[8,128]) -> f32[128] {
+  %ghi.1 = f32[8,128]{1,0} parameter(0)
+  %copy.12 = f32[8,128]{0,1} copy(%ghi.1), metadata={op_name="jit(lgbm_scores_read)/lgbm.scores_read/scatter"}
+  %fusion.8 = f32[128]{0} fusion(%copy.12), kind=kLoop, calls=%fc, metadata={op_name="jit(lgbm_scores_read)/lgbm.scores_read/scatter"}
+  ROOT %fusion = f32[128]{0} fusion(%fusion.8), kind=kLoop, calls=%fc2, metadata={op_name="jit(lgbm_scores_read)/lgbm.scores_read/scatter"}
+}
+'''
+
+PROGRAMS = ["train.fused_step", "train.scores_read"]
+GROUPS = {"partition": ["partition", "split_mega"],
+          "row_pass": ["gradients", "sampling", "quantize", "leaf_renew",
+                       "score_update", "scores_read", "layout_init"],
+          "histogram": ["histogram", "hist_state"],
+          "split_search": ["search", "bookkeeping"]}
+
+
+def _ctx(op_seconds, busy=None):
+    tree = types.SimpleNamespace(internal_count=np.array([1000, 600, 400]))
+    return {"trace": {"op_seconds": op_seconds,
+                      "busy_s": busy or sum(op_seconds.values())},
+            "traced_trees": [tree, tree], "features": 28, "bin_bytes": 1,
+            "chips": 1, "peak": {"hbm_bytes_per_s": 1e9}}
+
+
+def test_reader_on_a_synthetic_window():
+    reader = _reader()
+    scopes._reset()
+    scopes.register("train.fused_step", _FakeExecutable(SNIPPET))
+    scopes.register("train.scores_read", _FakeExecutable(SCORES_READ))
+    op_seconds = {
+        "lgbm_partition.7": 0.50,            # partition
+        "broadcast_select_fusion.15": 0.20,  # bookkeeping, by its body
+        "reduce.3": 0.10,                    # search
+        "dynamic-update-slice.3": 0.04,      # score_update
+        "fusion": 0.06,                      # scores_read: its own name
+        "fusion.8": 0.30,      # histogram in the step, scores_read in the
+                               # read-back: ambiguous
+        "copy.12": 0.03,       # no phase in the step: ambiguous too
+        "two_phase_fusion": 0.02,            # no phase
+        "custom-call.99": 0.01,              # no program has it
+    }
+    ctx = _ctx(op_seconds)
+    got = {k: reader.read(ctx, "s_per_iter", PROGRAMS, phases=v)
+           for k, v in GROUPS.items()}
+    assert got == pytest.approx({"partition": 0.25, "row_pass": 0.05,
+                                 "histogram": 0.0, "split_search": 0.15})
+    share = reader.read(ctx, "unattributed_share", PROGRAMS)
+    total = sum(op_seconds.values())
+    assert share == pytest.approx(100 * 0.36 / total)
+    # the identity the benchmark leans on: the four and the unattributed
+    # seconds are the window's operation seconds per iteration
+    assert sum(got.values()) + share / 100 * total / 2 == pytest.approx(
+        total / 2)
+    # least time: 2 trees x 2000 rows x (28 + 8) B at 1e9 B/s, over 0.5 s
+    assert reader.read(ctx, "roofline", PROGRAMS,
+                       phases=GROUPS["partition"]) == pytest.approx(
+        100 * 2 * 2000 * 36 / 1e9 / 0.5)
+    # the step alone: its instruction names are ambiguous no more
+    assert reader.read(ctx, "s_per_iter", ["train.fused_step"],
+                       phases=GROUPS["histogram"]) == pytest.approx(0.15)
+    with pytest.raises(ValueError):
+        reader.read(ctx, "seconds", PROGRAMS)
+    scopes._reset()
+
+
+def test_reader_without_a_table_reads_finite_values():
+    reader = _reader()
+    scopes._reset()
+    ctx = _ctx({"fusion.1": 0.5, "closed_call.33": 1.5})
+    for phases in GROUPS.values():
+        assert reader.read(ctx, "s_per_iter", PROGRAMS, phases=phases) == 0.0
+    assert reader.read(ctx, "roofline", PROGRAMS,
+                       phases=GROUPS["partition"]) == 0.0
+    assert reader.read(ctx, "unattributed_share", PROGRAMS) == 100.0
+
+
+def test_dump_scope_table_names_the_modules(tmp_path):
+    import json
+    scopes._reset()
+    scopes.register("train.fused_step", _FakeExecutable(SNIPPET))
+    scopes.dump_scope_table(str(tmp_path / "table.json"))
+    doc = json.loads((tmp_path / "table.json").read_text())
+    assert doc["modules"] == {"train.fused_step": "jit_lgbm_fused_step"}
+    assert doc["tables"]["train.fused_step"]["lgbm_partition.7"] == \
+        "partition"
+    scopes._reset()
+
+
+# ---------------------------------------------------------------------------
+# the kernel's name on the chip's compiler (no chip: the described topology)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
+    from jax.experimental.compilation_cache import compilation_cache
+    from lightgbm_tpu.ops.partition_pallas import (make_scalars,
+                                                   partition_leaf_pallas,
+                                                   sc_rows_for)
+    C, G32, Np = 2048, 32, 64 * 2048
+
+    def splits(pb, pg, sp):
+        def body(i, carry):
+            pb, pg, sp = carry
+            with scopes.scope("partition"):
+                pb, pg, sp, _ = partition_leaf_pallas(
+                    pb, pg, sp,
+                    make_scalars(C + i, 20 * C, 3, 0, 0, 255, 0, 0, 100, 0),
+                    row_chunk=C)
+            return pb, pg, sp
+        return jax.lax.fori_loop(0, 3, body, (pb, pg, sp))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(splits).lower(
+            sds((G32, Np), jnp.uint8), sds((8, Np), jnp.float32),
+            sds((sc_rows_for(G32), Np), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    table = scopes.phase_of(text)
+    kernels = [n for n in table if n.startswith("lgbm_partition")]
+    assert kernels and all(table[n] == "partition" for n in kernels)
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and set(calls) == set(kernels)

@@ -121,3 +121,48 @@ def test_trace_report_merge_distinct_pids(tmp_path, capsys):
     rc = tool.main([out_path])
     capsys.readouterr()
     assert rc == 0
+
+
+def test_trace_report_device_joins_a_tpu_trace_with_a_scope_table(
+        tmp_path, capsys):
+    """`device` on the trace recorded on a v5e for the benchmark's tests
+    (three calls of one jitted step, a sleep between them): seconds per
+    phase by the module each operation ran in, and the idle gaps put
+    down to the host span that covers them."""
+    trace = os.path.join(HERE, "benchmark", "tests", "data",
+                         "small_tpu.xplane.pb")
+    step = {"convolution_sine_fusion.2": "histogram", "fusion": "search",
+            "copy.9": None}
+    # a second program that gives `fusion` another phase: the trace's
+    # module line says every operation ran in jit_step
+    table = {"modules": {"train.fused_step": "jit_step",
+                         "train.scores_read": "jit_other"},
+             "tables": {"train.fused_step": step,
+                        "train.scores_read": {"fusion": "scores_read"}}}
+    path = tmp_path / "table.json"
+    path.write_text(json.dumps(table))
+    tool = _load_tool("trace_report")
+    rc = tool.main(["device", trace, "--table", str(path),
+                    "--span-prefix", "$time", "--json"])
+    rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0
+    with open(os.path.join(HERE, "benchmark", "tests", "data",
+                           "small_tpu.json")) as f:
+        want = json.load(f)
+    assert abs(rep["busy_s"] - want["busy_s"]) < 1e-9
+    assert abs(rep["window_s"] - want["window_s"]) < 1e-9
+    phases = {p["phase"]: p for p in rep["phases"]}
+    assert set(phases) == {"histogram", "search", "unattributed"}
+    assert abs(phases["histogram"]["seconds"] + phases["search"]["seconds"]
+               - want["pattern_s"]) < 1e-9
+    assert phases["histogram"]["top"][0][0] == \
+        "histogram/convolution_sine_fusion.2"
+    assert abs(sum(p["seconds"] for p in rep["phases"])
+               - rep["busy_s"]) < 1e-9
+    gaps = rep["idle_gaps"]
+    assert len(gaps) == 2 and all(g["ms"] > 10 for g in gaps)
+    assert {g["during"] for g in gaps} == {"$time sleep"}
+    # without a table every operation reads unattributed; the text form
+    assert tool.main(["device", trace]) == 0
+    text = capsys.readouterr().out
+    assert text.startswith("device 0: window") and "unattributed" in text

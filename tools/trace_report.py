@@ -10,6 +10,7 @@ the artifact is malformed).
     python tools/trace_report.py out/trace.json
     python tools/trace_report.py out/telemetry.jsonl
     python tools/trace_report.py merge -o merged.json r0.jsonl r1.jsonl
+    python tools/trace_report.py device <xplane or dir> --table table.json
     python tools/trace_report.py --smoke      # tier-1 self-check
 
 ``merge`` combines multiple per-rank/per-process exports (either
@@ -17,6 +18,13 @@ format) into ONE Chrome trace with a distinct pid per input file —
 multi-process mesh runs write one telemetry file per rank, and
 Perfetto shows them as separate process tracks only when their pids
 differ (they usually don't: every rank reports its own os.getpid).
+
+``device`` reads a ``jax.profiler`` trace (``*.xplane.pb``) and the scope
+table that ``lightgbm_tpu.obs.scopes.dump_scope_table(path)`` wrote in the
+traced process, and prints, per ``lgbm.*`` phase, the device seconds, the
+share of the window and the top operations as ``phase/instruction``; then
+every idle gap over 1 ms, put down to the innermost host span (``train.*``
+with ``LIGHTGBM_TPU_TELEMETRY=trace``) that covers it.
 
 ``--smoke`` runs the continual drift drills (swap + rollback, with
 ``health=counters`` so drift-attribution marks ride the trace) at
@@ -184,6 +192,138 @@ def merge_main(argv: List[str]) -> int:
 
 
 # ---------------------------------------------------------------------------
+# device: a profiler trace joined with the program's scope table
+# ---------------------------------------------------------------------------
+def module_intervals(xplane: str) -> Dict[int, List[Any]]:
+    """{device: [(HLO module name, start_ns, end_ns)]} from the ``XLA
+    Modules`` line of each device plane (events are named
+    ``<module>(<fingerprint>)``)."""
+    import trace_reduce as tr
+    from jax.profiler import ProfileData
+    out: Dict[int, List[Any]] = {}
+    for plane in ProfileData.from_file(xplane).planes:
+        m = tr.DEVICE_PLANE.match(plane.name)
+        for line in plane.lines if m else ():
+            if line.name == "XLA Modules":
+                out.setdefault(int(m.group(1)), []).extend(
+                    (e.name.split("(", 1)[0], e.start_ns,
+                     e.start_ns + e.duration_ns) for e in line.events)
+    return out
+
+
+GAP_MS = 1.0     # idle gaps shorter than this are not listed
+
+
+def device_report(path: str, table: Dict[str, Any], span_prefix: str,
+                  top: int) -> Dict[str, Any]:
+    """The ``device`` report of the trace's fullest device.  The trace is
+    reduced by the benchmark's own code (benchmark/trace_reduce.py: which
+    events are operations, busy time, gaps) and names get their phase by
+    the benchmark reader's rule where the trace names no module."""
+    import bisect
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench, os.path.join(bench, "readers")]
+    import device_phase
+    import trace_reduce as tr
+    xplane = tr.find_xplane(path) if os.path.isdir(path) else path
+    ops, spans = tr.read_trace(xplane, span_prefix=span_prefix)
+    if not ops:   # a CPU run: the host events that carry an hlo_op
+        ops, spans = tr.read_trace(xplane, host_ops=True,
+                                   span_prefix=span_prefix)
+    if not ops:
+        raise SystemExit(f"{path}: the trace holds no device operation")
+    dev = max(ops, key=lambda d: sum(b - a for _, a, b in ops[d]))
+    red = tr.reduce_events({dev: ops[dev]})
+    tables = table.get("tables", {})
+    program_of = {m: p for p, m in table.get("modules", {}).items()}
+    agreed = device_phase.phase_by_name(tables.values())
+    mods = sorted(module_intervals(xplane).get(dev, []), key=lambda e: e[1])
+    starts = [a for _, a, _ in mods]
+
+    def phase_of(name, a):
+        i = bisect.bisect_right(starts, a) - 1
+        if i >= 0 and a < mods[i][2] and mods[i][0] in program_of:
+            return tables[program_of[mods[i][0]]].get(name)
+        return agreed.get(name)
+
+    per_phase: Dict[str, Dict[str, float]] = {}
+    for name, a, b in ops[dev]:
+        if not tr.CONTAINERS.match(name):
+            per = per_phase.setdefault(phase_of(name, a) or "unattributed",
+                                       {})
+            per[name] = per.get(name, 0.0) + (b - a) / 1e9
+
+    def doing(a, b):
+        mid = (a + b) / 2
+        cover = [(e - s, n) for n, s, e in spans if s <= mid <= e]
+        return min(cover)[1] if cover else "no " + span_prefix + "* span"
+
+    idle = [{"ms": secs * 1e3, "during": doing(a, b)}
+            for secs, a, b in red["gaps"] if secs * 1e3 > GAP_MS]
+    by_span: Dict[str, float] = {}
+    for g in idle:
+        by_span[g["during"]] = by_span.get(g["during"], 0.0) + g["ms"] / 1e3
+    window = red["window_s"]
+    return {
+        "path": path, "device": dev, "window_s": window,
+        "busy_s": red["busy_s"],
+        "idle_share_pct": 100.0 * (1.0 - red["busy_s"] / window),
+        "phases": [
+            {"phase": ph, "seconds": sum(per.values()),
+             "share_pct": 100.0 * sum(per.values()) / window,
+             "top": [[f"{ph}/{n}", s] for n, s in sorted(
+                 per.items(), key=lambda kv: -kv[1])[:top]]}
+            for ph, per in sorted(per_phase.items(),
+                                  key=lambda kv: -sum(kv[1].values()))],
+        "idle_gaps": idle[:20],
+        "idle_s_by_span": dict(sorted(by_span.items(),
+                                      key=lambda kv: -kv[1])),
+    }
+
+
+def device_main(argv: List[str]) -> int:
+    ap = argparse.ArgumentParser(
+        prog="trace_report.py device",
+        description="device seconds per lgbm.* phase and idle gaps per "
+                    "host span, from a profiler trace and the scope table "
+                    "obs.scopes.dump_scope_table() wrote")
+    ap.add_argument("trace", help="an .xplane.pb file or a directory "
+                                  "that holds one")
+    ap.add_argument("--table", help="the JSON file dump_scope_table wrote; "
+                                    "without it every operation reads "
+                                    "unattributed")
+    ap.add_argument("--span-prefix", default="train.",
+                    help="host spans that name the idle gaps")
+    ap.add_argument("--top", type=int, default=5,
+                    help="operations listed per phase")
+    ap.add_argument("--json", action="store_true",
+                    help="print the report as one JSON object")
+    args = ap.parse_args(argv)
+    table: Dict[str, Any] = {}
+    if args.table:
+        with open(args.table, encoding="utf-8") as fh:
+            table = json.load(fh)
+    rep = device_report(args.trace, table, args.span_prefix, args.top)
+    if args.json:
+        print(json.dumps(rep))
+        return 0
+    print(f"device {rep['device']}: window {rep['window_s']:.4f} s, busy "
+          f"{rep['busy_s']:.4f} s, idle {rep['idle_share_pct']:.2f}%")
+    for ph in rep["phases"]:
+        print(f"{ph['phase']:<14}{ph['seconds']:>9.4f} s "
+              f"{ph['share_pct']:>6.2f}%  " + "  ".join(
+                  f"{n} {secs:.4f}" for n, secs in ph["top"]))
+    print(f"idle gaps over {GAP_MS:g} ms: {len(rep['idle_gaps'])} "
+          "listed, seconds by span: " + (", ".join(
+              f"{n} {secs:.4f}" for n, secs in
+              rep["idle_s_by_span"].items()) or "none"))
+    for g in rep["idle_gaps"]:
+        print(f"  {g['ms']:>9.3f} ms during {g['during']}")
+    return 0
+
+
+# ---------------------------------------------------------------------------
 # --smoke: drive a drill at telemetry=trace and validate its trace
 # ---------------------------------------------------------------------------
 _REQUIRED_SPANS = ("continual.tick", "continual.retrain",
@@ -280,6 +420,8 @@ def main(argv=None) -> int:
         argv = sys.argv[1:]
     if argv and argv[0] == "merge":
         return merge_main(argv[1:])
+    if argv and argv[0] == "device":
+        return device_main(argv[1:])
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("trace", nargs="?", help="trace.json or telemetry.jsonl")
     ap.add_argument("--smoke", action="store_true",
