@@ -243,6 +243,14 @@ def _renew_leaves_percentile(rec, resid, pweight, sel, alpha: float,
     return jnp.where(nb > 0, v, old)
 
 
+def _small_record(rec, scalars=("s", "made", "feat_used")):
+    """The per-tree record without its row-length buffers: the node and
+    leaf arrays and the named scalars (``made``: splits the frontier body
+    executed, committed or pruned; absent from the K=1 body's record)."""
+    return {k: v for k, v in rec.items()
+            if k.startswith(("node_", "leaf_")) or k in scalars}
+
+
 def _phys_leaf_delta(rec, Npad: int):
     """Per-row score delta from the physical leaf ranges: leaves are
     disjoint contiguous row windows, so scatter +/- leaf values at the
@@ -793,9 +801,7 @@ class GBDT:
                 delta = jnp.zeros((N,), jnp.float32).at[rec["indices"]].set(
                     delta_phys, mode="drop")
                 new_scores = scores + delta * shrink
-            small = {k: v for k, v in rec.items()
-                     if k.startswith(("node_", "leaf_")) or k in
-                     ("s", "feat_used")}
+            small = _small_record(rec)
             small["leaf_delta"] = rec["leaf_value"] * shrink
             return new_scores, small
 
@@ -1046,9 +1052,7 @@ class GBDT:
             with scopes.scope("score_update"):
                 ghi_out = rec["part_ghi"].at[3].add(
                     shrink * _phys_leaf_delta(rec, Npad))
-            small = {k: v for k, v in rec.items()
-                     if k.startswith(("node_", "leaf_")) or k in
-                     ("s", "feat_used")}
+            small = _small_record(rec)
             small["leaf_delta"] = rec["leaf_value"] * shrink
             return rec["part_bins"], ghi_out, small
 
@@ -1192,9 +1196,7 @@ class GBDT:
                     ghi = ghi.at[3 + k].add(
                         shrink * _phys_leaf_delta(rec, Npad))
                 feat_used = rec["feat_used"]
-                small = {kk: v for kk, v in rec.items()
-                         if kk.startswith(("node_", "leaf_")) or kk in
-                         ("s", "feat_used")}
+                small = _small_record(rec)
                 small["leaf_delta"] = rec["leaf_value"] * shrink
                 smalls.append(small)
             return part_bins, ghi, smalls
@@ -1360,9 +1362,7 @@ class GBDT:
             with scopes.scope("score_update"):
                 ghi_out = rec["part_ghi"].at[3].add(
                     shrink * _phys_leaf_delta(rec, Npad))
-            small = {k: v for k, v in rec.items()
-                     if k.startswith(("node_", "leaf_")) or k in
-                     ("s", "feat_used")}
+            small = _small_record(rec)
             # per-shard leaf offsets must not leak out replicated
             small.pop("leaf_start", None)
             small.pop("leaf_cnt", None)
@@ -1452,8 +1452,7 @@ class GBDT:
         if self.learner.has_cegb:
             self._cegb_feat_used = recs[-1]["feat_used"]
         for r in recs:
-            small = {k: v for k, v in r.items()
-                     if k.startswith(("node_", "leaf_")) or k == "s"}
+            small = _small_record(r, scalars=("s", "made"))
             for v in small.values():
                 try:
                     v.copy_to_host_async()
@@ -1526,6 +1525,7 @@ class GBDT:
                 tree.leaf_value = np.asarray([self.init_scores[k_cls]])
         self._health_record_tree(host_record, num_nodes)
         self._telemetry_chunk_waste(host_record, num_nodes)
+        self._telemetry_pruned_splits(host_record, num_nodes)
         self.models.append(tree)
         self.device_trees.append({
             "nodes": nodes, "leaf_value": delta_leaf,
@@ -1578,6 +1578,21 @@ class GBDT:
         self.flight.record_tree(idx // K, idx % K, host_record,
                                 num_nodes,
                                 effective_rows=self._health_effective_rows())
+
+    # -- frontier-body speculation counters (obs/telemetry.py) ----------
+    @staticmethod
+    def _telemetry_pruned_splits(record, num_nodes: int) -> None:
+        """``train.frontier.pruned_splits`` / ``train.frontier.undo_trees``:
+        how many speculative splits the frontier body (tpu_frontier_k > 1)
+        executed and pruned again, and in how many trees — the trees whose
+        tree-end undo pass, the rowid snapshot's only reader, ran.  The
+        K=1 body's record has no ``made``; off costs nothing."""
+        if obs.get().mode == "off" or "made" not in record:
+            return
+        pruned = int(record["made"]) - num_nodes
+        if pruned > 0:
+            obs.counter("train.frontier.pruned_splits", pruned)
+            obs.counter("train.frontier.undo_trees")
 
     # -- chunk-policy padding-waste gauges (obs/telemetry.py) -----------
     def _telemetry_chunk_waste(self, host_record, num_nodes: int) -> None:
@@ -2168,6 +2183,7 @@ class GBDT:
                         tree.leaf_const = np.asarray([self.init_scores[k]])
             self._health_record_tree(host_record, num_nodes)
             self._telemetry_chunk_waste(host_record, num_nodes)
+            self._telemetry_pruned_splits(record, num_nodes)
             self.models.append(tree)
             self.device_trees.append({
                 "nodes": nodes, "leaf_value": delta_leaf,

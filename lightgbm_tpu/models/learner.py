@@ -87,6 +87,9 @@ NND = 17
 # host round-trip (see _renumber_frontier).
 (ND_START, ND_CNTP, ND_SUM_G, ND_DEPTH) = range(NND, NND + 4)
 NND_FR = NND + 4
+# rows per window of the frontier body's undo snapshot copy
+# (_snapshot_rowids)
+_SNAP_WINDOW = 1 << 18
 
 
 def _i2f(x):
@@ -947,6 +950,32 @@ class SerialTreeLearner:
             jnp.int32, (1, cat_set.shape[0]), 1)
         cat_left = jnp.any(oh & cat_set[None, :], axis=1)
         return jnp.where(is_cat, cat_left, num_left)
+
+    @scopes.phase("bookkeeping")
+    def _snapshot_rowids(self, snap, part_ghi, start, cnt):
+        """Copy the rowid row of the leaf range [start, start+cnt) into
+        the frontier body's undo snapshot ``snap`` (f32[N_pad], rowid
+        bits), leaving every other element of it as it was.
+
+        Fixed-width windows with a masked tail, like the partition's own
+        window writes, so the cost follows the rows about to be
+        partitioned and not N.  The window is wider than ``row_chunk``
+        (one trip moves one row of the payload, not G+8) and clamped to
+        the buffer's end; the mask is on absolute positions, so a
+        clamped window re-copies rows of the range it already holds."""
+        Np = snap.shape[0]
+        W = min(_SNAP_WINDOW, Np)
+
+        def window(ci, snap):
+            off = jnp.minimum(start + ci * W, Np - W)
+            pos = off + jax.lax.iota(jnp.int32, W)
+            mask = (pos >= start) & (pos < start + cnt)
+            row = jax.lax.dynamic_slice(part_ghi, (2, off), (1, W))[0]
+            win = jax.lax.dynamic_slice(snap, (off,), (W,))
+            return jax.lax.dynamic_update_slice(
+                snap, jnp.where(mask, row, win), (off,))
+
+        return jax.lax.fori_loop(0, (cnt + W - 1) // W, window, snap)
 
     @scopes.phase("partition")
     def _partition_leaf(self, st, start, cnt, col, decision_scalars):
@@ -2662,14 +2691,16 @@ class SerialTreeLearner:
         Pruned speculative partitions are UNDONE at tree end: f32
         histogram accumulation is not order-invariant, so a permuted
         row order inside a pruned leaf's range would ULP-perturb the
-        NEXT tree's histograms.  The slot-reserve rule bounds live
-        uncommitted splits by K-1, so a K-slot liveness ring of
-        pre-step rowid-row snapshots suffices: each step stamps its
-        snapshot into a ring slot whose previous occupants have all
-        committed, and the tree-end undo pass inverse-gathers the (at
-        most K-1, mutually disjoint) pruned ranges back into their
-        snapshot order — restoring the exact physical layout the K=1
-        oracle would hand the next iteration.
+        NEXT tree's histograms.  ONE N-long snapshot row suffices:
+        before a leaf is partitioned, the rowid row of ITS range is
+        copied into the snapshot (``_snapshot_rowids``).  A leaf becomes
+        selectable only when the replay commits its parent split, so
+        while a split is uncommitted none of its rows is partitioned
+        again and no later copy can overwrite a range that a live
+        uncommitted split still needs.  The tree-end undo pass
+        inverse-gathers the (at most K-1, mutually disjoint) pruned
+        ranges back into their snapshot order — restoring the exact
+        physical layout the K=1 oracle would hand the next iteration.
 
         The amortization: ONE top-k election, ONE (NLF, K) leafmat
         gather, ONE K-row parent-hist gather (replacing the K dynamic
@@ -2745,12 +2776,9 @@ class SerialTreeLearner:
             "pop_split": jnp.full((L,), -1, jnp.int32),
             "ora_of": jnp.full((MS + 1,), -1, jnp.int32),
             "slot_item": jnp.full((L + 1,), -1, jnp.int32).at[0].set(0),
-            # pre-step rowid snapshots for the tree-end undo of pruned
-            # speculative partitions (K slots suffice: live uncommitted
-            # splits never exceed K-1, each pinning one ring slot)
-            "ring": jnp.zeros((K, part_bins.shape[1]), jnp.float32),
-            "ring_live": jnp.zeros((K,), jnp.int32),
-            "rslot": jnp.zeros((MS + 1,), jnp.int32),
+            # pre-partition rowid order of every range partitioned so
+            # far, for the tree-end undo of pruned speculative splits
+            "snap": jnp.zeros((part_bins.shape[1],), jnp.float32),
         }
         if not use_mega:
             with scopes.scope("hist_state"):
@@ -2767,7 +2795,7 @@ class SerialTreeLearner:
         else:
             state["sc32"] = jnp.zeros((G + self._ghi_rows,
                                        part_bins.shape[1]), jnp.int32)
-        buf_keys = ("part_bins", "part_ghi",
+        buf_keys = ("part_bins", "part_ghi", "snap",
                     "sc_packed" if self._use_pallas_part else "sc32")
 
         def cond(st):
@@ -2799,19 +2827,6 @@ class SerialTreeLearner:
             j_idx = jnp.where(active, st["made"] + iotK, jnp.int32(MS))
             wrb_slots = jnp.where(active, st["made"] + 1 + iotK,
                                   jnp.int32(TRASH))
-            # stamp the pre-step rowid order into a free ring slot (one
-            # always exists: live slots <= uncommitted splits <= K-1).
-            # The row read pins the pre-mutation payload, which costs
-            # two coherence copies of part_ghi per step (~2% of the
-            # 262k-row iteration; barrier-sequencing did not remove
-            # them — measured, PERF.md round 12)
-            free_r = jnp.argmax(st["ring_live"] == 0).astype(jnp.int32)
-            if getattr(self, "_frontier_no_undo", False):
-                ring2 = st["ring"]        # measurement-only ablation
-            else:
-                ring2 = st["ring"].at[free_r].set(st["part_ghi"][2])
-            ring_live2 = st["ring_live"].at[free_r].set(k_step)
-            rslot2 = st["rslot"].at[j_idx].set(free_r)
 
             # ---- ONE gather of the K chosen leaves' packed scalars ----
             pbits = jnp.take(lm, sel_slots, axis=1)           # (NLF, K)
@@ -2861,6 +2876,21 @@ class SerialTreeLearner:
                        thrs[k], dls[k], is_cats[k], cat_sets[k])
                 start = starts[k]
                 cnt = cnts[k]
+                # lane 0 is the oracle's guaranteed-next split: the
+                # replay commits it, so it is never undone and needs no
+                # snapshot.  The snapshot reads the rows the partition
+                # is about to overwrite in place; nothing else orders
+                # the two, and unordered XLA:TPU keeps the pre-partition
+                # payload alive in a copy of part_ghi per split.  The
+                # barrier hands the partition its range only once the
+                # snapshot is taken.
+                with scopes.scope("bookkeeping"):
+                    snap2, start = jax.lax.optimization_barrier(
+                        (self._snapshot_rowids(
+                            bufs["snap"], bufs["part_ghi"], start,
+                            jnp.where(k == 0, 0, cnt)),
+                         start))
+                bufs = {**bufs, "snap": snap2}
                 if use_mega:
                     moved, left_cnt, mh = self._split_leaf_mega(
                         bufs, start, cnt, fm[1], dsc, hist_scale)
@@ -3050,7 +3080,6 @@ class SerialTreeLearner:
                 "avail": st["avail"], "it_oslot": st["it_oslot"],
                 "slot_item": st["slot_item"],
                 "pop_split": st["pop_split"], "ora_of": st["ora_of"],
-                "ring_live": ring_live2,
                 "m": st["m"], "u_item": st["u_item"], "done": st["done"],
                 "stop": jnp.bool_(False),
             }
@@ -3089,14 +3118,10 @@ class SerialTreeLearner:
                     jnp.where(can_pop, i, jnp.int32(L - 1))].set(j2c)
                 ora2 = c["ora_of"].at[
                     jnp.where(can_pop, j2c, jnp.int32(MS))].set(i)
-                # a committed split releases its undo-snapshot pin
-                rl2 = c["ring_live"].at[
-                    jnp.where(can_pop, rslot2[j2c], jnp.int32(K))].add(
-                    -1, mode="drop")
                 return {
                     "avail": avail2, "it_oslot": oslot2,
                     "slot_item": slot_item2, "pop_split": pop_split2,
-                    "ora_of": ora2, "ring_live": rl2,
+                    "ora_of": ora2,
                     "m": c["m"] + can_pop.astype(jnp.int32),
                     "u_item": jnp.where(stall, it, c["u_item"]),
                     "done": c["done"] | budget_done | dead,
@@ -3116,8 +3141,6 @@ class SerialTreeLearner:
                 "u_item": sim["u_item"],
                 "pop_split": sim["pop_split"], "ora_of": sim["ora_of"],
                 "slot_item": sim["slot_item"],
-                "ring": ring2, "ring_live": sim["ring_live"],
-                "rslot": rslot2,
                 **{kk: bufs[kk] for kk in buf_keys},
                 **upd_hist, **upd_cat,
             }
@@ -3149,8 +3172,7 @@ class SerialTreeLearner:
                 stt = ncol[ND_START]
                 cntt = ncol[ND_CNTP]
                 mask = (jt >= 0) & (iota_n >= stt) & (iota_n < stt + cntt)
-                src_bits = jnp.where(
-                    mask, final["ring"][final["rslot"][jc]], src_bits)
+                src_bits = jnp.where(mask, final["snap"], src_bits)
                 anymask = anymask | mask
             cur = jnp.clip(_f2i(pg0[2]), 0, self.N)
             pos_of = jnp.zeros((self.N + 1,),
@@ -3255,12 +3277,13 @@ class SerialTreeLearner:
 
         drop = ("leafmat", "nodemat", "hist", "it_gain", "it_slot",
                 "it_split", "it_oslot", "avail", "u_item", "pop_split",
-                "ora_of", "slot_item", "made", "m", "best_cat_set",
-                "node_cat_set", "ring", "ring_live", "rslot")
+                "ora_of", "slot_item", "m", "best_cat_set",
+                "node_cat_set", "snap")
         out = {k: v for k, v in st.items() if k not in drop}
         if getattr(self, "_frontier_debug", False):
             # test-only introspection of the replay (tests/test_frontier)
             out["frontier_debug"] = {k: st[k] for k in drop if k in st}
+        # "made" stays in the record: made - s = splits pruned and undone
         out["s"] = m
         out["leafmat"] = _i2f(lm_f)
         out["nodemat"] = _i2f(nm_f)

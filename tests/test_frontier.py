@@ -5,6 +5,11 @@ the num_leaves budget boundary, where the oracle-order replay prunes
 speculative splits and the tree-end undo pass restores the pruned
 ranges' physical row order (next-iteration f32 accumulation order).
 
+The undo reads ONE N-long snapshot row that each step fills over the
+ranges it is about to partition (``_snapshot_rowids``): the prune tests
+run under the XLA partition and under the interpreted Pallas partition,
+over several trees in a row, and with a window narrower than the leaves.
+
 Order-dependent machinery (forced splits, monotone constraints, CEGB,
 extra_trees, bynode sampling, interaction constraints, parallel
 learners) must fall back to K=1 with a warning.
@@ -142,43 +147,193 @@ def test_frontier_megakernel_interpret_interplay():
 # speculation/prune internals: the replay's invariants where pruning
 # actually engages
 # ---------------------------------------------------------------------------
-def test_frontier_prune_engages_and_stays_bitidentical():
+# the partitions the frontier body drives: the XLA window partition, and
+# the Pallas kernel through the interpreter under the plan the 42M-row
+# benchmark cells run (partition=pallas search=xla mega=off: path_smooth
+# keeps the general XLA search, so neither the pair-search kernel nor the
+# mega-kernel takes the partition over)
+PARTITIONS = {
+    "xla": {},
+    "pallas": {"tpu_kernel_interpret": True, "tpu_row_chunk": 256,
+               "path_smooth": 1.0},
+}
+RECORD_FIELDS = ("s", "leaf_start", "leaf_cnt", "leaf_value",
+                 "leaf_sum_g", "leaf_sum_h", "best_gain",
+                 "node_feature", "node_threshold", "node_gain",
+                 "node_left", "node_right", "indices")
+
+
+def _masked_tree(X, y, seed, k, extra=None):
+    """One tree on a seeded 55% gradient mask (noisy gains at a binding
+    budget of 12 leaves), with the replay's state exported."""
+    import jax.numpy as jnp
+    mask = np.random.RandomState(seed).rand(len(y)) < 0.55
+    grad = np.where(mask, 0.5 - y, 0.0).astype(np.float32)
+    hess = np.where(mask, 0.25, 0.0).astype(np.float32)
+    cfg = Config({**BASE, "num_leaves": 12, "tpu_frontier_k": k,
+                  **(extra or {})})
+    lr = SerialTreeLearner(BinnedDataset.from_matrix(X, cfg, label=y), cfg)
+    lr._frontier_debug = True
+    return lr, lr.build_tree(jnp.asarray(grad), jnp.asarray(hess),
+                             bag_cnt=int(mask.sum()))
+
+
+def _pruned_splits(rec):
+    """(debug arrays, executed splits the replay never committed)."""
+    dbg = {k: np.asarray(v) for k, v in rec["frontier_debug"].items()}
+    return dbg, [j for j in range(int(rec["made"]))
+                 if dbg["ora_of"][j] < 0]
+
+
+@pytest.mark.parametrize("partition", sorted(PARTITIONS))
+def test_frontier_prune_engages_and_stays_bitidentical(partition):
     """Noisy (bagged) gains at a binding budget make children outrank
     speculative picks, so some speculative splits must be PRUNED
     (made > committed); the replay bounds the overshoot by K-1 and the
     renumber+undo passes keep the record bit-identical to the oracle."""
-    import jax.numpy as jnp
     X, y = _data(seed=7, n=900)
-    g0 = (0.5 - y).astype(np.float32)
     K = 4
     pruned_seen = 0
-    for seed in range(6):
-        r2 = np.random.RandomState(seed)
-        mask = r2.rand(len(y)) < 0.55
-        grad = np.where(mask, g0, 0.0).astype(np.float32)
-        hess = np.where(mask, 0.25, 0.0).astype(np.float32)
-        recs = {}
-        for k in (1, K):
-            cfg = Config({**BASE, "num_leaves": 12, "tpu_frontier_k": k})
-            ds = BinnedDataset.from_matrix(X, cfg, label=y)
-            lr = SerialTreeLearner(ds, cfg)
-            lr._frontier_debug = True
-            recs[k] = lr.build_tree(jnp.asarray(grad), jnp.asarray(hess),
-                                    bag_cnt=int(mask.sum()))
-        a, b = recs[1], recs[K]
-        for field in ("s", "leaf_start", "leaf_cnt", "leaf_value",
-                      "leaf_sum_g", "leaf_sum_h", "best_gain",
-                      "node_feature", "node_threshold", "node_gain",
-                      "node_left", "node_right", "indices"):
+    for seed in range(6 if partition == "xla" else 3):
+        lr, a = _masked_tree(X, y, seed, 1, PARTITIONS[partition])
+        lr, b = _masked_tree(X, y, seed, K, PARTITIONS[partition])
+        assert lr._use_pallas_part == (partition == "pallas")
+        assert lr.frontier_k == K
+        for field in RECORD_FIELDS:
             assert np.array_equal(np.asarray(a[field]),
                                   np.asarray(b[field])), (seed, field)
-        dbg = b["frontier_debug"]
-        made = int(np.asarray(dbg["made"]))
+        made = int(np.asarray(b["made"]))
         m = int(np.asarray(b["s"]))
         assert made - m <= K - 1          # overshoot bound
         pruned_seen += int(made > m)
     assert pruned_seen > 0, \
         "no seed engaged pruning: the boundary lane tests nothing"
+
+
+def test_frontier_snapshot_off_every_boundary(monkeypatch):
+    """The undo snapshot with a window (96 rows) narrower than the leaves:
+    a pruned range that starts and ends off a 128 boundary, off the
+    window and off the row chunk, copied in several windows; and a pruned
+    split whose sibling subtree was partitioned again AFTER its snapshot
+    (ranges of live uncommitted splits are never selected again, so the
+    later copies cannot reach the rows the undo restores)."""
+    from lightgbm_tpu.models import learner as learner_mod
+    from lightgbm_tpu.models.learner import ND_CNTP, ND_START
+    monkeypatch.setattr(learner_mod, "_SNAP_WINDOW", 96)
+    X, y = _data(seed=7, n=900)
+    off_boundary = later_sibling = 0
+    for seed in (0, 1, 5):
+        _, a = _masked_tree(X, y, seed, 1)
+        lr, b = _masked_tree(X, y, seed, 4)
+        for field in RECORD_FIELDS:
+            assert np.array_equal(np.asarray(a[field]),
+                                  np.asarray(b[field])), (seed, field)
+        dbg, pruned = _pruned_splits(b)
+        assert pruned
+        nm, made = dbg["nodemat"], int(b["made"])
+        for j in pruned:
+            st, cn = int(nm[ND_START, j]), int(nm[ND_CNTP, j])
+            off_boundary += (st % 128 != 0 and (st + cn) % 128 != 0
+                             and cn > 2 * 96 and cn % 96 != 0
+                             and cn % lr.row_chunk != 0)
+            item = int(np.nonzero(dbg["it_split"] == j)[0][0])
+            par = (item - 1) // 2
+            ps, pc = int(nm[ND_START, par]), int(nm[ND_CNTP, par])
+            later_sibling += any(
+                ps <= int(nm[ND_START, j2]) < ps + pc
+                and not st <= int(nm[ND_START, j2]) < st + cn
+                for j2 in range(j + 1, made))
+    assert off_boundary > 0 and later_sibling > 0
+
+
+@pytest.mark.parametrize("window", [64, 96, 1 << 18])
+def test_snapshot_rowids_copies_exactly_the_range(monkeypatch, window):
+    """``_snapshot_rowids`` against numpy: interior ranges off every
+    boundary, one row, no row, a range that ends at the buffer's end (the
+    last window is clamped and re-copies rows it already holds), and the
+    whole buffer; everything outside the range stays as it was."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.models import learner as learner_mod
+    monkeypatch.setattr(learner_mod, "_SNAP_WINDOW", window)
+    X, y = _data(n=300)
+    lr = _learner_for({"tpu_frontier_k": 4}, X, y)
+    Np = lr.N_pad
+    rng = np.random.RandomState(0)
+    ghi = rng.randn(8, Np).astype(np.float32)
+    snap = rng.randn(Np).astype(np.float32)
+    for start, cnt in ((131, 333), (7, 1), (500, 0), (Np - 211, 211),
+                       (Np - 70, 65), (0, Np)):
+        got = np.asarray(lr._snapshot_rowids(
+            jnp.asarray(snap), jnp.asarray(ghi), jnp.int32(start),
+            jnp.int32(cnt)))
+        want = snap.copy()
+        want[start:start + cnt] = ghi[2, start:start + cnt]
+        assert np.array_equal(got, want), (start, cnt)
+
+
+@pytest.mark.parametrize("partition", sorted(PARTITIONS))
+def test_frontier_undo_feeds_the_next_tree(partition):
+    """Several trees in a row through the booster, pruning in every one
+    (bagged gains, 12 leaves): the undone layout is what the next tree's
+    histograms accumulate over, so the trees AND the physical row layout
+    left after the last one equal the K=1 learner's bit for bit."""
+    from lightgbm_tpu import obs
+    X, y = _data(seed=7, n=900)
+    kw = {"num_leaves": 12, "bagging_fraction": 0.55, "bagging_freq": 1,
+          **PARTITIONS[partition]}
+    obs.get().reset(mode="counters")
+    try:
+        b1 = _train(X, y, nbr=4, **kw)
+        trees1 = _trees(b1)
+        assert "train.frontier.pruned_splits" not in \
+            b1.telemetry_report(include_memory=False)["counters"]
+        bk = _train(X, y, nbr=4, tpu_frontier_k=4, **kw)
+        treesk = _trees(bk)
+        counters = bk.telemetry_report(include_memory=False)["counters"]
+    finally:
+        obs.get().reset(mode="off")
+    assert bk._gbdt.learner._use_pallas_part == (partition == "pallas")
+    assert counters["train.frontier.undo_trees"] >= 3   # of 4 trees
+    assert trees1 == treesk
+    for a, b in zip(b1._gbdt._phys, bk._gbdt._phys):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_frontier_prune_counters():
+    """``train.frontier.pruned_splits`` / ``undo_trees`` say how often the
+    snapshot's only reader runs; with telemetry off nothing is counted,
+    and the record's extra scalar changes no model text."""
+    from lightgbm_tpu import obs
+    X, y = _data(seed=7, n=900)
+    kw = {"num_leaves": 12, "bagging_fraction": 0.55, "bagging_freq": 1,
+          "tpu_frontier_k": 4}
+    texts = {}
+    for mode in ("off", "counters"):
+        obs.get().reset(mode=mode)
+        try:
+            bst = _train(X, y, nbr=5, **kw)
+            texts[mode] = bst.model_to_string()    # drains the records
+            counters = bst.telemetry_report(
+                include_memory=False)["counters"]
+        finally:
+            obs.get().reset(mode="off")
+        if mode == "off":
+            assert not any(k.startswith("train.frontier") for k in counters)
+        else:
+            trees = counters["train.frontier.undo_trees"]
+            assert 0 < trees <= 5
+            assert trees <= counters["train.frontier.pruned_splits"] \
+                <= 3 * trees                       # at most K-1 a tree
+    assert texts["off"] == texts["counters"]
+    # the eager path counts the same way (one scalar read per tree, only
+    # when telemetry is on)
+    obs.get().reset(mode="counters")
+    try:
+        bst = _train(X, y, nbr=2, tpu_fused_iteration=False, **kw)
+        counters = bst.telemetry_report(include_memory=False)["counters"]
+    finally:
+        obs.get().reset(mode="off")
+    assert 0 < counters["train.frontier.undo_trees"] <= 2
 
 
 # ---------------------------------------------------------------------------

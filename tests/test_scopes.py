@@ -17,6 +17,7 @@ import contextlib
 import gc
 import importlib.util
 import os
+import re
 import types
 import weakref
 
@@ -232,6 +233,127 @@ def test_registry_frees_the_booster_and_compiles_nothing(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the frontier loop's shape: what it carries, and what a step may move
+# ---------------------------------------------------------------------------
+_SHAPE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+_REFERS = re.compile(r"(?:body|condition|calls|to_apply|true_computation|"
+                     r"false_computation)=%([^\s,}]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _elements(dims):
+    return int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module's text."""
+    out, current = {}, None
+    for line in text.splitlines():
+        head = scopes._COMPUTATION.match(line)
+        if head:
+            current = out.setdefault(head.group(1), [])
+        elif current is not None and scopes._INSTRUCTION.match(line):
+            current.append(line)
+    return out
+
+
+def _reachable(computations, roots):
+    """The computations a loop runs: its body and condition and whatever
+    they call."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for line in computations[name]:
+            todo += _REFERS.findall(line)
+            for group in _BRANCHES.findall(line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+def _window_write(line, computations):
+    """Is the instruction an in-place window write: a dynamic-update-slice,
+    or a fusion whose body ends in one?"""
+    kind = line.split(" = ", 1)[1]
+    if " dynamic-update-slice(" in kind:
+        return True
+    return any(" dynamic-update-slice(" in ln
+               for c in scopes._CALLS.findall(line)
+               for ln in computations.get(c, ()) if "ROOT " in ln)
+
+
+def _frontier_loop(text):
+    """(the line of the frontier body's while instruction, {computation:
+    lines}, the operations that loop runs) of a compiled fused step.  An
+    operation is (line, phase, element type, dims) of an instruction that
+    the scope table lists and that yields one array of its own: no tuple,
+    no get-tuple-element, no nested loop."""
+    computations = _computations(text)
+    loops = [ln for lines in computations.values() for ln in lines
+             if " while(" in ln
+             and 'op_name="jit(lgbm_fused_step)/lgbm.bookkeeping/while"'
+             in ln]
+    assert len(loops) == 1
+    table = scopes.phase_of(text)
+    ops = []
+    for name in _reachable(computations,
+                           _REFERS.findall(loops[0].split(" while(")[1])):
+        for line in computations[name]:
+            inst = scopes._INSTRUCTION.match(line).group(1)
+            kind = line.split(" = ", 1)[1]
+            if inst in table and not kind.lstrip().startswith("(") \
+                    and " get-tuple-element(" not in kind \
+                    and " while(" not in kind:
+                ops.append((line, table[inst])
+                           + _SHAPE.search(kind).groups())
+    return loops[0], computations, ops
+
+
+def test_frontier_loop_carries_one_snapshot_row(monkeypatch):
+    """What keeps the undo ring from coming back unseen: the frontier
+    step's while loop carries ONE N-wide f32 row (the undo snapshot) and no
+    (K, N) buffer, and no ``lgbm.bookkeeping`` operation inside the loop
+    writes N or more elements, except the snapshot's own window writes (in
+    place: a dynamic-update-slice of the row, or a fusion rooted in one).
+    Read from the step as the CPU compiles it at toy size, with the
+    snapshot's window narrowed so that a window is not the whole row."""
+    from lightgbm_tpu.models import learner as learner_mod
+    monkeypatch.setattr(learner_mod, "_SNAP_WINDOW", 128)
+    K = 4
+    bst = _booster({"tpu_frontier_k": K, "max_bin": 15})
+    g = bst._gbdt
+    Np = g.learner.N_pad
+    assert g.learner.frontier_k == K and Np > 4 * 128
+    pb, ghi = g._init_phys(g.learner._part0, g.scores)
+    text = g._fused_phys.lower(
+        pb, ghi, g._feature_mask(0), 1,
+        jnp.zeros((g.learner.F,), bool)).compile().as_text()
+    loop, computations, ops = _frontier_loop(text)
+    carried = _SHAPE.findall(loop.split(" while(")[0])
+    wide = [(t, d) for t, d in carried if d.split(",")[-1] == str(Np)]
+    # bins, payload, partition scratch and the snapshot row
+    assert sorted(d.count(",") for _, d in wide) == [0, 1, 1, 1], wide
+    assert ("f32", str(Np)) in wide
+    assert ("f32", f"{K},{Np}") not in wide
+    assert not [d for _, d in wide
+                if d.count(",") == 1 and d.split(",")[0] == str(K)]
+
+    offenders, window_writes = [], 0
+    for line, phase, typ, dims in ops:
+        if phase != "bookkeeping" or _elements(dims) < Np:
+            continue
+        if (typ, dims) == ("f32", str(Np)) \
+                and _window_write(line, computations):
+            window_writes += 1
+        else:
+            offenders.append(line.strip()[:160])
+    assert window_writes >= 1
+    assert not offenders, offenders
+
+
+# ---------------------------------------------------------------------------
 # the benchmark's reader
 # ---------------------------------------------------------------------------
 def _reader():
@@ -399,3 +521,54 @@ def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
     calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls and set(calls) == set(kernels)
+
+
+def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
+        one_chip, monkeypatch):
+    """What the CPU cannot show: XLA:TPU's copy insertion.  The frontier
+    step (Pallas partition in place, general XLA search: the plan of the
+    benchmark's 42M-row cells, reached at toy size through path_smooth)
+    compiled for a described v5e holds no copy of the (8, N) payload or of
+    the bins anywhere in its split loop, and its only bookkeeping
+    operation there with N or more elements is the snapshot's window
+    write.  (The snapshot reads rows that the partition kernel then
+    overwrites in place; unordered, the compiler keeps them alive in two
+    payload copies per split.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from lightgbm_tpu.models import learner as learner_mod
+    monkeypatch.setattr(learner_mod, "_SNAP_WINDOW", 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bst = _booster({"tpu_frontier_k": 4, "path_smooth": 1.0})
+    g = bst._gbdt
+    lr = g.learner
+    kp = g.kernel_plan()
+    assert (kp["partition"], kp["search"], kp["mega"], kp["frontier_k"]) \
+        == ("pallas", "xla", "off", 4)
+    Np = lr.N_pad
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = g._fused_phys.lower(
+            sds((lr._pb_rows, Np), jnp.uint8), sds((8, Np), jnp.float32),
+            sds((lr.F,), jnp.bool_), 1,
+            sds((lr.F,), jnp.bool_)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    _, computations, ops = _frontier_loop(text)
+    wide_bookkeeping = 0
+    for line, phase, typ, dims in ops:
+        if dims.split(",")[-1] != str(Np):      # not a row buffer
+            continue
+        assert not re.search(r"[\])}] copy(-start)?\(", line), line[:200]
+        if phase == "bookkeeping":
+            assert (typ, dims) == ("f32", str(Np)) \
+                and _window_write(line, computations), line[:200]
+            wide_bookkeeping += 1
+    assert "lgbm_partition" in text
+    assert wide_bookkeeping >= 1
