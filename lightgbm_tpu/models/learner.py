@@ -431,12 +431,11 @@ class SerialTreeLearner:
         kernel_backend_ok = jax.default_backend() == "tpu" or self._interp
 
         # ---- Pallas partition kernel ----
-        # The leaf partition dominates the tree build in the XLA
-        # formulation (window ops on few-sublane shapes run at 12-16 GB/s
-        # on this stack, see PERF.md); the Pallas kernel
-        # (ops/partition_pallas.py) streams aligned window DMAs at
-        # ~360 GB/s with in-VMEM shift-network compaction (~4 ms per 1M
-        # rows vs ~500 ms).  Selected by eligibility only: off-TPU and
+        # The Pallas kernel (ops/partition_pallas.py) streams aligned
+        # window DMAs through an in-VMEM shift-network compaction
+        # (about 1 ms per 1M rows on the v5e, PERF.md section 6, PR 30;
+        # the XLA formulation has not been timed there at the cells'
+        # size).  Selected by eligibility only: off-TPU and
         # categorical splits / cegb-lazy payloads (not yet kernelized)
         # take the XLA path; a kernel that fails to compile on an eligible
         # shape raises from the first build.  DMA tiling requires

@@ -3,15 +3,16 @@
 TPU-native replacement for the reference DataPartition::Split
 (src/treelearner/data_partition.hpp:118-149) and the CUDA
 bitvector + AggregateBlockOffset + SplitInner pipeline
-(src/treelearner/cuda/cuda_data_partition.cu:288-907), built for the
-measured cost structure of this stack (see PERF.md): XLA window ops on
-few-sublane shapes run at 12-16 GB/s, while Pallas aligned window DMAs
-run at ~360 GB/s and an in-VMEM roll-network compaction costs ~3 us per
-(16, 8192) chunk.  The XLA formulation of the same partition
-(models/learner.py:_partition_leaf) is kept as the CPU / fallback path
-and as the correctness oracle — both produce bit-identical layouts
-(lefts forward-packed in original order, rights behind them in original
-order).
+(src/treelearner/cuda/cuda_data_partition.cu:288-907): aligned window
+DMAs and an in-VMEM roll-network compaction.  On the v5e the kernel is
+bound by the network's lane rotates, not by DMA: a 4096-row chunk of the
+benchmark's geometry took 6.2 us before PR 30 trimmed the network's
+step and takes 4.0 us since, against 0.2 us for its 36 B a row at the
+HBM peak (PERF.md sections 5 and 6).  The XLA formulation of the same
+partition (models/learner.py:_partition_leaf) is kept as the CPU /
+fallback path and as the correctness oracle — both produce bit-identical
+layouts (lefts forward-packed in original order, rights behind them in
+original order).
 
 Design notes (all constraints below were probed on the live toolchain):
   * Window DMAs compile only with provably 128-aligned dynamic lane
@@ -23,7 +24,7 @@ Design notes (all constraints below were probed on the live toolchain):
     returns them to exactly their original positions.
   * No sort / gather / cumsum lower inside Pallas TPU kernels.  Prefix
     sums are computed with strictly-lower-triangular one-hot matmuls on
-    the MXU; the stable two-way compaction is a 13-step binary shift
+    the MXU; the stable two-way compaction is a log2(C)-step binary shift
     network built from ``pltpu.roll`` (bool rolls don't lower — all
     masks stay i32).
   * The compaction payload is PACKED: 4 u8 bin rows ride per i32 row
@@ -101,19 +102,34 @@ def _compact(payload, flag, shift0, C, logc):
     unflagged lanes before it).  Monotone deficits make every step
     collision-free; unflagged lanes are treated as holes.
 
-    The live flag rides bit 16 of the shift vector so each step rolls and
-    selects ONE metadata row instead of two (deficits < C <= 2^15)."""
-    cur = payload
-    live = jnp.int32(1 << 16)
-    meta = jnp.where(flag != 0, shift0 | live, 0)
+    The kernel is bound by the lane rotates of this loop (a step under 128
+    lanes rotates every vreg it rolls; PERF.md section 6, PR 30; counted
+    by tools/kernel_ops.py), so a step is ONE roll and one select:
+      * The deficit row rides the payload's last sublane tile, which has
+        room for it whenever P is no multiple of 8 (11 of 16 at the
+        benchmark's geometry), and is tested after the roll: a lane takes
+        its right neighbour when the deficit that arrives has the bit.
+      * A lane that an element has left is not cleared.  Before step b the
+        stale copies of element k sit at o_k - s, for o_k its first lane
+        and s the strict subsets of the bits of its deficit below b, that
+        is less than 2^b lanes to the right of k itself.  A step moves
+        every copy along with k, so the one lane a copy can overwrite lies
+        strictly between k's new lane and its old one, where a network
+        that keeps the elements' order has no element to lose.  Holes
+        carry deficit 0 and never move.  (Every flag row of C = 16:
+        tests/test_pallas_interpret.py.)
+    Lanes past the flagged count come back holding stale copies: callers
+    mask them off (``stage``)."""
+    P = payload.shape[0]
+    aug = jnp.concatenate([payload, jnp.where(flag != 0, shift0, 0)], axis=0)
     for b in range(logc):
         bit = 1 << b
-        move = jnp.where((meta & live) != 0, meta & bit, 0)
-        m_in = pltpu_roll(move, C - bit) != 0
-        cur = jnp.where(m_in, pltpu_roll(cur, C - bit), cur)
-        meta = jnp.where(m_in, pltpu_roll(meta, C - bit),
-                         jnp.where(move != 0, meta & (live - 1), meta))
-    return cur
+        rolled = pltpu_roll(aug, C - bit)
+        # the mask goes to the tiles as i32: an i1 row is broadcast over
+        # sublanes through an extui and a second compare
+        take = jnp.broadcast_to(rolled[P:P + 1] & bit, aug.shape) != 0
+        aug = jnp.where(take, rolled, aug)
+    return aug[0:P]
 
 
 def _compact_radix4(payload, flag, shift0, C, logc):

@@ -6,6 +6,8 @@ checked on the chip by `python tpu_selfcheck.py` (kernel-vs-oracle steps
 builder runs through the chip tool; no pytest lane can reach a TPU under
 this suite's CPU-pinned conftest."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -65,6 +67,83 @@ def test_partition_kernel_interpreted(trial):
     np.testing.assert_array_equal(np.asarray(rpb), epb)
     np.testing.assert_array_equal(
         np.asarray(rpg)[:3].view(np.int32), epg[:3].view(np.int32))
+
+
+# The kernel's own edges, each against the NumPy stable partition: the
+# split column holds the pattern itself (0 goes left, 1 goes right), so a
+# case says exactly which lane of which chunk holds what.  (start, cnt,
+# pattern over the range); C = 256, the cover starts at start's 128-floor.
+_EDGE_C = 256
+_EDGE_CASES = {
+    "all_left": (_EDGE_C + 37, 2 * _EDGE_C + 11, np.zeros_like),
+    "all_right": (_EDGE_C + 37, 2 * _EDGE_C + 11, np.ones_like),
+    "alternating": (_EDGE_C + 37, 2 * _EDGE_C + 11, lambda i: i % 2),
+    "one_right_at_lane_0": (2 * _EDGE_C, _EDGE_C + 50, lambda i: i == 0),
+    "one_right_at_lane_C-1": (2 * _EDGE_C, _EDGE_C + 50,
+                              lambda i: i == _EDGE_C - 1),
+    "short_unaligned": (_EDGE_C + 77, 100, lambda i: (i * 7 // 3) % 2),
+    "empty": (3 * _EDGE_C + 17, 0, lambda i: i),
+    "cover_exactly_two_chunks": (_EDGE_C + 40, 2 * _EDGE_C - 40,
+                                 lambda i: (i * 5 // 7) % 2),
+    "three_chunks_unaligned": (_EDGE_C + 5, 2 * _EDGE_C + 100,
+                               lambda i: (i // 3) % 2),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_partition(pack):
+    return jax.jit(functools.partial(
+        partition_leaf_pallas, row_chunk=_EDGE_C, pack_rowid=pack,
+        interpret=True))
+
+
+@pytest.mark.parametrize("pack", [False, True],
+                         ids=["rowid_row", "rowid_packed"])
+@pytest.mark.parametrize("case", sorted(_EDGE_CASES))
+def test_partition_kernel_edges_interpreted(case, pack):
+    C, G32, G, col = _EDGE_C, 32, 28, 5
+    Np = 6 * C
+    start, cnt, pattern = _EDGE_CASES[case]
+    rng = np.random.RandomState(7)
+    pb = rng.randint(0, 250, (G32, Np)).astype(np.uint8)
+    pb[G:] = 0                     # pad rows zero: the dataset invariant
+    pb[col, start:start + cnt] = pattern(np.arange(cnt))
+    pg = rng.randn(8, Np).astype(np.float32)
+    epb, epg, enl = _oracle(pb, pg, start, cnt, col, 0, 0, 4, 0, 0, 0, 0)
+    assert enl == int((pb[col, start:start + cnt] == 0).sum())
+    sc = make_scalars(start, cnt, col, 0, 0, 4, 0, 0, 0, 0)
+    rpb, rpg, _, rnl = _edge_partition(pack)(
+        jnp.asarray(pb), jnp.asarray(pg),
+        jnp.zeros((sc_rows_for(G32), Np), jnp.int32), sc)
+    assert int(np.asarray(rnl)[0, 0]) == enl
+    np.testing.assert_array_equal(np.asarray(rpb), epb)
+    np.testing.assert_array_equal(
+        np.asarray(rpg)[:3].view(np.int32), epg[:3].view(np.int32))
+
+
+@pytest.mark.parametrize("P", [3, 11, 19])
+def test_compaction_network_every_flag_row_of_16_lanes(P, monkeypatch):
+    """The network alone, outside any kernel (its rolls as jnp.roll), on
+    all 2^16 flag rows of a 16-lane chunk: the flagged lanes come back at
+    the front in their order, for a payload of one, two and three sublane
+    tiles.  The lanes an element has left are never cleared, so this is
+    the proof by cases that a stale copy reaches no live lane."""
+    from lightgbm_tpu.ops import partition_pallas as pp
+    monkeypatch.setattr(pp, "pltpu_roll", lambda x, s: jnp.roll(x, s, 1))
+    C = 16
+    flags = (np.arange(1 << C)[:, None] >> np.arange(C)) & 1     # (2^16, C)
+    holes = 1 - flags
+    deficit = np.cumsum(holes, axis=1) - holes
+    payload = np.arange(C)[None, :] + 100 * np.arange(P)[:, None]
+    out = np.asarray(jax.vmap(
+        lambda f, d: pp._compact(jnp.asarray(payload, jnp.int32), f[None],
+                                 d[None], C, 4))(
+        jnp.asarray(flags, jnp.int32), jnp.asarray(deficit, jnp.int32)))
+    order = np.argsort(holes, axis=1, kind="stable")             # lanes
+    want = payload[:, order].transpose(1, 0, 2)                  # (2^16, P, C)
+    kept = np.arange(C)[None, :] < flags.sum(axis=1)[:, None]
+    np.testing.assert_array_equal(np.where(kept[:, None, :], out, 0),
+                                  np.where(kept[:, None, :], want, 0))
 
 
 def test_split_kernel_interpreted():

@@ -1,0 +1,186 @@
+"""Vector operations per loop body of a Pallas kernel, as Mosaic lowers it
+for the v5e (CLI; no chip: the described ``v5e:2x2`` topology).
+
+The partition kernel is bound by the work of its compaction network, not
+by DMA, and on the v5e by the network's lane rotates before its ALU
+operations: over eight forms of the network the kernel's time followed
+the ``tpu.dynamic_rotate`` count at about 0.22 s an iteration per 1,000
+rotates a chunk, and the other operations at under 0.01 s per 1,000
+(PERF.md section 6, PR 30).  So what a change to it saves can be counted
+before any chip time is spent.  Mosaic's own dump
+(``LIBTPU_INIT_ARGS=--xla_mosaic_dump_to=<dir>``, the file after
+``apply-vector-layout`` and its simplification) holds one line per
+operation on one vreg; this tool compiles the named kernel with the dump
+on and counts those lines per ``scf.for`` body, by kind, the branches
+nested in a body included.  libtpu reads the flag once, when it is loaded,
+so each count is a process of its own:
+
+    python tools/kernel_ops.py partition                 # the cells' geometry
+    python tools/kernel_ops.py partition --chunk 2048 --pack-rowid
+    python tools/kernel_ops.py split_mega --json
+
+Body 0 of ``partition`` is pass 1 (a chunk: decide, two compaction
+networks, stage, flush), body 1 is pass 2 (a destination window).  A
+count is no time: it says how much work the vector units are handed, not
+which of them binds; only arms on the chip said that.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import glob
+import json
+import os
+import re
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+DUMP_STAGE = "post-apply-vector-layout-simplify"
+_KIND_RE = re.compile(r"^(?:%[^=]*= )?\"?([A-Za-z_][\w.]*)")
+
+
+def count_bodies(text: str) -> list[dict]:
+    """Per ``scf.for`` body of a Mosaic dump, in order of appearance:
+    ``{"line", "vector_ops", "by_kind"}``.  An operation counts when one of
+    its types is a ``vector<...>`` (after apply-vector-layout: one vreg),
+    and goes to the innermost enclosing loop, through any ``scf.if``."""
+    bodies: list[dict] = []
+    stack: list[int | None] = []     # per open region, its innermost loop
+    for no, raw in enumerate(text.splitlines(), 1):
+        line = raw.strip()
+        if line.startswith("}"):
+            region = stack.pop() if stack else None
+            if line.endswith("{"):                      # "} else {"
+                stack.append(region)
+            continue
+        opens = line.endswith("{")
+        if opens and re.search(r"\bscf\.for\b", line):
+            bodies.append({"line": no, "vector_ops": 0,
+                           "by_kind": collections.Counter()})
+            stack.append(len(bodies) - 1)
+            continue
+        if opens:
+            stack.append(stack[-1] if stack else None)
+            continue
+        if "vector<" not in line:
+            continue
+        body = stack[-1] if stack else None
+        kind = _KIND_RE.match(line)
+        if body is None or kind is None:
+            continue
+        bodies[body]["vector_ops"] += 1
+        bodies[body]["by_kind"][kind.group(1)] += 1
+    for b in bodies:
+        b["by_kind"] = dict(b["by_kind"].most_common())
+    return bodies
+
+
+# ---------------------------------------------------------------------------
+# the kernels: name -> (kernel name in the dump, function, argument shapes)
+# ---------------------------------------------------------------------------
+def _operands(args):
+    """The four operands both kernels take, at 64 chunks of rows."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.partition_pallas import N_SCALARS, sc_rows_for
+    Np = 64 * args.chunk
+    return [((args.g32, Np), jnp.uint8), ((8, Np), jnp.float32),
+            ((sc_rows_for(args.g32), Np), jnp.int32),
+            ((N_SCALARS,), jnp.int32)]
+
+
+def _partition(args):
+    from lightgbm_tpu.ops.partition_pallas import partition_leaf_pallas
+
+    def fn(pb, pg, sp, s):
+        return partition_leaf_pallas(
+            pb, pg, sp, s, row_chunk=args.chunk, ghi_live=args.ghi_live,
+            pack_rowid=args.pack_rowid, compact_radix=args.radix)
+    return "lgbm_partition", fn, _operands(args)
+
+
+def _split_mega(args):
+    from lightgbm_tpu.ops.split_megakernel_pallas import \
+        split_megakernel_pallas
+
+    def fn(pb, pg, sp, s):
+        return split_megakernel_pallas(
+            pb, pg, sp, s, row_chunk=args.chunk, num_bins=args.bins,
+            num_groups=args.g32 - 4, ghi_live=args.ghi_live,
+            pack_rowid=args.pack_rowid, compact_radix=args.radix)
+    return "lgbm_split_mega", fn, _operands(args)
+
+
+KERNELS = {"partition": _partition, "split_mega": _split_mega}
+
+
+def compile_and_count(args) -> dict:
+    """Compile the kernel for the described chip with the dump on and
+    count it.  Must run before anything has loaded libtpu."""
+    with tempfile.TemporaryDirectory(prefix="kernel_ops_") as dump:
+        os.environ["LIBTPU_INIT_ARGS"] = " ".join(
+            [os.environ.get("LIBTPU_INIT_ARGS", ""),
+             f"--xla_mosaic_dump_to={dump}"]).strip()
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        sys.path.insert(0, ROOT)
+        import jax
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        # a compile for a described chip cannot be read back from the
+        # persistent cache, and a hit would write no dump
+        jax.config.update("jax_enable_compilation_cache", False)
+        name, fn, shapes = KERNELS[args.kernel](args)
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:      # no libtpu, or it is held elsewhere
+            return {"kernel": name, "error": f"no v5e:2x2 topology: {e}"}
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        jax.jit(fn).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]).compile()
+        files = sorted(glob.glob(
+            os.path.join(dump, f"*-mosaic-dump-{name}-{DUMP_STAGE}.txt")))
+        if not files:
+            return {"kernel": name, "error": "Mosaic wrote no dump"}
+        with open(files[-1]) as fh:
+            bodies = count_bodies(fh.read())
+    return {"kernel": name, "stage": DUMP_STAGE, "bodies": bodies,
+            "geometry": {k: v for k, v in vars(args).items()
+                         if k not in ("kernel", "json")}}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("kernel", choices=sorted(KERNELS))
+    ap.add_argument("--g32", type=int, default=32,
+                    help="sublanes of the u8 bin matrix (multiple of 32)")
+    ap.add_argument("--chunk", type=int, default=4096, help="row_chunk")
+    ap.add_argument("--ghi-live", type=int, default=3)
+    ap.add_argument("--pack-rowid", action="store_true")
+    ap.add_argument("--radix", action="store_true",
+                    help="the radix-4 network (tpu_compact_radix)")
+    ap.add_argument("--bins", type=int, default=255,
+                    help="split_mega: histogram bins")
+    ap.add_argument("--json", action="store_true")
+    args = ap.parse_args(argv)
+    out = compile_and_count(args)
+    if args.json:
+        print(json.dumps(out))
+    elif "error" in out:
+        print(out["error"], file=sys.stderr)
+    else:
+        print(f"{out['kernel']}  {out['geometry']}")
+        for i, b in enumerate(out["bodies"]):
+            kinds = ", ".join(f"{k} {n}" for k, n in
+                              list(b["by_kind"].items())[:8])
+            print(f"body {i} (line {b['line']}): {b['vector_ops']} "
+                  f"vector ops: {kinds}")
+    return 1 if "error" in out else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
