@@ -44,11 +44,9 @@ TOY = {"rows": 20_000, "holdout": 5_000, "leaves": 31, "timed": 3,
 
 # what `auto` must resolve to for this shape (all-numerical, u8 bins, serial)
 TPU_PLAN = {"partition": "pallas", "search": "pallas", "mega": "pallas",
-            "compaction": "binary", "frontier_k": 4, "fused": "on",
-            "tree_learner": "serial"}
+            "frontier_k": 4, "fused": "on", "tree_learner": "serial"}
 CPU_PLAN = {"partition": "xla", "search": "xla", "mega": "off",
-            "compaction": "binary", "frontier_k": 1, "fused": "on",
-            "tree_learner": "serial"}
+            "frontier_k": 1, "fused": "on", "tree_learner": "serial"}
 
 
 def say(msg):

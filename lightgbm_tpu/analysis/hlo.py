@@ -134,13 +134,13 @@ def report(params: Dict[str, Any] = None) -> Dict[str, Any]:
     hlo, learner = compile_tree_build(params)
     out = body_counts(hlo)
     out["params"] = dict(params or {})
-    out["mega"] = learner._use_mega
-    out["frontier_k"] = learner.frontier_k
+    out["mega"] = learner.plan.mega
+    out["frontier_k"] = learner.plan.frontier_k
     # the hist-state buffer shape (the subtraction path's per-split
     # dynamic-slice target) — its copies are the round-4 smoking gun.
     # The frontier-batched body sizes the state by its speculative slack
     # (L + K slots) instead of L + 1.
-    slots = learner.L + max(learner.frontier_k, 1)
+    slots = learner.L + learner.plan.frontier_k
     G, B = learner.G, learner.B
     state_shapes = [f"f32[{slots},{G},{B},2]",
                     f"f32[{slots},8,{learner._flat_geom[2]}]"

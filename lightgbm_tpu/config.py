@@ -279,7 +279,7 @@ _PARAMS: List[_Param] = [
     # (ops/split.py:find_best_split_linear) so the STRUCTURE itself is
     # PL-aware, and the per-leaf models come out of the winning split
     # candidates — no extra data pass.  Ineligible configs (see
-    # learner._linear_gain_eligible) fall back to refit with a warning
+    # models/plan.py) fall back to refit with a warning
     _p("linear_tree_mode", "refit", str),
     _p("max_bin", 255, int, ("max_bins",), ">1"),
     _p("max_bin_by_feature", "", str),
@@ -410,16 +410,11 @@ _PARAMS: List[_Param] = [
     _p("gpu_use_dp", False, bool),
     _p("num_gpu", 1, int, (), ">0"),
     # --- TPU-specific (new in this framework) ---
-    _p("tpu_hist_dtype", "float32", str),       # float32 | bfloat16_pair
     # per-leaf histogram state: "auto" = lane-flattened state updated in
     # place by the Pallas RMW kernel (ops/hist_state_pallas.py) when the
     # fast serial path is active; "xla" = (L+1, G, B, 2) dynamic-slice
     # state (the fallback and the A/B baseline)
     _p("tpu_hist_state", "auto", str),
-    # measurement-only: duplicate one component inside the compiled tree
-    # loop with a runtime-opaque select so tools/ab_bench.py can read its
-    # IN-CONTEXT cost as the paired e2e delta ("" | "hist" | "search")
-    _p("tpu_ab_double", "", str),
     _p("tpu_partition_kernel", "pallas", str),  # pallas | xla
     # split mega-kernel: partition + BOTH children's histograms in one
     # Pallas program per split (ops/split_megakernel_pallas.py) — no
@@ -441,28 +436,23 @@ _PARAMS: List[_Param] = [
     # monotone constraints, CEGB, extra_trees, feature_fraction_bynode,
     # interaction constraints or a parallel tree learner are active)
     _p("tpu_frontier_k", "auto", str),
-    # radix-4 compaction network in the partition/mega kernels: half the
-    # roll-network steps of the binary network (bit-identical layouts;
-    # an instruction-budget lever — see PERF.md round 6)
-    _p("tpu_compact_radix", False, bool),
     # run the Pallas kernels through the interpreter on any backend
     # (testing/debug: enables the kernel paths off-TPU; SLOW)
     _p("tpu_kernel_interpret", False, bool),
     # rows per partition/histogram chunk; 4096 measured best end-to-end
     # on v5e (round 3: fixed cost 15.9 -> 12.1 ms/iter vs 8192 at equal
-    # slope — smaller per-split padding waste).  "auto" consults the
-    # BENCH_history.jsonl trajectory for a same-fingerprint chunk-sweep
-    # winner before falling back to 4096 (ops/chunkpolicy.py); also the
-    # SEED of the leaf-size-adaptive menu below
+    # slope — smaller per-split padding waste).  "auto" is the constant
+    # 4096 (ops/chunkpolicy.py); also the SEED of the leaf-size-adaptive
+    # menu below
     _p("tpu_row_chunk", "4096", str),
     # leaf-size-adaptive chunk policy (ops/chunkpolicy.py): per-leaf
     # histogram/partition passes pick their chunk width from a bounded
     # static menu seeded by tpu_row_chunk, so small leaves stop paying
     # the worst-case padded chunk (68% of the CPU iteration, PERF.md
     # round 12) while trees stay BIT-identical to the fixed grid.
-    # "auto" = adaptive in the small-leaf regime (or per a measured
-    # same-fingerprint chunk-sweep verdict) on the plain XLA serial
-    # path; "fixed" = the base grid everywhere; "adaptive" = force on
+    # "auto" = adaptive in the small-leaf regime
+    # ((num_leaves-1) * chunk > rows) on the plain XLA serial path;
+    # "fixed" = the base grid everywhere; "adaptive" = force on
     _p("tpu_chunk_policy", "auto", str),
     # ride the rowid row inside the spare packed-bin bytes when G <= G32-4
     # (one fewer payload sublane through the partition roll networks)
@@ -477,9 +467,6 @@ _PARAMS: List[_Param] = [
     # crosses the wire once instead of ndev times; "psum" = full-hist
     # allreduce (the round-4 behavior)
     _p("tpu_data_hist_sync", "scatter", str),
-    _p("tpu_feature_block", 64, int, (), ">0"),  # feature groups per histogram block
-    _p("tpu_min_bucket_log2", 10, int, (), ">=0"),  # smallest partition bucket
-    _p("tpu_donate_state", True, bool),
 ]
 
 _PARAM_BY_NAME: Dict[str, _Param] = {p.name: p for p in _PARAMS}

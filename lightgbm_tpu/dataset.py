@@ -685,8 +685,7 @@ class BinnedDataset:
             from .ops.chunkpolicy import resolve_base
             from .ops.construct import DeviceIngest
             return DeviceIngest(len(self.groups), self.num_data, dtype,
-                                resolve_base(self.config, self.num_data,
-                                             self.num_total_features))
+                                resolve_base(self.config.tpu_row_chunk))
         except Exception as exc:
             log.warning("device ingest unavailable (%s); keeping the "
                         "host binned matrix", str(exc).split("\n")[0][:120])

@@ -725,13 +725,19 @@ class GBDT:
                      "objective lacks gradients_from_payload")
         log.info("kernel plan: %s", " ".join(
             f"{k}={v}" for k, v in self.kernel_plan().items()))
+        for k, v in self._tree_learner().plan.why.items():
+            log.info("  %s: %s", k, v)
+
+    def _tree_learner(self) -> SerialTreeLearner:
+        sb = self.sharded_builder
+        return sb.learner if sb is not None else self.learner
 
     def kernel_plan(self) -> Dict[str, Any]:
         """What actually builds the trees: the tree-building learner's
         resolved kernels (learner.kernel_plan) plus whether the whole
         iteration runs as one fused program."""
         sb = self.sharded_builder
-        plan = (sb.learner if sb is not None else self.learner).kernel_plan()
+        plan = self._tree_learner().kernel_plan()
         plan["fused"] = "on" if self._fused is not None else "off"
         plan["tree_learner"] = sb.mode if sb is not None else "serial"
         return plan

@@ -45,9 +45,11 @@ from ..config import Config
 from ..dataset import BinnedDataset
 from ..obs import scopes
 from ..ops import split as split_ops
+from ..ops.chunkpolicy import ChunkPolicy
 from ..ops.histogram import leaf_hist_slice
 from ..ops.partition import split_decision
 from ..utils import log
+from . import plan as plan_mod
 
 NEG_INF = float("-inf")
 
@@ -186,13 +188,6 @@ def parse_per_feature_penalty(spec, num_total_features: int):
             f"per-feature penalty has {len(vals)} entries, expected "
             f"{num_total_features}")
     return np.asarray(vals, dtype=np.float32)
-
-
-def _pow2ceil(x: int) -> int:
-    p = 1
-    while p < x:
-        p *= 2
-    return p
 
 
 class SerialTreeLearner:
@@ -403,16 +398,40 @@ class SerialTreeLearner:
             dataset.binned.dtype if dataset.binned is not None
             else (self._ingest.dtype if self._ingest is not None
                   else np.uint8))
-        self._host_bin_dtype = host_bin_dtype
-        from ..ops import chunkpolicy
-        self.row_chunk = min(
-            chunkpolicy.resolve_base(config, self.N,
-                                     dataset.num_total_features),
-            max(_pow2ceil(self.N), 256))
-        if self.row_chunk & (self.row_chunk - 1):
-            self.row_chunk = _pow2ceil(self.row_chunk)
-        # the partition packs (dest << bits) | src into one uint32 sort key
-        self.row_chunk = min(self.row_chunk, 1 << 15)
+        self.l1 = float(config.lambda_l1)
+        self.max_delta_step = float(config.max_delta_step)
+        self.path_smooth = float(config.path_smooth)
+        # tpu_kernel_interpret runs every Pallas kernel through the
+        # interpreter, enabling the kernel code paths on any backend
+        # (the off-TPU correctness lane for the kernels; SLOW)
+        self._interp = bool(config.tpu_kernel_interpret)
+
+        # ---- which split-step program: decided in models/plan.py ----
+        self.plan = plan_mod.resolve(plan_mod.PlanFacts(
+            backend=jax.default_backend(), interpret=self._interp,
+            rows=self.N, F=self.F, G=self.G, B=self.B,
+            num_leaves=self.L, host_bin_dtype=str(host_bin_dtype),
+            has_bins=(dataset.binned is not None
+                      or self._ingest is not None),
+            plain_view=self._plain_view,
+            has_categorical=self.has_categorical, use_mc=self.use_mc,
+            has_cegb=self.has_cegb, cegb_lazy=self.cegb_lazy is not None,
+            path_smooth=self.path_smooth, forced=self.forced is not None,
+            extra_trees=self.extra_trees, has_bynode=self.has_bynode,
+            feature_contri=self.feature_contri is not None,
+            interaction_constraints=self.ic_masks is not None,
+            l1=self.l1, max_delta_step=self.max_delta_step,
+            linear_gain_requested=(
+                bool(config.linear_tree)
+                and config.linear_tree_mode == "leafwise_gain"),
+            parallel_mode=parallel_mode, axis_name=axis_name is not None,
+            num_shards=num_shards,
+            **{k: getattr(config, k) for k in plan_mod.OPTION_FIELDS}))
+        plan = self.plan
+        for unmet in plan.unmet:
+            log.warning("%s", unmet)
+        pallas_part = plan.partition == "pallas"
+        self.row_chunk = plan.row_chunk
         self._chunk_bits = self.row_chunk.bit_length() - 1
         C = self.row_chunk
         # layout: [C front-pad rows][N data rows][>=2C tail-pad rows]; the
@@ -424,34 +443,13 @@ class SerialTreeLearner:
         # to C-1 rows.  Root range starts at C.
         self.row0 = C
         self.N_pad = C + ((self.N + C - 1) // C + 2) * C
-        # tpu_kernel_interpret runs every Pallas kernel through the
-        # interpreter, enabling the kernel code paths on any backend
-        # (the off-TPU correctness lane for the kernels; SLOW)
-        self._interp = bool(getattr(config, "tpu_kernel_interpret", False))
-        kernel_backend_ok = jax.default_backend() == "tpu" or self._interp
-
         # ---- Pallas partition kernel ----
         # The Pallas kernel (ops/partition_pallas.py) streams aligned
         # window DMAs through an in-VMEM shift-network compaction
         # (about 1 ms per 1M rows on the v5e, PERF.md section 6, PR 30;
         # the XLA formulation has not been timed there at the cells'
-        # size).  Selected by eligibility only: off-TPU and
-        # categorical splits / cegb-lazy payloads (not yet kernelized)
-        # take the XLA path; a kernel that fails to compile on an eligible
-        # shape raises from the first build.  DMA tiling requires
-        # sublane-padded row buffers: bins to a multiple of 32 (u8 tile),
-        # grad/hess/rowid to 8 f32 rows.
-        self._use_pallas_part = (
-            kernel_backend_ok
-            and config.tpu_partition_kernel == "pallas"
-            and not self.has_categorical
-            and self.cegb_lazy is None
-            and parallel_mode == "serial"
-            and self.F > 0
-            and (dataset.binned is not None or self._ingest is not None)
-            and host_bin_dtype == np.uint8)
-        self._compact_radix = bool(getattr(config, "tpu_compact_radix",
-                                           False))
+        # size).  DMA tiling requires sublane-padded row buffers: bins
+        # to a multiple of 32 (u8 tile), grad/hess/rowid to 8 f32 rows.
         self._pb_rows = self.G
         # (8, N_pad) f32 ghi payload in BOTH partition modes: rows are
         # (grad, hess, rowid-bits, then optional score/objective-payload
@@ -460,18 +458,14 @@ class SerialTreeLearner:
         # gather cost is width-independent (PERF.md).
         self._ghi_rows = 8
         self._ghi_live = 3     # rows the Pallas kernel must carry
-        if self._use_pallas_part:
-            g32 = ((self.G + 31) // 32) * 32
-            self._pack_rowid = (bool(getattr(config, "tpu_pack_rowid",
-                                             True))
-                                and g32 - self.G >= 4 and g32 >= 16)
-            self._pb_rows = g32
+        if pallas_part:
+            self._pb_rows = ((self.G + 31) // 32) * 32
         # fused multiclass carries K score rows + label (+ weight) through
         # the partition; the XLA path takes any row count (its per-row
         # gather cost is width-independent), the Pallas kernel is capped
         # at its 8-row f32 tile (partition_pallas.py asserts GH == 8)
         K_cls = max(int(config.num_class), 1)
-        if K_cls > 1 and not self._use_pallas_part:
+        if K_cls > 1 and not pallas_part:
             need = 4 + K_cls + (1 if dataset.metadata.weight is not None
                                 else 0)
             if need > self._ghi_rows:
@@ -542,62 +536,22 @@ class SerialTreeLearner:
                     self._part0 = jnp.asarray(pad)
 
         # ---- scalars ----
-        self.l1 = float(config.lambda_l1)
         self.l2 = float(config.lambda_l2)
-        self.max_delta_step = float(config.max_delta_step)
         self.min_gain_to_split = float(config.min_gain_to_split)
         self.min_data_in_leaf = int(config.min_data_in_leaf)
         self.min_sum_hessian = float(config.min_sum_hessian_in_leaf)
         self.max_depth = int(config.max_depth)
         self.top_k = int(config.top_k)
-        self.path_smooth = float(config.path_smooth)
-
-        # lean split search: the per-split fixed cost is op-dispatch-bound
-        # (PERF.md); plain configs take the op-packed formulation whose
-        # f32 count cumsum is exact below 2^24 rows
-        self._fast_search = (not self.has_categorical and not self.use_mc
-                             and not self.has_cegb
-                             and self.path_smooth <= 0.0
-                             and self.N < (1 << 24))
 
         # ---- piece-wise linear leafwise gain (linear_tree_mode) ----
         # Split gain over leaf-local linear models inside the device
-        # search (ops/split.py:find_best_split_linear).  The eligibility
-        # set is the fast-search envelope minus the split refinements
-        # whose bodies re-derive candidate stats (the linear candidate's
-        # child models ride the packed winner read): ineligible configs
-        # warn once and fall back to the post-hoc refit mode, which
-        # trains exactly like before.
-        want_lin = (bool(config.linear_tree) and
-                    str(getattr(config, "linear_tree_mode", "refit"))
-                    == "leafwise_gain")
-        if want_lin:
-            lin_block = []
-            if not self._fast_search:
-                lin_block.append("categorical/monotone/CEGB/path_smooth"
-                                 "/huge-N configs")
-            if self.forced is not None:
-                lin_block.append("forced splits")
-            if parallel_mode != "serial" or axis_name is not None:
-                lin_block.append("parallel tree learners")
-            if self.l1 > 0.0:
-                lin_block.append("lambda_l1 > 0")
-            if self.max_delta_step > 0.0:
-                lin_block.append("max_delta_step > 0")
-            if self.feature_contri is not None:
-                lin_block.append("feature_contri")
-            if self.F == 0:
-                lin_block.append("no usable features")
-            if lin_block:
-                log.warning("linear_tree_mode=leafwise_gain is not "
-                            "supported with %s; falling back to the "
-                            "post-hoc refit mode", ", ".join(lin_block))
-                want_lin = False
-        self._linear_gain = want_lin
+        # search (ops/split.py:find_best_split_linear); a configuration
+        # the plan excludes trains in the post-hoc refit mode, exactly
+        # like before.
         self.linear_lambda = float(config.linear_lambda)
-        self._nlf = NLF_LINEAR if self._linear_gain else NLF
+        self._nlf = NLF_LINEAR if plan.linear_gain else NLF
         self._rep_vals = None
-        if self._linear_gain:
+        if plan.linear_gain:
             # per-(feature, bin) representative raw values — the linear
             # moment planes are rank-1 scalings of the histogram by this
             # table (ops/histogram.py:linear_moment_planes).  Empirical
@@ -614,35 +568,13 @@ class SerialTreeLearner:
             self._rep_vals = jnp.asarray(rep)
 
         # ReduceScatter histogram ownership (reference placement:
-        # data_parallel_tree_learner.cpp:282-296) — see _psum.  Plain
-        # fast-search geometry only; the forced/monotone/categorical
-        # paths read whole-histogram state and keep the full psum.
-        self._scatter_per = 0
-        self._scatter_groups = (
-            parallel_mode == "data" and self.axis_name is not None
-            and getattr(config, "tpu_data_hist_sync",
-                        "scatter") == "scatter"
-            and self._fast_search and self._plain_view
-            and self.forced is None
-            and num_shards > 1 and self.F >= num_shards)
-        if self._scatter_groups:
-            self._scatter_per = -(-self.G // num_shards)
+        # data_parallel_tree_learner.cpp:282-296) — see _psum
+        self._scatter_per = (-(-self.G // num_shards)
+                             if plan.scatter_groups else 0)
 
         # Pallas split-search kernel: one program per split evaluates
-        # both children (ops/split_pallas.py).  Plain serial TPU path
-        # only; falls back to the XLA fast search elsewhere.
-        self._use_pallas_search = (self._use_pallas_part
-                                   and self._fast_search
-                                   and self._plain_view
-                                   and self.forced is None
-                                   # the pair kernel's 13-scalar tile
-                                   # carries no linear child models
-                                   and not self._linear_gain
-                                   and not self.extra_trees
-                                   and self.feature_contri is None
-                                   and parallel_mode == "serial"
-                                   and self.F > 0)
-        if self._use_pallas_search:
+        # both children (ops/split_pallas.py)
+        if plan.search == "pallas":
             half = np.zeros((self.F, 8), np.int32)
             half[:, 0] = meta["num_bin"]
             half[:, 1] = meta["missing_type"]
@@ -654,84 +586,8 @@ class SerialTreeLearner:
         # XLA to materialize two full-state copies per split (PERF.md
         # "fixed-cost smoking gun"); the flat (L+1, 8, WL) state is
         # updated in place by ops/hist_state_pallas.py with one-row DMAs.
-        self._ab_double = str(getattr(config, "tpu_ab_double", "") or "")
-        # bfloat16_pair: one-hot/gradient OPERANDS in bf16 with f32
-        # accumulation — the TPU analog of the reference GPU's
-        # single-precision histograms (gpu_use_dp=false default,
-        # docs/GPU-Performance.rst); float32 keeps strict CPU-parity
-        self._hist_dtype = (jnp.bfloat16
-                            if str(getattr(config, "tpu_hist_dtype",
-                                           "float32")) == "bfloat16_pair"
-                            else jnp.float32)
-        self._init_megakernel(config, dataset, parallel_mode)
-
-        # ---- frontier-batched growth (tpu_frontier_k) ----
-        # Grow the top-K gain leaves of the frontier per while-loop step
-        # instead of 1: the per-split fixed bookkeeping cost (scalar DUS
-        # writes, the parent-hist dynamic-slice read, kernel-launch fixed
-        # work) amortizes ~K-fold while an oracle-order replay carried in
-        # the loop keeps trained trees BIT-identical to the K=1 learner,
-        # including at the num_leaves budget boundary (see
-        # _build_tree_frontier).  Order-dependent machinery — forced
-        # splits, monotone constraint propagation, CEGB feature
-        # accounting, per-step RNG draws (extra_trees / bynode sampling),
-        # interaction constraints, parallel learners — falls back to K=1.
-        spec = str(getattr(config, "tpu_frontier_k", "auto")
-                   or "auto").strip().lower()
-        frontier_eligible = (parallel_mode == "serial"
-                             and axis_name is None
-                             and self.forced is None
-                             # leafwise linear gain stays on the K=1
-                             # body (residual, see ROADMAP item 3)
-                             and not self._linear_gain
-                             and not self.use_mc
-                             and not self.has_cegb
-                             and not self.extra_trees
-                             and not self.has_bynode
-                             and self.ic_masks is None
-                             and not self._ab_double
-                             # the Pallas pair-search without the mega
-                             # kernel implies the flat-hist RMW state
-                             # machinery; the batched body reproduces
-                             # the pair search only on the mega path
-                             and not (self._use_pallas_search
-                                      and self._use_mega is None)
-                             and self.F > 0)
-        if spec in ("auto", ""):
-            # on CPU hosts auto stays at 1: the win is real (see PERF.md
-            # round 12) but the bigger traced program taxes every fresh
-            # compile, which test-sized trainings pay more than they save
-            k_req = 4 if (frontier_eligible
-                          and jax.default_backend() == "tpu") else 1
-        else:
-            try:
-                k_req = int(spec)
-            except ValueError:
-                raise ValueError("tpu_frontier_k must be 'auto' or a "
-                                 f"positive integer, got {spec!r}")
-            if k_req < 1:
-                raise ValueError("tpu_frontier_k must be >= 1")
-            if k_req > 1 and not frontier_eligible:
-                log.warning(
-                    "tpu_frontier_k=%d needs the plain serial tree path "
-                    "(no forced splits, monotone constraints, CEGB, "
-                    "extra_trees, feature_fraction_bynode, interaction "
-                    "constraints or parallel learners); using 1", k_req)
-                k_req = 1
-        self.frontier_k = max(1, min(k_req, self.L - 1))
-
-        # no histogram state exists on the mega path (the children
-        # histograms feed the split search in-register), so the flat
-        # state is skipped entirely there; the
-        # frontier-batched body replaces the per-split state RMW with
-        # one K-row gather + one 2K-row scatter, so it skips it too
-        self._use_flat_hist = (self._use_pallas_search
-                               and self._use_mega is None
-                               and self.frontier_k == 1
-                               and getattr(config, "tpu_hist_state",
-                                           "auto") != "xla")
         self._flat_geom = None
-        if self._use_flat_hist:
+        if plan.hist_state == "flat":
             from ..ops.hist_state_pallas import flat_geometry
             self._flat_geom = flat_geometry(self.G, self.B)
 
@@ -741,23 +597,11 @@ class SerialTreeLearner:
         # padded chunk (68% of the CPU iteration, PERF.md round 12).
         # Band dispatch is zero-trip fori_loops — never lax.switch/cond,
         # whose branch plumbing copies the multi-MB row buffers per
-        # split.  Plain XLA serial paths only: the Pallas kernels keep
-        # their proven base grid until the on-TPU round (ROADMAP 4b),
-        # and the in-context doubling probe must measure the fixed
-        # formulation it was calibrated on.  Trees stay BIT-identical
-        # to tpu_chunk_policy=fixed (see chunkpolicy module docstring;
-        # pinned by tests/test_chunkpolicy.py and ab_bench --chunk).
-        chunk_eligible = (parallel_mode == "serial"
-                          and axis_name is None
-                          and not self._use_pallas_part
-                          and self._use_mega != "pallas"
-                          and not self._ab_double
-                          and self._hist_dtype is jnp.float32
-                          and self.F > 0)
-        _, self._chunk_policy = chunkpolicy.resolve(
-            config, self.N, self.L, chunk_eligible,
-            base=self.row_chunk,
-            features=dataset.num_total_features)
+        # split.  Trees stay BIT-identical to tpu_chunk_policy=fixed
+        # (see chunkpolicy module docstring; pinned by
+        # tests/test_chunkpolicy.py and ab_bench --chunk).
+        self._chunk_policy = ChunkPolicy(plan.row_chunk,
+                                         adaptive=plan.chunk_adaptive)
 
         axes = (0, 0, 0, 0, 0, 0, 0, 0, 0, 0, None)
         if self.cegb_lazy is not None:
@@ -772,70 +616,7 @@ class SerialTreeLearner:
         """The kernels this learner resolved to, as one printable record
         (selection is by backend and shape eligibility only — a kernel
         named here that cannot compile raises, it is never swapped)."""
-        return {"partition": "pallas" if self._use_pallas_part else "xla",
-                "search": "pallas" if self._use_pallas_search else "xla",
-                "hist_state": "flat" if self._use_flat_hist else "xla",
-                "mega": self._use_mega or "off",
-                "compaction": "radix4" if self._compact_radix else "binary",
-                "frontier_k": self.frontier_k}
-
-    def _init_megakernel(self, config, dataset, parallel_mode):
-        """Split mega-kernel gate (partition + both-children
-        histograms in ONE Pallas program per split;
-        ops/split_megakernel_pallas.py).  Direct both-children
-        accumulation removes the parent-histogram read, the
-        smaller/larger selection + subtraction machinery and the
-        (L+1)-slot histogram state from the while-loop carry (the
-        round-4 "fixed-cost smoking gun": two contextual full-state
-        copies per split).  "xla" runs the identical math as plain XLA
-        ops — the oracle and the any-backend fallback form.  NOTE the
-        mega path's histogram chunk grid is the parent cover, so its
-        trees are bit-identical to the mega XLA oracle but only
-        numerically equivalent to the subtraction-path trees."""
-        mega_mode = str(getattr(config, "tpu_megakernel", "auto")
-                        or "off").lower()
-        self._use_mega = None
-        mega_eligible = (self._fast_search and self._plain_view
-                         and self.forced is None
-                         # leafwise linear gain: the mega bodies return
-                         # the 13-scalar split tiles, not the linear
-                         # candidate's child models — residual, see
-                         # ROADMAP item 3
-                         and not self._linear_gain
-                         and not self.extra_trees
-                         and self.feature_contri is None
-                         and parallel_mode == "serial"
-                         and self.F > 0
-                         and not self.has_categorical
-                         and self.cegb_lazy is None
-                         and self.B <= 256
-                         and (dataset.binned is not None
-                              or self._ingest is not None)
-                         and self._host_bin_dtype == np.uint8
-                         # the in-context doubling probe hooks the
-                         # per-split _hist_leaf calls, which the mega
-                         # path does not make — measuring "hist" with
-                         # mega active would silently read ~0
-                         and self._ab_double != "hist")
-        if mega_mode == "xla":
-            if mega_eligible:
-                self._use_mega = "xla"
-            else:
-                log.warning("tpu_megakernel=xla needs the plain "
-                            "all-numerical serial fast path; using the "
-                            "current split path")
-        elif mega_mode in ("auto", "pallas"):
-            if mega_eligible and self._use_pallas_part:
-                self._use_mega = "pallas"
-            elif mega_mode == "pallas":
-                log.warning("tpu_megakernel=pallas needs the Pallas "
-                            "partition geometry on a kernel-capable "
-                            "backend; using the current split path")
-        elif mega_mode != "off":
-            log.warning("unknown tpu_megakernel=%r; treating as off",
-                        mega_mode)
-        if self._use_mega is not None:
-            log.debug("split mega-kernel active (%s mode)", self._use_mega)
+        return self.plan.kernel_plan()
 
     def _rand_bins(self, key):
         """One random threshold per feature (reference:
@@ -847,17 +628,6 @@ class SerialTreeLearner:
     # ------------------------------------------------------------------
     @scopes.phase("histogram")
     def _hist_leaf(self, part_bins, part_ghi, start, cnt, scale=None):
-        if self._chunk_policy.adaptive:
-            # leaf-size-adaptive bands (eligibility guarantees the
-            # plain-XLA path with no in-context doubling); quantized
-            # integer carriers are exact at any width by construction
-            from ..ops.histogram import leaf_hist_banded
-            return leaf_hist_banded(
-                part_bins, part_ghi, start, cnt, num_bins=self.B,
-                policy=self._chunk_policy,
-                dtype=(jnp.bfloat16 if scale is not None
-                       else self._hist_dtype),
-                vary=self._pvary, num_groups=self.G)
         # quantized training rides INTEGER gradient carriers: the one-hot
         # matmuls run in bfloat16 (exact for the small int grid, double
         # MXU rate — the int16-histogram analog).  The histogram stays
@@ -869,19 +639,20 @@ class SerialTreeLearner:
         # multiply-subtract in some compilation contexts and not others,
         # so "identical" programs drifted by ULPs (the frontier-batched
         # body's bit-identity contract caught it, PERF.md round 12).
-        h = leaf_hist_slice(part_bins, part_ghi, start, cnt,
-                            num_bins=self.B, row_chunk=self.row_chunk,
-                            vary=self._pvary, num_groups=self.G,
-                            dtype=(jnp.bfloat16 if scale is not None
-                                   else self._hist_dtype))
-        if self._ab_double == "hist" and scale is None:
-            h = self._double_opaque(
-                h, lambda s2: leaf_hist_slice(
-                    part_bins, part_ghi, s2, cnt, num_bins=self.B,
-                    row_chunk=self.row_chunk, vary=self._pvary,
-                    num_groups=self.G, dtype=self._hist_dtype),
-                part_ghi, start)
-        return h
+        dtype = jnp.bfloat16 if scale is not None else jnp.float32
+        if self._chunk_policy.adaptive:
+            # leaf-size-adaptive bands (eligibility guarantees the
+            # plain-XLA path); quantized integer carriers are exact at
+            # any width by construction
+            from ..ops.histogram import leaf_hist_banded
+            return leaf_hist_banded(
+                part_bins, part_ghi, start, cnt, num_bins=self.B,
+                policy=self._chunk_policy, dtype=dtype,
+                vary=self._pvary, num_groups=self.G)
+        return leaf_hist_slice(part_bins, part_ghi, start, cnt,
+                               num_bins=self.B, row_chunk=self.row_chunk,
+                               vary=self._pvary, num_groups=self.G,
+                               dtype=dtype)
 
     @staticmethod
     def _scale_hist(h, scale):
@@ -896,20 +667,10 @@ class SerialTreeLearner:
     def _hist_leaf_flat(self, part_bins, part_ghi, start, cnt):
         """Smaller-child histogram directly in the lane-flattened (8, WL)
         slot layout of the Pallas hist-state RMW kernel."""
-        h = leaf_hist_slice(part_bins, part_ghi, start, cnt,
-                            num_bins=self.B, row_chunk=self.row_chunk,
-                            vary=self._pvary, num_groups=self.G,
-                            dtype=self._hist_dtype,
-                            flat_geom=self._flat_geom)
-        if self._ab_double == "hist":
-            h = self._double_opaque(
-                h, lambda s2: leaf_hist_slice(
-                    part_bins, part_ghi, s2, cnt, num_bins=self.B,
-                    row_chunk=self.row_chunk, vary=self._pvary,
-                    num_groups=self.G, dtype=self._hist_dtype,
-                    flat_geom=self._flat_geom),
-                part_ghi, start)
-        return h
+        return leaf_hist_slice(part_bins, part_ghi, start, cnt,
+                               num_bins=self.B, row_chunk=self.row_chunk,
+                               vary=self._pvary, num_groups=self.G,
+                               flat_geom=self._flat_geom)
 
     def _flatten_hist(self, h):
         """(G, B, 2) histogram -> one (8, WL) flat state slot."""
@@ -917,16 +678,6 @@ class SerialTreeLearner:
         x = jnp.moveaxis(h, 2, 0)                       # (2, G, B)
         x = jnp.pad(x, ((0, 0), (0, Gf - self.G), (0, Bf - self.B)))
         return x.reshape(8, WL)
-
-    @staticmethod
-    def _double_opaque(first, recompute, part_ghi, start):
-        """Measurement-only in-context doubling (tpu_ab_double): run the
-        component twice with a runtime-opaque perturbation so XLA can
-        neither CSE nor hoist the duplicate, and select the second
-        (bit-identical) result.  f32 * 0.0 is not folded (NaN rules)."""
-        opq = part_ghi[0, :1] * 0.0
-        second = recompute(start + opq[0].astype(jnp.int32))
-        return jnp.where(opq[0] < 1.0, second, first)
 
     def _goes_left(self, colv, scalars):
         """Per-row decision from raw group-column values.
@@ -996,7 +747,7 @@ class SerialTreeLearner:
         measured ~1.7x SLOWER end-to-end: the read-modify-write hazard on
         the loop-carried row buffers defeats XLA's in-place scheduling.)
         """
-        if self._use_pallas_part:
+        if self.plan.partition == "pallas":
             return self._partition_leaf_pallas(st, start, cnt, col,
                                                decision_scalars)
         pol = self._chunk_policy
@@ -1198,8 +949,7 @@ class SerialTreeLearner:
         pb, pg, sp, nl = partition_leaf_pallas(
             st["part_bins"], st["part_ghi"], st["sc_packed"],
             scalars, row_chunk=self.row_chunk, ghi_live=self._ghi_live,
-            pack_rowid=getattr(self, "_pack_rowid", False),
-            compact_radix=self._compact_radix, interpret=self._interp)
+            pack_rowid=self.plan.pack_rowid, interpret=self._interp)
         moved = {"part_bins": pb, "part_ghi": pg, "sc_packed": sp}
         return moved, nl[0, 0]
 
@@ -1216,7 +966,7 @@ class SerialTreeLearner:
                                                    unpack_hist4)
         bstart, isb, nb, dbin, mtype, thr, dl, is_cat, cat_set = \
             decision_scalars
-        if self._use_mega == "pallas":
+        if self.plan.mega == "pallas":
             from ..ops.partition_pallas import make_scalars
             scalars = make_scalars(start, cnt, col, bstart, isb, nb, dbin,
                                    mtype, thr, dl)
@@ -1224,8 +974,7 @@ class SerialTreeLearner:
                 st["part_bins"], st["part_ghi"], st["sc_packed"], scalars,
                 row_chunk=self.row_chunk, num_bins=self.B,
                 num_groups=self.G, ghi_live=self._ghi_live,
-                pack_rowid=getattr(self, "_pack_rowid", False),
-                compact_radix=self._compact_radix, interpret=self._interp)
+                pack_rowid=self.plan.pack_rowid, interpret=self._interp)
             moved = {"part_bins": pb, "part_ghi": pg, "sc_packed": sp}
             left_cnt = nl[0, 0]
         else:
@@ -1441,7 +1190,7 @@ class SerialTreeLearner:
                 hist_group, sum_g, sum_h, cnt, local_cnt, depth, cmin, cmax,
                 parent_out, feature_mask, feat_used, lazy_cnt=lazy_cnt,
                 rand_bins=rand_bins)
-        if self._scatter_groups:
+        if self.plan.scatter_groups:
             # each device searches only the groups it owns post-scatter;
             # the election in _sync_best agrees on the global winner
             d = jax.lax.axis_index(self.axis_name)
@@ -1480,13 +1229,13 @@ class SerialTreeLearner:
             lazy_term = self.cegb_lazy * lazy_cnt.astype(jnp.float32)
             cegb_delta = (lazy_term if cegb_delta is None
                           else cegb_delta + lazy_term)
-        if self._linear_gain:
+        if self.plan.linear_gain:
             return split_ops.find_best_split_linear(
                 feat_hist, self.ctx, sum_g, sum_h, cnt,
                 self.l2, self.min_gain_to_split, self.min_data_in_leaf,
                 self.min_sum_hessian, self._rep_vals, self.linear_lambda,
                 feature_mask, rand_bins=rand_bins)
-        if (self._fast_search and cegb_delta is None
+        if (self.plan.fast_search and cegb_delta is None
                 and not with_feature_gains):
             return split_ops.find_best_split_fast(
                 feat_hist, self.ctx, sum_g, sum_h, cnt,
@@ -1860,7 +1609,7 @@ class SerialTreeLearner:
         masks to owned features, and the winner is elected by the same
         all-gather arg-max the feature-parallel mode uses."""
         if self.axis_name is not None and self.parallel_mode == "data":
-            if self._scatter_groups:
+            if self.plan.scatter_groups:
                 per = self._scatter_per
                 Gp = per * self.num_shards
                 xp = jnp.pad(x, ((0, Gp - self.G), (0, 0), (0, 0)))
@@ -1891,7 +1640,7 @@ class SerialTreeLearner:
         feature range, so the arg-max's first-max tie-break matches the
         serial scan order."""
         if self.axis_name is None or not (
-                self.parallel_mode == "feature" or self._scatter_groups):
+                self.parallel_mode == "feature" or self.plan.scatter_groups):
             return best
         gathered = jax.tree.map(
             lambda a: jax.lax.all_gather(a, self.axis_name), best)
@@ -1911,7 +1660,7 @@ class SerialTreeLearner:
         ``lgbm.bookkeeping``: leaf election, the packed-scalar gathers,
         node/leaf matrix writes, the frontier replay, renumbering and the
         undo of pruned partitions."""
-        if self.frontier_k > 1:
+        if self.plan.frontier_k > 1:
             # batched frontier growth (the eligibility gate guarantees
             # feat_used_init/aux0 are absent: no CEGB in batched mode)
             return self._build_tree_frontier(part_bins, part_ghi0, bag_cnt,
@@ -1941,7 +1690,7 @@ class SerialTreeLearner:
         # in voting mode root_hist stays LOCAL; in scatter mode only the
         # owned groups survive in root_hist — either way the leaf totals
         # come from the LOCAL histogram reduced across ranks
-        if self.parallel_mode == "voting" or self._scatter_groups:
+        if self.parallel_mode == "voting" or self.plan.scatter_groups:
             sum_g = self._psum_scalar(root_local[0, :, 0].sum())
             sum_h = self._psum_scalar(root_local[0, :, 1].sum())
         else:
@@ -1992,7 +1741,7 @@ class SerialTreeLearner:
             best0.right_sum_g, best0.right_sum_h,
             best0.left_output, best0.right_output,
             best0.is_cat.astype(jnp.float32), _i2f(root_forced)])
-        if self._linear_gain:
+        if self.plan.linear_gain:
             # the root's own whole-leaf model from its search (a
             # root-only tree still predicts linearly)
             col0 = jnp.concatenate([col0, jnp.stack([
@@ -2006,8 +1755,8 @@ class SerialTreeLearner:
             .at[LM_FORCED].set(_i2f(jnp.full((L + 1,), -1, jnp.int32))) \
             .at[:, 0].set(col0)
 
-        use_mega = self._use_mega is not None
-        use_flat = (self._use_flat_hist and hist_scale is None
+        use_mega = self.plan.mega != "off"
+        use_flat = (self.plan.hist_state == "flat" and hist_scale is None
                     and not use_mega)
         state = {
             "s": jnp.int32(0),
@@ -2037,7 +1786,7 @@ class SerialTreeLearner:
                 (L + 1, self.BF), jnp.bool_).at[0].set(best0.cat_set)
             state["node_cat_set"] = jnp.zeros((nodes + 1, self.BF),
                                               jnp.bool_)
-        if self._use_pallas_part:
+        if self.plan.partition == "pallas":
             from ..ops.partition_pallas import sc_rows_for
             state["sc_packed"] = jnp.zeros(
                 (sc_rows_for(self._pb_rows), part_bins.shape[1]),
@@ -2273,7 +2022,7 @@ class SerialTreeLearner:
                 if use_mega:
                     hist = None
                     hist_left = hist_right = None
-                    if not self._use_pallas_search:
+                    if self.plan.search != "pallas":
                         hl_g, hl_h, hr_g, hr_h = mega_hists
                         hist_left = jnp.stack(
                             [hl_g[:, :B], hl_h[:, :B]], axis=2)
@@ -2429,7 +2178,7 @@ class SerialTreeLearner:
                 head_r = child_head(r_start, right_cnt, right_cnt_g, rsg,
                                     rsh, rout, r_cmin, r_cmax, 1)
 
-                if self._use_pallas_search:
+                if self.plan.search == "pallas":
                     # both children's searches as ONE kernel emitting the
                     # packed [LM_BGAIN..LM_BISCAT] leafmat segments
                     from ..ops.split_pallas import best_split_pair_pallas
@@ -2483,22 +2232,6 @@ class SerialTreeLearner:
                             min_sum_hessian=self.min_sum_hessian,
                             max_depth=self.max_depth,
                             interpret=self._interp)
-                    if self._ab_double == "search":
-                        # measurement-only in-context doubling: the
-                        # opaque select blocks CSE; results bit-identical
-                        opq = moved["part_ghi"][0, :1] * 0.0
-                        with scopes.scope("search"):
-                            tile2 = best_split_pair_pallas(
-                                jnp.where(opq[0] < 1.0, hg, hg + 1.0), hh,
-                                self._fmeta_pair, info,
-                                l1=self.l1, l2=self.l2,
-                                max_delta_step=self.max_delta_step,
-                                min_gain_to_split=self.min_gain_to_split,
-                                min_data_in_leaf=self.min_data_in_leaf,
-                                min_sum_hessian=self.min_sum_hessian,
-                                max_depth=self.max_depth,
-                                interpret=self._interp)
-                        tile = jnp.where(opq[0] < 1.0, tile2, tile)
                     col_l = jnp.concatenate(
                         [head_l, tile[0, :13],
                          _i2f(forced_l)[None]])
@@ -2585,7 +2318,7 @@ class SerialTreeLearner:
                         [head_l, seg13(best_l), _i2f(forced_l)[None]])
                     col_r = jnp.concatenate(
                         [head_r, seg13(best_r), _i2f(forced_r)[None]])
-                    if self._linear_gain:
+                    if self.plan.linear_gain:
                         # each child's model comes from its OWN search
                         # (best whole-leaf single-feature fit)
                         col_l = jnp.concatenate([col_l, jnp.stack([
@@ -2709,13 +2442,13 @@ class SerialTreeLearner:
         partition/histogram passes (the payload-bound work) looping over
         the K selected leaves.
         """
-        L, G, B, F, K = self.L, self.G, self.B, self.F, self.frontier_k
+        L, G, B, F, K = self.L, self.G, self.B, self.F, self.plan.frontier_k
         MS = (L - 1) + (K - 1)      # split slots: budget + speculative slack
         SL = MS + 2                 # leaf slots incl. one trash slot
         TRASH = SL - 1
         NI = 2 * MS + 2             # items: root + 2 per split + trash
         IT = NI - 1                 # trash item
-        use_mega = self._use_mega is not None
+        use_mega = self.plan.mega != "off"
         neg_inf = jnp.float32(-jnp.inf)
         pos_inf = jnp.float32(jnp.inf)
 
@@ -2787,7 +2520,7 @@ class SerialTreeLearner:
             state["best_cat_set"] = jnp.zeros(
                 (SL, self.BF), jnp.bool_).at[0].set(best0.cat_set)
             state["node_cat_set"] = jnp.zeros((MS + 1, self.BF), jnp.bool_)
-        if self._use_pallas_part:
+        if self.plan.partition == "pallas":
             from ..ops.partition_pallas import sc_rows_for
             state["sc_packed"] = jnp.zeros(
                 (sc_rows_for(self._pb_rows), part_bins.shape[1]), jnp.int32)
@@ -2795,7 +2528,7 @@ class SerialTreeLearner:
             state["sc32"] = jnp.zeros((G + self._ghi_rows,
                                        part_bins.shape[1]), jnp.int32)
         buf_keys = ("part_bins", "part_ghi", "snap",
-                    "sc_packed" if self._use_pallas_part else "sc32")
+                    "sc_packed" if self.plan.partition == "pallas" else "sc32")
 
         def cond(st):
             return (~st["done"]) & (st["made"] < MS)
@@ -2859,7 +2592,7 @@ class SerialTreeLearner:
             # lanes never touched) ----
             depth_c = pbits[LM_DEPTH] + 1
             bufs0 = {kk: st[kk] for kk in buf_keys}
-            use_ppair = use_mega and self._use_pallas_search
+            use_ppair = use_mega and self.plan.search == "pallas"
             if use_mega:
                 acc0 = tuple(jnp.zeros((K, G, B), jnp.float32)
                              for _ in range(4))
@@ -3203,7 +2936,7 @@ class SerialTreeLearner:
         reads them — only LM_BGAIN, which the snapshot preserves).
         Output shapes match the K=1 path exactly: leafmat (NLF, L+1),
         nodemat (NND, L), s = committed split count."""
-        L, K = self.L, self.frontier_k
+        L, K = self.L, self.plan.frontier_k
         MS = (L - 1) + (K - 1)
         NI = 2 * MS + 2
         nodes = self.max_splits
@@ -3348,7 +3081,7 @@ class SerialTreeLearner:
             "node_missing_type": ni(ND_MISSING),
             "node_is_cat": nm[ND_IS_CAT] > 0.5,
         })
-        if self._linear_gain:
+        if self.plan.linear_gain:
             # per-leaf linear model: const + coeff over the raw value
             # of leaf_lin_feat (ORIGINAL feature id, from the leaf's
             # own search — boosting._set_leafwise_linear consumes it)
@@ -3377,7 +3110,7 @@ class SerialTreeLearner:
         hess_p = jnp.pad(hess, (C, tail))
         iota = jax.lax.iota(jnp.int32, self.N_pad)
         rowid = jnp.where((iota >= C) & (iota < C + self.N), iota - C, self.N)
-        # row writes rather than jnp.stack+concat; tpu_selfcheck.py step 4
+        # row writes rather than jnp.stack+concat; tpu_selfcheck.py step 3
         # checks the bitcast rowid row survives a full build on the chip
         part_ghi0 = jnp.zeros((self._ghi_rows, self.N_pad), jnp.float32) \
             .at[0].set(grad_p).at[1].set(hess_p) \

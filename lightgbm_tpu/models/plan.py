@@ -1,0 +1,334 @@
+"""Which split-step program a tree learner runs, decided in one place.
+
+``resolve`` maps a ``PlanFacts`` record (plain Python values: the backend's
+name, the dataset's shape, which tree features are on, and the ``tpu_*``
+option values) to a ``SplitPlan``: one field per decision, plus ``why``,
+which holds for every path NOT taken the conditions that excluded it.
+Nothing here touches an array, a ``Dataset``, a ``Config`` or JAX, so the
+plan of any shape can be read without building a learner:
+
+    >>> from lightgbm_tpu.config import Config
+    >>> from lightgbm_tpu.models import plan
+    >>> options = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
+    >>> p = plan.resolve(plan.PlanFacts(
+    ...     backend="tpu", rows=42_000_000, F=28, G=28, B=255,
+    ...     num_leaves=255, **options))
+    >>> p.mega, p.frontier_k, p.why["mega"]
+
+``SerialTreeLearner.__init__`` gathers the facts, calls ``resolve`` once,
+keeps the result as ``learner.plan`` and builds only the arrays that plan
+needs.  A kernel named in the plan that cannot compile raises from the
+first build: it is never swapped for another.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, fields
+from typing import Any, Dict, List, Tuple
+
+from ..ops import chunkpolicy
+
+# the f32 count cumsum of the op-packed fast search is exact below this
+FAST_SEARCH_MAX_ROWS = 1 << 24
+
+
+@dataclass(frozen=True, kw_only=True)
+class PlanFacts:
+    """Everything the choice of a split-step program may depend on."""
+
+    backend: str                  # jax.default_backend(), passed in
+    interpret: bool = False       # tpu_kernel_interpret
+    rows: int                     # rows this learner holds (local shard)
+    F: int                        # used features
+    G: int                        # feature groups
+    B: int                        # bins of the widest group
+    num_leaves: int
+    host_bin_dtype: str = "uint8"
+    has_bins: bool = True         # a binned matrix or a device ingest
+    plain_view: bool = True       # one unbundled group per feature, in order
+    has_categorical: bool = False
+    use_mc: bool = False          # monotone constraints on a used feature
+    has_cegb: bool = False
+    cegb_lazy: bool = False
+    path_smooth: float = 0.0
+    forced: bool = False          # forced splits loaded
+    extra_trees: bool = False
+    has_bynode: bool = False      # 0 < feature_fraction_bynode < 1
+    feature_contri: bool = False
+    interaction_constraints: bool = False
+    l1: float = 0.0
+    max_delta_step: float = 0.0
+    linear_gain_requested: bool = False   # linear_tree_mode=leafwise_gain
+    parallel_mode: str = "serial"
+    axis_name: bool = False       # runs inside shard_map
+    num_shards: int = 1
+    # the option values, as config.py holds them (its defaults are the
+    # only defaults: none here)
+    tpu_partition_kernel: str
+    tpu_megakernel: str
+    tpu_frontier_k: str
+    tpu_hist_state: str
+    tpu_chunk_policy: str
+    tpu_row_chunk: str
+    tpu_pack_rowid: bool
+    tpu_data_hist_sync: str
+
+
+OPTION_FIELDS = tuple(f.name for f in fields(PlanFacts)
+                      if f.name.startswith("tpu_"))
+
+
+@dataclass(frozen=True, kw_only=True)
+class SplitPlan:
+    partition: str          # "pallas" | "xla"
+    fast_search: bool       # op-packed XLA search (else the general one)
+    search: str             # "pallas" (pair kernel) | "xla"
+    mega: str               # "pallas" | "xla" | "off"
+    frontier_k: int
+    hist_state: str         # "flat" (Pallas RMW) | "xla" ((L+1,G,B,2))
+    row_chunk: int          # rows per partition/histogram chunk
+    chunk_adaptive: bool
+    pack_rowid: bool
+    scatter_groups: bool    # ReduceScatter histogram ownership
+    linear_gain: bool
+    # decision -> "<value taken> (<what excluded the other path>)"
+    why: Dict[str, str]
+    # explicit requests that could not be honoured, as warnings to log
+    unmet: Tuple[str, ...] = ()
+
+    def kernel_plan(self) -> Dict[str, Any]:
+        """The printable record ``GBDT.kernel_plan`` extends."""
+        return {"partition": self.partition, "search": self.search,
+                "hist_state": self.hist_state, "mega": self.mega,
+                "frontier_k": self.frontier_k}
+
+
+def _given(*pairs) -> List[str]:
+    return [text for cond, text in pairs if cond]
+
+
+def _said(value, reasons: List[str]) -> str:
+    return f"{value} ({'; '.join(reasons)})"
+
+
+def _refused(request: str, reasons: List[str], instead: str) -> str:
+    return f"{request} cannot be honoured ({'; '.join(reasons)}); {instead}"
+
+
+def resolve(f: PlanFacts) -> SplitPlan:
+    why: Dict[str, str] = {}
+    unmet: List[str] = []
+    serial = f.parallel_mode == "serial"
+    parallel = _given(
+        (not serial or f.axis_name, f"tree_learner={f.parallel_mode}"))
+    no_features = _given((f.F == 0, "no usable features"))
+    u8_bins = _given(
+        (not f.has_bins, "no binned matrix or device ingest"),
+        (f.host_bin_dtype != "uint8",
+         f"{f.host_bin_dtype} bins: the kernels' tiles are uint8"))
+
+    # ---- Pallas partition kernel (ops/partition_pallas.py) ----
+    block = _given(
+        (f.tpu_partition_kernel != "pallas",
+         f"tpu_partition_kernel={f.tpu_partition_kernel}"),
+        (f.backend != "tpu" and not f.interpret,
+         f"backend {f.backend} without tpu_kernel_interpret"),
+        (f.has_categorical,
+         "categorical features: the kernel decides numerical splits only"),
+        (f.cegb_lazy, "cegb_penalty_feature_lazy: the kernel does not "
+                      "carry its per-row bitset"),
+        (not serial, f"tree_learner={f.parallel_mode}"),
+    ) + no_features + u8_bins
+    pallas_part = not block
+    if block:
+        why["partition"] = _said("xla", block)
+    partition_xla = _given((not pallas_part, "partition=xla"))
+
+    # ---- op-packed fast search ----
+    not_fast = _given(
+        (f.has_categorical, "categorical features"),
+        (f.use_mc, "monotone constraints"),
+        (f.has_cegb, "CEGB penalties"),
+        (f.path_smooth > 0.0, "path_smooth > 0"),
+        (f.rows >= FAST_SEARCH_MAX_ROWS,
+         f"rows {f.rows:,} >= 2^24: the f32 count cumsum of the fast "
+         "search is exact only below it"))
+    fast = not not_fast
+    if not_fast:
+        why["fast_search"] = _said("off", not_fast)
+
+    # ---- leafwise-linear gain (ops/split.py:find_best_split_linear):
+    # the fast-search envelope less the refinements whose bodies
+    # re-derive candidate statistics ----
+    linear_gain = f.linear_gain_requested
+    if linear_gain:
+        block = not_fast + _given(
+            (f.forced, "forced splits"),
+            (bool(parallel), "parallel tree learners"),
+            (f.l1 > 0.0, "lambda_l1 > 0"),
+            (f.max_delta_step > 0.0, "max_delta_step > 0"),
+            (f.feature_contri, "feature_contri")) + no_features
+        if block:
+            linear_gain = False
+            why["linear_gain"] = _said("off", block)
+            unmet.append(
+                "linear_tree_mode=leafwise_gain is not supported with "
+                + ", ".join(block)
+                + "; falling back to the post-hoc refit mode")
+
+    # ---- ReduceScatter histogram ownership (data-parallel only) ----
+    scatter = False
+    if f.parallel_mode == "data":
+        block = _given(
+            (not f.axis_name, "not inside shard_map"),
+            (f.tpu_data_hist_sync != "scatter",
+             f"tpu_data_hist_sync={f.tpu_data_hist_sync}"),
+        ) + not_fast + _given(
+            (not f.plain_view, "bundled feature groups"),
+            (f.forced, "forced splits"),
+            (f.num_shards <= 1, "one shard"),
+            (f.F < f.num_shards,
+             f"{f.F} features < {f.num_shards} shards"))
+        scatter = not block
+        if block:
+            why["scatter_groups"] = _said("off", block)
+
+    # ---- what the pair-search kernel and the mega-kernel share: the
+    # plain all-numerical fast path, whose 13-scalar split tile carries
+    # neither linear child models nor per-feature refinements ----
+    not_plain = not_fast + _given(
+        (not f.plain_view, "bundled feature groups: the kernels read one "
+                           "group per feature"),
+        (f.forced, "forced splits"),
+        (linear_gain, "linear_tree_mode=leafwise_gain"),
+        (f.extra_trees, "extra_trees"),
+        (f.feature_contri, "feature_contri"))
+
+    # ---- Pallas pair search (ops/split_pallas.py) ----
+    block = partition_xla + not_plain
+    search = "xla" if block else "pallas"
+    if block:
+        why["search"] = _said("xla", block)
+
+    # ---- split mega-kernel (ops/split_megakernel_pallas.py): "xla" is
+    # the same math as plain XLA operations, on any backend ----
+    mode = str(f.tpu_megakernel or "off").lower()
+    block = not_plain + _given(
+        (not serial, f"tree_learner={f.parallel_mode}"),
+    ) + no_features + _given(
+        (f.B > 256, f"{f.B} bins in a group > 256")) + u8_bins
+    mega = "off"
+    if mode == "xla":
+        if not block:
+            mega = "xla"
+            why["mega"] = "xla (tpu_megakernel=xla)"
+    elif mode in ("auto", "pallas"):
+        block = partition_xla + block
+        if not block:
+            mega = "pallas"
+    elif mode == "off":
+        block = ["tpu_megakernel=off"]
+    else:
+        block = [f"unknown tpu_megakernel={f.tpu_megakernel!r}"]
+        unmet.append(f"unknown tpu_megakernel={f.tpu_megakernel!r}; "
+                     "treating as off")
+    if mega == "off":
+        why["mega"] = _said("off", block)
+        if mode in ("xla", "pallas"):
+            unmet.append(_refused(f"tpu_megakernel={mode}", block,
+                                  "using the current split path"))
+
+    # ---- frontier-batched growth: order-dependent machinery stays on
+    # the K=1 body ----
+    block = _given(
+        (bool(parallel), "parallel tree learners"),
+        (f.forced, "forced splits"),
+        (linear_gain, "linear_tree_mode=leafwise_gain"),
+        (f.use_mc, "monotone constraints"),
+        (f.has_cegb, "CEGB penalties"),
+        (f.extra_trees, "extra_trees"),
+        (f.has_bynode, "feature_fraction_bynode"),
+        (f.interaction_constraints, "interaction constraints"),
+        (search == "pallas" and mega == "off",
+         "search=pallas with mega=off: the batched body has the pair "
+         "search only on the mega path"),
+    ) + no_features
+    spec = str(f.tpu_frontier_k or "auto").strip().lower()
+    if spec == "auto":
+        # off the TPU auto stays at 1: the larger traced program taxes
+        # every fresh compile, which test-sized trainings pay
+        if f.backend != "tpu":
+            block = block + [f"tpu_frontier_k=auto is 1 on backend "
+                             f"{f.backend}"]
+        k_req = 1 if block else 4
+    else:
+        try:
+            k_req = int(spec)
+        except ValueError:
+            raise ValueError("tpu_frontier_k must be 'auto' or a "
+                             f"positive integer, got {spec!r}")
+        if k_req < 1:
+            raise ValueError("tpu_frontier_k must be >= 1")
+        if k_req > 1 and block:
+            unmet.append(_refused(f"tpu_frontier_k={k_req}", block,
+                                  "using 1"))
+            k_req = 1
+        elif k_req == 1:
+            block = ["tpu_frontier_k=1"]
+    frontier_k = max(1, min(k_req, f.num_leaves - 1))
+    if frontier_k == 1 and k_req > 1:
+        block = [f"num_leaves={f.num_leaves}"]
+    if frontier_k == 1:
+        why["frontier_k"] = _said(1, block)
+
+    # ---- flat histogram state + Pallas RMW (ops/hist_state_pallas.py):
+    # the mega path holds no state, the batched body moves its rows
+    # itself ----
+    block = _given(
+        (search != "pallas", "search=xla"),
+        (mega != "off", f"mega={mega}: no histogram state"),
+        (frontier_k > 1, f"frontier_k={frontier_k}"),
+        (f.tpu_hist_state == "xla", "tpu_hist_state=xla"))
+    hist_state = "xla" if block else "flat"
+    if block:
+        why["hist_state"] = _said("xla", block)
+
+    # ---- leaf-size-adaptive chunks (ops/chunkpolicy.py): the plain XLA
+    # serial paths only, the kernels keep their base grid ----
+    block = parallel + _given(
+        (pallas_part, "partition=pallas: the kernels keep the base "
+                      "grid")) + no_features
+    row_chunk, policy = chunkpolicy.resolve(
+        f.tpu_chunk_policy, f.tpu_row_chunk, f.rows, f.num_leaves,
+        eligible=not block)
+    chunk_mode = str(f.tpu_chunk_policy or "auto").strip().lower()
+    if not policy.adaptive:
+        if block and chunk_mode == "adaptive":
+            unmet.append(_refused("tpu_chunk_policy=adaptive", block,
+                                  "using the fixed grid"))
+        why["chunk_adaptive"] = _said("fixed", block or _given(
+            (chunk_mode == "fixed", "tpu_chunk_policy=fixed"),
+            (len(policy.sizes) < 2,
+             f"row_chunk {row_chunk} has no narrower menu width"),
+        ) or [f"auto: (num_leaves-1) * {row_chunk} <= rows, the average "
+              "leaf fills a chunk"])
+
+    # ---- rowid in the spare packed-bin bytes ----
+    pack_rowid = False
+    if f.tpu_pack_rowid:
+        g32 = -(-f.G // 32) * 32
+        block = partition_xla + _given(
+            (g32 - f.G < 4,
+             f"{f.G} groups leave {g32 - f.G} spare rows of {g32}: "
+             "needs 4"))
+        pack_rowid = not block
+        if block:
+            why["pack_rowid"] = _said("off", block)
+
+    return SplitPlan(
+        partition="pallas" if pallas_part else "xla", fast_search=fast,
+        search=search, mega=mega, frontier_k=frontier_k,
+        hist_state=hist_state, row_chunk=row_chunk,
+        chunk_adaptive=policy.adaptive, pack_rowid=pack_rowid,
+        scatter_groups=scatter, linear_gain=linear_gain, why=why,
+        unmet=tuple(unmet))

@@ -73,7 +73,7 @@ _MAD_TO_SIGMA = 1.4826
 # fork the series
 _FINGERPRINT_KNOBS = (
     "tpu_row_chunk", "tpu_chunk_policy", "tpu_frontier_k",
-    "tpu_megakernel", "tpu_compact_radix", "tpu_kernel_interpret",
+    "tpu_megakernel", "tpu_kernel_interpret",
     "construct_device", "tree_learner", "num_leaves", "max_bin",
     "telemetry", "health",
 )

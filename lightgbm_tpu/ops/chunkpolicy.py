@@ -45,18 +45,17 @@ bit-identical to ``fixed``):
   tests/test_chunkpolicy.py pins the equivalence; quantized integer
   carriers are exact at any width by construction).
 
-``tpu_row_chunk=auto`` / ``tpu_chunk_policy=auto`` consult the PR-11
-``BENCH_history.jsonl`` trajectory first: an ``ab_bench --chunk``
-sweep records the winning base width and the measured adaptive
-speedup under the host/shape fingerprint (obs/regress.py), and a
-same-fingerprint entry overrides the static heuristics below.
+``tpu_row_chunk=auto`` is the constant ``DEFAULT_ROW_CHUNK`` and
+``tpu_chunk_policy=auto`` the small-leaf rule in ``resolve``: both follow
+from the training parameters and the data's shape alone and consult
+nothing else.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 # Smaller menu widths considered below the base width (descending).
 # The menu is the base width plus every entry strictly below it, capped
@@ -68,19 +67,14 @@ MENU_LADDER = (1024, 256, 64)
 # partition passes may use every menu width (integer-exact).
 HIST_EXACT_MAX = 256
 
-# default base width when nothing measured says otherwise
+# base width under tpu_row_chunk=auto
 # (PERF.md round 3: best end-to-end on v5e at equal slope)
 DEFAULT_ROW_CHUNK = 4096
 
-# trajectory tool name the ab_bench --chunk sweep records its winner
-# under; resolve() only trusts same-fingerprint entries of this tool
-SWEEP_TOOL = "chunk_sweep"
-
 __all__ = [
     "ChunkPolicy", "DEFAULT_ROW_CHUNK", "HIST_EXACT_MAX", "MENU_LADDER",
-    "SWEEP_TOOL", "consult_history", "note_variant", "parse_row_chunk",
-    "resolve", "resolve_base", "reset_variant_log", "sweep_fingerprint",
-    "variant_log", "waste_stats",
+    "note_variant", "parse_row_chunk", "resolve", "resolve_base",
+    "reset_variant_log", "variant_log", "waste_stats",
 ]
 
 
@@ -191,9 +185,8 @@ class ChunkPolicy:
 
 
 def parse_row_chunk(spec) -> Optional[int]:
-    """``tpu_row_chunk`` accepts an integer or ``auto`` (consult the
-    measured trajectory, then the static default).  Returns None for
-    auto."""
+    """``tpu_row_chunk`` accepts an integer or ``auto`` (the constant
+    ``DEFAULT_ROW_CHUNK``).  Returns None for auto."""
     s = str(spec).strip().lower()
     if s in ("auto", ""):
         return None
@@ -210,124 +203,46 @@ def parse_row_chunk(spec) -> Optional[int]:
     return v
 
 
-# ---------------------------------------------------------------------------
-# trajectory consult (ROADMAP item 7 slice): the ab_bench --chunk sweep
-# records its winner keyed by the host/shape fingerprint; auto modes
-# trust a same-fingerprint entry over the static heuristics.
-# ---------------------------------------------------------------------------
-def sweep_fingerprint(rows: Optional[int], features: Optional[int]
-                      ) -> Dict[str, Any]:
-    """The fingerprint chunk-sweep entries are keyed by: hardware +
-    shape band only.  Deliberately knob-free — the sweep's JOB is to
-    choose the knob, so the knob must not fork its series."""
-    from ..obs import regress
-    return regress.fingerprint(config={}, rows=rows, features=features)
+def _pow2ceil(x: int) -> int:
+    return 1 << max(x - 1, 0).bit_length()
 
 
-# (path, mtime, size) -> parsed entries: learner/dataset construction
-# consults per Booster under the default auto modes, and re-parsing a
-# growing committed trajectory per fold would be O(folds x file size)
-_HISTORY_CACHE: Dict[str, Any] = {}
-
-
-def _read_history_cached(path: Optional[str]):
-    import os
-
-    from ..obs import regress
-    real = path or regress.default_path()
-    try:
-        st = os.stat(real)
-        stamp = (st.st_mtime_ns, st.st_size)
-    except OSError:
-        stamp = None
-    if (_HISTORY_CACHE.get("path") == real
-            and _HISTORY_CACHE.get("stamp") == stamp):
-        return _HISTORY_CACHE["entries"]
-    entries, _ = regress.read_history(real)
-    _HISTORY_CACHE.update(path=real, stamp=stamp, entries=entries)
-    return entries
-
-
-def consult_history(rows: Optional[int], features: Optional[int],
-                    path: Optional[str] = None) -> Dict[str, Any]:
-    """Latest same-fingerprint ``chunk_sweep`` verdict, or {}.
-
-    Recognized metrics: ``best_row_chunk`` (the sweep's winning base
-    width) and ``adaptive_speedup`` (fixed/adaptive wall ratio; > 1
-    means adaptive won on this hardware/shape)."""
-    from ..obs import regress
-    try:
-        key = regress.fingerprint_key(sweep_fingerprint(rows, features))
-        entries = _read_history_cached(path)
-    except Exception:
-        return {}
-    out: Dict[str, Any] = {}
-    for e in entries:
-        if e.get("aborted") or e.get("tool") != SWEEP_TOOL:
-            continue
-        if e.get("fingerprint_key") != key:
-            continue
-        m = e.get("metrics") or {}
-        if "best_row_chunk" in m:
-            out["best_row_chunk"] = int(m["best_row_chunk"])
-        if "adaptive_speedup" in m:
-            out["adaptive_speedup"] = float(m["adaptive_speedup"])
-    return out
-
-
-def resolve_base(config, rows: Optional[int] = None,
-                 features: Optional[int] = None) -> int:
+def resolve_base(row_chunk) -> int:
     """Uncapped base chunk width: the explicit ``tpu_row_chunk`` value,
-    or — under ``auto`` — a same-fingerprint chunk-sweep winner from
-    the trajectory, else the static default.  Dataset construction and
-    the learner both resolve through here so the streamed ingest
-    geometry matches the training geometry."""
-    spec = parse_row_chunk(getattr(config, "tpu_row_chunk",
-                                   DEFAULT_ROW_CHUNK))
-    if spec is None:
-        spec = int(consult_history(rows, features).get(
-            "best_row_chunk", DEFAULT_ROW_CHUNK))
-    return spec
+    or the constant under ``auto``.  Dataset construction and the
+    learner both resolve through here so the streamed ingest geometry
+    matches the training geometry."""
+    spec = parse_row_chunk(row_chunk)
+    return DEFAULT_ROW_CHUNK if spec is None else spec
 
 
-def resolve(config, num_data: int, num_leaves: int,
-            eligible: bool, base: int,
-            features: Optional[int] = None) -> Tuple[int, "ChunkPolicy"]:
-    """(base row chunk, policy) for one learner.
+def resolve(chunk_policy, row_chunk, num_data: int, num_leaves: int,
+            eligible: bool) -> Tuple[int, "ChunkPolicy"]:
+    """(base row chunk, policy) for one learner from the two option
+    values ``tpu_chunk_policy`` and ``tpu_row_chunk``.
 
-    ``base`` is the learner's ALREADY-derived chunk width (it owns the
-    pow2/geometry caps — one derivation site, so ``policy.base`` can
-    never drift from the grid the partition loops stride).
-    ``eligible`` gates the adaptive mode: the caller owns the path
-    checks (plain XLA hist/partition, serial mode, f32 hist dtype, no
-    in-context doubling).
+    The base is derived HERE and nowhere else (a power of two, no wider
+    than the rows need and than the partition's packed sort key allows),
+    so ``policy.base`` can never drift from the grid the partition loops
+    stride.  ``eligible`` gates the adaptive mode: the caller
+    (models/plan.py) owns the path checks and the warning for an
+    explicit ``adaptive`` it refuses.
     """
-    mode = str(getattr(config, "tpu_chunk_policy", "auto")
-               or "auto").strip().lower()
+    base = _pow2ceil(min(resolve_base(row_chunk),
+                         max(_pow2ceil(num_data), 256)))
+    # the partition packs (dest << bits) | src into one uint32 sort key
+    base = min(base, 1 << 15)
+    mode = str(chunk_policy or "auto").strip().lower()
     if mode not in ("auto", "fixed", "adaptive"):
         mode = "auto"      # Config._post_process already warned
     if mode == "fixed" or not eligible:
-        if mode == "adaptive":
-            from ..utils import log
-            log.warning(
-                "tpu_chunk_policy=adaptive needs the plain XLA serial "
-                "tree path (no Pallas hist/partition/mega kernels, "
-                "parallel learners, tpu_ab_double or non-f32 hist "
-                "dtype); using the fixed grid")
         return base, ChunkPolicy(base, adaptive=False)
-    if mode == "auto":
-        verdict = consult_history(num_data, features)
-        speed = verdict.get("adaptive_speedup")
-        if speed is not None:
-            adaptive = speed > 1.0
-        else:
-            # small-leaf-regime heuristic: adaptive pays when the
-            # fixed grid's worst case (one base chunk per split)
-            # exceeds the data actually touched per tree level —
-            # i.e. when the average leaf is smaller than the chunk
-            adaptive = max(num_leaves - 1, 1) * base > num_data
-    else:
-        adaptive = True
+    # auto, the small-leaf regime: adaptive pays when the fixed grid's
+    # worst case (one base chunk per split) exceeds the data actually
+    # touched per tree level, i.e. when the average leaf is smaller
+    # than the chunk
+    adaptive = (mode == "adaptive"
+                or max(num_leaves - 1, 1) * base > num_data)
     policy = ChunkPolicy(base, adaptive=adaptive)
     if len(policy.sizes) < 2:
         policy = ChunkPolicy(base, adaptive=False)

@@ -132,57 +132,6 @@ def _compact(payload, flag, shift0, C, logc):
     return aug[0:P]
 
 
-def _compact_radix4(payload, flag, shift0, C, logc):
-    """Same contract as ``_compact`` but consuming the deficit TWO bits
-    per step (radix-4): ceil(logc/2) network steps instead of logc.
-
-    Each merged step moves every live lane by digit * 4^k where digit is
-    the lane's k-th base-4 deficit digit.  Destinations after a merged
-    step equal the binary network's positions after its two constituent
-    steps, which are collision-free, so the merged move is injective on
-    live lanes and the roll-select mechanism stays sound.  The metadata
-    row rides the SAME rolls as the payload (one (P+1, C) roll per
-    distance instead of separate payload+meta rolls), so a step costs 3
-    rolls + 3 selects where two binary steps cost 4 rolls + 4 selects
-    plus twice the mask arithmetic — the partition kernel is
-    VPU-issue-bound on per-step fixed work, not element throughput
-    (PERF.md round 5), which is what this trades for.
-    """
-    live = jnp.int32(1 << 16)
-    meta = jnp.where(flag != 0, shift0 | live, 0)
-    aug = jnp.concatenate([payload, meta], axis=0)
-    P = payload.shape[0]
-
-    def dig_of(mrow, k, mask_d):
-        d = jax.lax.shift_right_logical(
-            mrow & (live - 1), jnp.broadcast_to(k, mrow.shape)) & mask_d
-        return jnp.where((mrow & live) != 0, d, 0)
-
-    for k in range(0, logc, 2):
-        s = 1 << k
-        nd = 2 if k + 1 < logc else 1      # bits consumed this step
-        mask_d = (1 << nd) - 1
-        d_self = dig_of(aug[P:P + 1], k, mask_d)
-        r1 = pltpu_roll(aug, C - s)
-        m1 = dig_of(r1[P:P + 1], k, mask_d) == 1
-        # an element that moves away and is not overwritten leaves a
-        # hole: clear its live bit (mirrors the binary network)
-        base = jnp.concatenate(
-            [aug[0:P],
-             jnp.where(d_self != 0, aug[P:P + 1] & (live - 1),
-                       aug[P:P + 1])], axis=0)
-        if nd == 2:
-            r2 = pltpu_roll(aug, C - 2 * s)
-            r3 = pltpu_roll(aug, C - 3 * s)
-            m2 = dig_of(r2[P:P + 1], k, mask_d) == 2
-            m3 = dig_of(r3[P:P + 1], k, mask_d) == 3
-            aug = jnp.where(m1, r1,
-                            jnp.where(m2, r2, jnp.where(m3, r3, base)))
-        else:
-            aug = jnp.where(m1, r1, base)
-    return aug[0:P]
-
-
 def payload_codecs(G32: int, ghi_live: int, pack_rowid: bool):
     """Packed-payload codec closures shared by the partition kernel and
     the split mega-kernel (ops/split_megakernel_pallas.py).
@@ -274,7 +223,6 @@ def _decide_left(colv, bstart, isb, nb, dbin, mtype, thr, dl):
 def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                           row_chunk: int, ghi_live: int = 3,
                           pack_rowid: bool = False,
-                          compact_radix: bool = False,
                           interpret: bool = False):
     """Two-way stable partition of the leaf range described by
     ``scalars`` (see the S_* layout above), in place.
@@ -295,9 +243,6 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         (PERF.md), so this drops P by one for free when G <= G32-4.
         Kernel-internal only: the HBM layout of part_ghi is unchanged
         and the pad bin rows come back zeroed.
-      compact_radix: use the radix-4 compaction network
-        (``_compact_radix4``: half the network steps) instead of the
-        binary one.  Bit-identical output; an issue-budget lever only.
     Returns (part_bins', part_ghi', sc_packed', nl) with the first three
     aliased in place; nl is an (8, 128) i32 tile whose [0, 0] element is
     the left count.
@@ -322,7 +267,6 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     P, W, pack_bins, unpack_bins, make_payload, split_payload = \
         payload_codecs(G32, ghi_live, pack_rowid)
     assert P <= SCR
-    compact = _compact_radix4 if compact_radix else _compact
 
     def kernel(s_ref, pb_in, pg_in, sp_in, pb, pg, sp, nl_ref,
                rb, rg, rs, stgl, stgr, wb, wg, wp, exb, exg, sems):
@@ -397,8 +341,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             nl_cnt = nl_cnt + nlc
             nrc = C - nlc
 
-            lcomp = compact(payload, left, pnr, C, logc)
-            rcomp = compact(payload, 1 - left, lane - pnr, C, logc)
+            lcomp = _compact(payload, left, pnr, C, logc)
+            rcomp = _compact(payload, 1 - left, lane - pnr, C, logc)
 
             def stage(stg, comp, fill, n_add):
                 # place comp[0:n_add) at staging positions [fill, +n_add)

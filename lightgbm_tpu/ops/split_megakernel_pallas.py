@@ -204,7 +204,6 @@ def both_children_hist_banded(part_bins, part_ghi, start, cnt, col,
 def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                             row_chunk: int, num_bins: int, num_groups: int,
                             ghi_live: int = 3, pack_rowid: bool = False,
-                            compact_radix: bool = False,
                             interpret: bool = False):
     """Two-way stable partition of the leaf range (scalar layout: the
     S_* constants of ops/partition_pallas.py) PLUS both children's
@@ -241,7 +240,7 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     assert P <= SCR
     # late-bound so tools/profile_partition.py's network-ablation
     # monkeypatch applies here too
-    compact = _pp._compact_radix4 if compact_radix else _pp._compact
+    compact = _pp._compact
 
     def kernel(s_ref, pb_in, pg_in, sp_in, pb, pg, sp, nl_ref, hist_ref,
                rb, rg, rs, stgl, stgr, wb, wg, wp, exb, exg, acc, sems):

@@ -10,8 +10,8 @@ Covered here:
   eager-path configurations;
 * the compiled-variant registry pin: <= menu-size traced variants per
   pass over a full training run, and warm updates add none;
-* ``tpu_row_chunk=auto`` / ``tpu_chunk_policy=auto`` consulting a
-  planted same-fingerprint chunk-sweep trajectory entry;
+* ``tpu_row_chunk=auto`` / ``tpu_chunk_policy=auto`` ignoring a planted
+  same-fingerprint chunk-sweep trajectory entry;
 * the ``train.chunk.waste`` telemetry gauges;
 * the PR-10 ``rec["hist"]`` dead-export deletion.
 """
@@ -174,7 +174,7 @@ def test_chunk_interpret_megakernel_fallback():
           "tpu_row_chunk": 256}
     bf = _train(X, y, nbr=1, tpu_chunk_policy="fixed", **kw)
     ba = _train(X, y, nbr=1, tpu_chunk_policy="adaptive", **kw)
-    assert ba._gbdt.learner._use_mega == "pallas"
+    assert ba._gbdt.learner.plan.mega == "pallas"
     assert not ba._gbdt.learner._chunk_policy.adaptive
     assert _trees(bf) == _trees(ba)
 
@@ -204,54 +204,53 @@ def test_variant_counts_bounded_by_menu():
 
 
 # ---------------------------------------------------------------------------
-# auto modes consult the measured trajectory (ROADMAP item 7 slice)
+# auto modes follow from the parameters and the data's shape alone: a
+# benchmark history that names another winner changes nothing
 # ---------------------------------------------------------------------------
-def test_row_chunk_auto_consults_history(tmp_path, monkeypatch):
+def _plant_sweep(ds, metrics):
+    """A same-host, same-shape ``chunk_sweep`` entry (what ``ab_bench
+    --chunk`` appends) in the history file the session points at."""
     from lightgbm_tpu.obs import regress
-    hist_path = str(tmp_path / "hist.jsonl")
-    monkeypatch.setenv("BENCH_HISTORY_PATH", hist_path)
+    regress.append_entry(
+        "chunk_sweep", metrics,
+        fingerprint_doc=regress.fingerprint(
+            config={}, rows=ds.num_data, features=ds.num_total_features),
+        path=os.environ["BENCH_HISTORY_PATH"])
+
+
+def test_row_chunk_auto_consults_history(tmp_path, monkeypatch):
+    monkeypatch.setenv("BENCH_HISTORY_PATH", str(tmp_path / "hist.jsonl"))
     X, y = _data(n=3000)
     cfg = Config({**BASE, "tpu_row_chunk": "auto"})
     ds = BinnedDataset.from_matrix(X, cfg, label=y)
-    # no history yet: the static default (capped by the row count)
-    lr = SerialTreeLearner(ds, cfg)
-    assert lr.row_chunk == min(chunkpolicy.DEFAULT_ROW_CHUNK, 4096)
-    # a same-fingerprint sweep entry flips the chosen chunk size
-    regress.append_entry(
-        chunkpolicy.SWEEP_TOOL, {"best_row_chunk": 512},
-        fingerprint_doc=chunkpolicy.sweep_fingerprint(
-            ds.num_data, ds.num_total_features),
-        path=hist_path)
-    lr2 = SerialTreeLearner(ds, cfg)
-    assert lr2.row_chunk == 512
-    # a DIFFERENT shape band must not flip anything (series isolation)
-    regress.append_entry(
-        chunkpolicy.SWEEP_TOOL, {"best_row_chunk": 2048},
-        fingerprint_doc=chunkpolicy.sweep_fingerprint(
-            10 * ds.num_data, ds.num_total_features),
-        path=hist_path)
-    assert SerialTreeLearner(ds, cfg).row_chunk == 512
+    _plant_sweep(ds, {"best_row_chunk": 512})
+    # the constant, capped by the rows (3000 -> 4096)
+    assert SerialTreeLearner(ds, cfg).row_chunk == 4096 \
+        == chunkpolicy.DEFAULT_ROW_CHUNK
+    Xs, ys = _data(n=700)
+    small = BinnedDataset.from_matrix(Xs, cfg, label=ys)
+    assert SerialTreeLearner(small, cfg).row_chunk == 1024
 
 
 def test_chunk_policy_auto_consults_history(tmp_path, monkeypatch):
-    from lightgbm_tpu.obs import regress
-    hist_path = str(tmp_path / "hist.jsonl")
-    monkeypatch.setenv("BENCH_HISTORY_PATH", hist_path)
+    monkeypatch.setenv("BENCH_HISTORY_PATH", str(tmp_path / "hist.jsonl"))
     X, y = _data(n=3000)
     cfg = Config(dict(BASE))
     ds = BinnedDataset.from_matrix(X, cfg, label=y)
-    # heuristic default at this shape: small-leaf regime -> adaptive
-    assert SerialTreeLearner(ds, cfg)._chunk_policy.adaptive
-    # a measured same-fingerprint verdict that adaptive LOST overrides
-    regress.append_entry(
-        chunkpolicy.SWEEP_TOOL,
-        {"best_row_chunk": 4096, "adaptive_speedup": 0.8},
-        fingerprint_doc=chunkpolicy.sweep_fingerprint(
-            ds.num_data, ds.num_total_features),
-        path=hist_path)
-    assert not SerialTreeLearner(ds, cfg)._chunk_policy.adaptive
-    # explicit settings ignore the trajectory
-    cfg_forced = Config({**BASE, "tpu_chunk_policy": "adaptive"})
+    # a measured verdict that adaptive LOST on this host and shape
+    _plant_sweep(ds, {"best_row_chunk": 4096, "adaptive_speedup": 0.8})
+    # the small-leaf rule, (num_leaves-1) * base > num_data, decides:
+    # 30 * 4096 > 3000 -> adaptive ...
+    lr = SerialTreeLearner(ds, cfg)
+    assert lr.plan.chunk_adaptive and lr._chunk_policy.adaptive
+    # ... and 1 * 256 <= 3000 -> the fixed grid
+    cfg2 = Config({**BASE, "num_leaves": 2, "tpu_row_chunk": 256})
+    lr2 = SerialTreeLearner(ds, cfg2)
+    assert not lr2.plan.chunk_adaptive
+    assert "auto" in lr2.plan.why["chunk_adaptive"]
+    # explicit settings win over the rule
+    cfg_forced = Config({**BASE, "num_leaves": 2, "tpu_row_chunk": 256,
+                         "tpu_chunk_policy": "adaptive"})
     assert SerialTreeLearner(ds, cfg_forced)._chunk_policy.adaptive
 
 

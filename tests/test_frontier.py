@@ -72,7 +72,7 @@ def test_frontier_bitidentity(extra, cat):
     X, y = _data(cat=cat)
     b1 = _train(X, y, cat=cat, **extra)
     bk = _train(X, y, cat=cat, tpu_frontier_k=3, **extra)
-    assert bk._gbdt.learner.frontier_k == 3
+    assert bk._gbdt.learner.plan.frontier_k == 3
     assert _trees(b1) == _trees(bk)
     d = np.abs(np.asarray(b1.predict(X[:200]))
                - np.asarray(bk.predict(X[:200]))).max()
@@ -122,9 +122,9 @@ def test_frontier_mega_xla_interplay():
     X, y = _data(seed=9)
     b1 = _train(X, y, tpu_megakernel="xla")
     bk = _train(X, y, tpu_megakernel="xla", tpu_frontier_k=3)
-    assert b1._gbdt.learner._use_mega == "xla"
-    assert bk._gbdt.learner._use_mega == "xla"
-    assert bk._gbdt.learner.frontier_k == 3
+    assert b1._gbdt.learner.plan.mega == "xla"
+    assert bk._gbdt.learner.plan.mega == "xla"
+    assert bk._gbdt.learner.plan.frontier_k == 3
     assert _trees(b1) == _trees(bk)
 
 
@@ -138,8 +138,8 @@ def test_frontier_megakernel_interpret_interplay():
           "tpu_row_chunk": 256}
     b1 = _train(X, y, nbr=1, **kw)
     bk = _train(X, y, nbr=1, tpu_frontier_k=3, **kw)
-    assert b1._gbdt.learner._use_mega == "pallas"
-    assert bk._gbdt.learner._use_mega == "pallas"
+    assert b1._gbdt.learner.plan.mega == "pallas"
+    assert bk._gbdt.learner.plan.mega == "pallas"
     assert _trees(b1) == _trees(bk)
 
 
@@ -197,8 +197,8 @@ def test_frontier_prune_engages_and_stays_bitidentical(partition):
     for seed in range(6 if partition == "xla" else 3):
         lr, a = _masked_tree(X, y, seed, 1, PARTITIONS[partition])
         lr, b = _masked_tree(X, y, seed, K, PARTITIONS[partition])
-        assert lr._use_pallas_part == (partition == "pallas")
-        assert lr.frontier_k == K
+        assert lr.plan.partition == partition
+        assert lr.plan.frontier_k == K
         for field in RECORD_FIELDS:
             assert np.array_equal(np.asarray(a[field]),
                                   np.asarray(b[field])), (seed, field)
@@ -292,7 +292,7 @@ def test_frontier_undo_feeds_the_next_tree(partition):
         counters = bk.telemetry_report(include_memory=False)["counters"]
     finally:
         obs.get().reset(mode="off")
-    assert bk._gbdt.learner._use_pallas_part == (partition == "pallas")
+    assert bk._gbdt.learner.plan.partition == partition
     assert counters["train.frontier.undo_trees"] >= 3   # of 4 trees
     assert trees1 == treesk
     for a, b in zip(b1._gbdt._phys, bk._gbdt._phys):
@@ -362,24 +362,25 @@ def test_frontier_fallbacks_to_k1(tmp_path):
     ]
     for p in fallback_params:
         lr = _learner_for({**p, "tpu_frontier_k": 4}, X, y)
-        assert lr.frontier_k == 1, p
+        assert lr.plan.frontier_k == 1, p
     # a fallback-engaged training equals the plain learner exactly
     b1 = _train(X, y, monotone_constraints="1,0,0,0,0,0")
     bk = _train(X, y, monotone_constraints="1,0,0,0,0,0",
                 tpu_frontier_k=4)
-    assert bk._gbdt.learner.frontier_k == 1
+    assert bk._gbdt.learner.plan.frontier_k == 1
     assert _trees(b1) == _trees(bk)
 
 
 def test_frontier_k_plumbing():
     X, y = _data()
     # auto on CPU stays 1 (compile-budget heuristic; README)
-    assert _learner_for({}, X, y).frontier_k == 1
-    assert _learner_for({"tpu_frontier_k": "auto"}, X, y).frontier_k == 1
+    assert _learner_for({}, X, y).plan.frontier_k == 1
+    assert _learner_for({"tpu_frontier_k": "auto"}, X,
+                        y).plan.frontier_k == 1
     # explicit K engages anywhere, capped at num_leaves - 1
-    assert _learner_for({"tpu_frontier_k": 6}, X, y).frontier_k == 6
-    assert _learner_for({"tpu_frontier_k": 99}, X, y).frontier_k == 14
-    assert _learner_for({"tpu_frontier_k": 1}, X, y).frontier_k == 1
+    assert _learner_for({"tpu_frontier_k": 6}, X, y).plan.frontier_k == 6
+    assert _learner_for({"tpu_frontier_k": 99}, X, y).plan.frontier_k == 14
+    assert _learner_for({"tpu_frontier_k": 1}, X, y).plan.frontier_k == 1
     with pytest.raises(ValueError):
         _learner_for({"tpu_frontier_k": 0}, X, y)
     with pytest.raises(ValueError):

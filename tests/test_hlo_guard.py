@@ -85,7 +85,7 @@ def test_mega_body_has_no_hist_state_buffer():
     counts = body_counts(hlo)
     L1, G, B = learner.L + 1, learner.G, learner.B
     state_token = f"f32[{L1},{G},{B},2]"
-    assert learner._use_mega == "xla"
+    assert learner.plan.mega == "xla"
     from hlo_report import _computation_blocks
     body_lines = _computation_blocks(hlo)[counts["body"]]
     assert not any(state_token in ln for ln in body_lines), state_token

@@ -48,8 +48,8 @@ def test_mega_xla_matches_default_path_numerically():
     X, y = _data()
     b0 = _train(X, y, nbr=5)
     b1 = _train(X, y, nbr=5, tpu_megakernel="xla")
-    assert b0._gbdt.learner._use_mega is None       # CPU auto: off
-    assert b1._gbdt.learner._use_mega == "xla"
+    assert b0._gbdt.learner.plan.mega == "off"       # CPU auto: off
+    assert b1._gbdt.learner.plan.mega == "xla"
     d = float(np.abs(b0.predict(X[:400]) - b1.predict(X[:400])).max())
     assert d < 1e-4, d
 
@@ -84,25 +84,11 @@ def test_mega_interpret_bitexact_vs_oracle(extra):
     bx = _train(X, y, tpu_megakernel="xla", **kw)
     bp = _train(X, y, tpu_megakernel="pallas", **kw)
     lr = bp._gbdt.learner
-    assert lr._use_mega == "pallas" and lr._use_pallas_part
-    assert bx._gbdt.learner._use_mega == "xla"
+    assert lr.plan.mega == "pallas" and lr.plan.partition == "pallas"
+    assert bx._gbdt.learner.plan.mega == "xla"
     assert _trees(bx) == _trees(bp)
     d = np.abs(bx.predict(X[:300]) - bp.predict(X[:300])).max()
     assert float(d) == 0.0
-
-
-@pytest.mark.slow  # 12.4 s: tier-1 window offender per
-# test_durations.json; kernel-level radix-4 interpret coverage stays
-# fast in tests/test_pallas_interpret.py
-def test_mega_interpret_radix4_bitexact():
-    """The radix-4 compaction network changes the instruction schedule,
-    never the layout: mega trees stay bit-identical to the oracle."""
-    X, y = _data(seed=13, n=900)
-    bx = _train(X, y, tpu_megakernel="xla", tpu_kernel_interpret=True)
-    bp = _train(X, y, tpu_megakernel="pallas", tpu_kernel_interpret=True,
-                tpu_compact_radix=True)
-    assert bp._gbdt.learner._compact_radix
-    assert _trees(bx) == _trees(bp)
 
 
 @pytest.mark.slow
@@ -114,7 +100,7 @@ def test_mega_interpret_bitexact_L255():
           "tpu_kernel_interpret": True}
     bx = _train(X, y, nbr=1, tpu_megakernel="xla", **kw)
     bp = _train(X, y, nbr=1, tpu_megakernel="pallas", **kw)
-    assert bp._gbdt.learner._use_mega == "pallas"
+    assert bp._gbdt.learner.plan.mega == "pallas"
     assert _trees(bx) == _trees(bp)
 
 
@@ -130,8 +116,8 @@ def test_nonmega_interpret_kernels_structural():
     bx = _train(X, y, tpu_megakernel="off")
     bi = _train(X, y, tpu_megakernel="off", tpu_kernel_interpret=True)
     lr = bi._gbdt.learner
-    assert (lr._use_pallas_part and lr._use_pallas_search
-            and lr._use_flat_hist)
+    assert (lr.plan.partition == "pallas" and lr.plan.search == "pallas"
+            and lr.plan.hist_state == "flat")
     struct = ("split_feature=", "threshold=", "left_child=",
               "right_child=", "num_leaves=", "decision_type=")
     sx = [ln for ln in _trees(bx) if ln.startswith(struct)]
@@ -153,16 +139,16 @@ def test_mega_fallback_routes_clean_at_init():
                     "categorical_feature": [3]},
                    lgb.Dataset(Xc, label=y, categorical_feature=[3]),
                    num_boost_round=2)
-    assert bc._gbdt.learner._use_mega is None
+    assert bc._gbdt.learner.plan.mega == "off"
     # u16 bins
     b16 = _train(X, y, tpu_megakernel="xla", max_bin=300)
-    assert b16._gbdt.learner._use_mega is None
+    assert b16._gbdt.learner.plan.mega == "off"
     assert b16._gbdt.learner.B > 256
     # cegb-lazy
     lazy = ",".join(["0.1"] * X.shape[1])
     bl = _train(X, y, tpu_megakernel="xla",
                 cegb_penalty_feature_lazy=lazy)
-    assert bl._gbdt.learner._use_mega is None
+    assert bl._gbdt.learner.plan.mega == "off"
     # forced splits
     import json
     import tempfile
@@ -175,7 +161,7 @@ def test_mega_fallback_routes_clean_at_init():
                     forcedsplits_filename=fname)
     finally:
         os.remove(fname)
-    assert bf._gbdt.learner._use_mega is None
+    assert bf._gbdt.learner.plan.mega == "off"
     # every fallback still trains a usable model
     for b in (bc, b16, bl, bf):
         assert np.isfinite(b.predict(X[:50])).all()
@@ -184,6 +170,6 @@ def test_mega_fallback_routes_clean_at_init():
 def test_mega_off_and_unknown_modes():
     X, y = _data(n=600)
     boff = _train(X, y, tpu_megakernel="off")
-    assert boff._gbdt.learner._use_mega is None
+    assert boff._gbdt.learner.plan.mega == "off"
     bauto = _train(X, y)            # auto on CPU without interpret: off
-    assert bauto._gbdt.learner._use_mega is None
+    assert bauto._gbdt.learner.plan.mega == "off"

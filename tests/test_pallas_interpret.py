@@ -2,7 +2,7 @@
 (Pallas correctness must not depend on TPU availability).  Small shapes —
 the interpreter is slow.  The real Mosaic lowering of the same kernels is
 checked on the chip by `python tpu_selfcheck.py` (kernel-vs-oracle steps
-1, 6 and the Pallas-vs-XLA end-to-end parity of step 7), which the
+1, 5 and the Pallas-vs-XLA end-to-end parity of step 6), which the
 builder runs through the chip tool; no pytest lane can reach a TPU under
 this suite's CPU-pinned conftest."""
 
@@ -199,36 +199,7 @@ def test_split_kernel_interpreted():
 
 
 @pytest.mark.parametrize("trial", [0, 1])
-def test_partition_kernel_radix4_interpreted(trial):
-    """The radix-4 compaction network must produce the identical stable
-    partition layout as the binary network (trial 1 adds pack_rowid)."""
-    C, G32, G = 256, 32, 28
-    Np = 8 * C
-    rng = np.random.RandomState(40 + trial)
-    pack = trial == 1
-    pb = rng.randint(0, 250, (G32, Np)).astype(np.uint8)
-    if pack:
-        pb[G:] = 0
-    pg = rng.randn(8, Np).astype(np.float32)
-    start = int(rng.randint(C, 4 * C))
-    cnt = int(rng.randint(1, 3 * C))
-    col = int(rng.randint(0, G))
-    nb = int(rng.randint(10, 250))
-    thr = int(rng.randint(0, nb))
-    epb, epg, enl = _oracle(pb, pg, start, cnt, col, 0, 0, nb, 0, 0, thr, 1)
-    sc = make_scalars(start, cnt, col, 0, 0, nb, 0, 0, thr, 1)
-    rpb, rpg, _, rnl = partition_leaf_pallas(
-        jnp.asarray(pb), jnp.asarray(pg),
-        jnp.zeros((sc_rows_for(G32), Np), jnp.int32), sc,
-        row_chunk=C, pack_rowid=pack, compact_radix=True, interpret=True)
-    assert int(np.asarray(rnl)[0, 0]) == enl
-    np.testing.assert_array_equal(np.asarray(rpb), epb)
-    np.testing.assert_array_equal(
-        np.asarray(rpg)[:3].view(np.int32), epg[:3].view(np.int32))
-
-
-@pytest.mark.parametrize("trial,radix", [(0, False), (1, True)])
-def test_megakernel_interpreted(trial, radix):
+def test_megakernel_interpreted(trial):
     """Mega-kernel: the partition must match the NumPy oracle bit-exact
     AND the both-children histogram accumulator must match the XLA
     oracle (both_children_hist_xla) bit-exact — the same chunk grid and
@@ -252,8 +223,7 @@ def test_megakernel_interpreted(trial, radix):
     rpb, rpg, _, rnl, acc = split_megakernel_pallas(
         jnp.asarray(pb), jnp.asarray(pg),
         jnp.zeros((sc_rows_for(G32), Np), jnp.int32), sc,
-        row_chunk=C, num_bins=B, num_groups=G, compact_radix=radix,
-        interpret=True)
+        row_chunk=C, num_bins=B, num_groups=G, interpret=True)
     assert int(np.asarray(rnl)[0, 0]) == enl
     np.testing.assert_array_equal(np.asarray(rpb), epb)
     np.testing.assert_array_equal(

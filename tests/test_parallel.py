@@ -223,7 +223,7 @@ def test_data_scatter_ownership_512_groups():
         cfg = Config(dict(base, tpu_data_hist_sync=sync))
         dsp = BinnedDataset.from_matrix(X, cfg, label=y)
         builder = ShardedTreeBuilder(dsp, cfg, mode="data")
-        assert builder.learner._scatter_groups == (sync == "scatter")
+        assert builder.learner.plan.scatter_groups == (sync == "scatter")
         recs[sync] = builder.build_tree(g, h)
 
     ns = int(rec_serial["s"])

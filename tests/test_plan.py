@@ -1,0 +1,219 @@
+"""Which split-step program a learner runs (lightgbm_tpu/models/plan.py).
+
+One table over ``PlanFacts``: no dataset, no array, no backend.  A row is
+(facts that differ from the benchmark cells' shape, the plan fields they
+must give, a decision and a phrase its ``why`` must hold, a phrase a
+warning must hold or None).  Then three real toy trainings whose
+``kernel_plan()`` must equal ``resolve`` on facts written by hand.
+"""
+
+import numpy as np
+import pytest
+
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.models import plan
+
+OPTIONS = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
+# benchmark/configs/higgs-l255-b255.json at rows42m on the chip
+CELL = dict(backend="tpu", rows=42_000_000, F=28, G=28, B=255,
+            num_leaves=255)
+CELL_PLAN = dict(partition="pallas", fast_search=False, search="xla",
+                 mega="off", frontier_k=4, hist_state="xla",
+                 row_chunk=4096, chunk_adaptive=False, pack_rowid=False,
+                 scatter_groups=False, linear_gain=False)
+SMOKE = dict(rows=10_500_000)          # chip_smoke.py's shape (PR 21)
+TOY_CPU = dict(backend="cpu", rows=2000, num_leaves=31)
+DATA4 = dict(TOY_CPU, parallel_mode="data", axis_name=True, num_shards=4)
+
+
+def facts(**kw):
+    return plan.PlanFacts(**{**OPTIONS, **CELL, **kw})
+
+
+CASES = {
+    "cell_b255": ({}, CELL_PLAN, ("mega", "off (rows 42,000,000 >= 2^24: "
+                                  "the f32 count cumsum of the fast search "
+                                  "is exact only below it)"), None),
+    "cell_b63": (dict(B=63), CELL_PLAN,
+                 ("chunk_adaptive", "partition=pallas"), None),
+    # PR 21's smoke line
+    "smoke_10m5": (SMOKE, dict(fast_search=True, search="pallas",
+                               mega="pallas", frontier_k=4,
+                               hist_state="xla"),
+                   ("hist_state", "mega=pallas"), None),
+    # PR 21's 0.42 s arm: the plan no default and no cell reaches
+    "smoke_10m5_mega_off": (dict(SMOKE, tpu_megakernel="off"),
+                            dict(partition="pallas", search="pallas",
+                                 mega="off", frontier_k=1,
+                                 hist_state="flat"),
+                            ("frontier_k", "search=pallas with mega=off"),
+                            None),
+    "rows_2p24_less_1": (dict(rows=(1 << 24) - 1),
+                         dict(fast_search=True, search="pallas",
+                              mega="pallas", frontier_k=4),
+                         ("hist_state", "frontier_k=4"), None),
+    "rows_2p24": (dict(rows=1 << 24),
+                  dict(fast_search=False, search="xla", mega="off",
+                       frontier_k=4),
+                  ("fast_search", "rows 16,777,216 >= 2^24"), None),
+    "cpu": (TOY_CPU, dict(partition="xla", fast_search=True, search="xla",
+                          mega="off", frontier_k=1, hist_state="xla",
+                          row_chunk=2048, chunk_adaptive=True),
+            ("partition", "backend cpu without tpu_kernel_interpret"),
+            None),
+    "cpu_interpret": (dict(TOY_CPU, interpret=True),
+                      dict(partition="pallas", search="pallas",
+                           mega="pallas", frontier_k=1, hist_state="xla",
+                           chunk_adaptive=False),
+                      ("frontier_k", "auto is 1 on backend cpu"), None),
+    "categorical": (dict(SMOKE, has_categorical=True),
+                    dict(partition="xla", fast_search=False, search="xla",
+                         mega="off", frontier_k=4),
+                    ("partition", "categorical features"), None),
+    "u16_bins": (dict(SMOKE, host_bin_dtype="uint16", B=300),
+                 dict(partition="xla", search="xla", mega="off"),
+                 ("partition", "uint16 bins"), None),
+    "bins_over_256": (dict(TOY_CPU, host_bin_dtype="uint16", B=300,
+                           tpu_megakernel="xla"),
+                      dict(mega="off"), ("mega", "300 bins in a group"),
+                      "tpu_megakernel=xla cannot be honoured"),
+    "monotone": (dict(SMOKE, use_mc=True),
+                 dict(partition="pallas", fast_search=False, search="xla",
+                      mega="off", frontier_k=1),
+                 ("frontier_k", "monotone constraints"), None),
+    "cegb_lazy": (dict(SMOKE, has_cegb=True, cegb_lazy=True),
+                  dict(partition="xla", fast_search=False, frontier_k=1),
+                  ("partition", "cegb_penalty_feature_lazy"), None),
+    "forced_k4": (dict(SMOKE, forced=True, tpu_frontier_k="4"),
+                  dict(partition="pallas", search="xla", mega="off",
+                       frontier_k=1),
+                  ("frontier_k", "forced splits"),
+                  "tpu_frontier_k=4 cannot be honoured (forced splits); "
+                  "using 1"),
+    "extra_trees": (dict(SMOKE, extra_trees=True),
+                    dict(fast_search=True, search="xla", mega="off",
+                         frontier_k=1),
+                    ("mega", "extra_trees"), None),
+    # how tests/test_scopes.py reaches the cells' plan at toy size
+    "path_smooth": (dict(rows=1500, num_leaves=15, path_smooth=1.0),
+                    dict(CELL_PLAN, row_chunk=2048),
+                    ("search", "path_smooth > 0"), None),
+    "data_scatter": (DATA4, dict(partition="xla", scatter_groups=True,
+                                 frontier_k=1, chunk_adaptive=False),
+                     ("partition", "tree_learner=data"), None),
+    "data_few_features": (dict(DATA4, F=2, G=2),
+                          dict(scatter_groups=False),
+                          ("scatter_groups", "2 features < 4 shards"),
+                          None),
+    "data_psum": (dict(DATA4, tpu_data_hist_sync="psum"),
+                  dict(scatter_groups=False),
+                  ("scatter_groups", "tpu_data_hist_sync=psum"), None),
+    "linear_l1": (dict(TOY_CPU, linear_gain_requested=True, l1=0.5),
+                  dict(linear_gain=False),
+                  ("linear_gain", "lambda_l1 > 0"),
+                  "lambda_l1 > 0; falling back to the post-hoc refit mode"),
+    "linear_on_chip": (dict(SMOKE, linear_gain_requested=True),
+                       dict(linear_gain=True, partition="pallas",
+                            search="xla", mega="off", frontier_k=1),
+                       ("search", "linear_tree_mode=leafwise_gain"), None),
+    "interaction_k4": (dict(TOY_CPU, interaction_constraints=True,
+                            tpu_frontier_k="4"),
+                       dict(frontier_k=1),
+                       ("frontier_k", "interaction constraints"),
+                       "tpu_frontier_k=4 cannot be honoured"),
+    "k4_on_cpu": (dict(TOY_CPU, tpu_frontier_k="4"), dict(frontier_k=4),
+                  ("hist_state", "search=xla"), None),
+    "k_capped": (dict(TOY_CPU, num_leaves=15, tpu_frontier_k="99"),
+                 dict(frontier_k=14), ("mega", "partition=xla"), None),
+    "mega_pallas_on_cpu": (dict(TOY_CPU, tpu_megakernel="pallas"),
+                           dict(mega="off"), ("mega", "partition=xla"),
+                           "tpu_megakernel=pallas cannot be honoured "
+                           "(partition=xla); using the current split path"),
+    "mega_unknown": (dict(TOY_CPU, tpu_megakernel="maybe"),
+                     dict(mega="off"), ("mega", "unknown"),
+                     "unknown tpu_megakernel='maybe'; treating as off"),
+    "adaptive_on_kernel_path": (dict(SMOKE, tpu_chunk_policy="adaptive"),
+                                dict(chunk_adaptive=False),
+                                ("chunk_adaptive", "partition=pallas"),
+                                "tpu_chunk_policy=adaptive cannot be "
+                                "honoured"),
+    "chunks_fill": (dict(TOY_CPU, rows=200_000), dict(chunk_adaptive=False),
+                    ("chunk_adaptive", "auto: (num_leaves-1) * 4096"),
+                    None),
+    "pack_rowid": (dict(SMOKE, tpu_pack_rowid=True), dict(pack_rowid=True),
+                   ("hist_state", "mega=pallas"), None),
+    "pack_rowid_no_room": (dict(SMOKE, F=30, G=30, tpu_pack_rowid=True),
+                           dict(pack_rowid=False),
+                           ("pack_rowid", "30 groups leave 2 spare rows"),
+                           None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_plan_table(name):
+    changed, expect, (decision, phrase), warning = CASES[name]
+    p = plan.resolve(facts(**changed))
+    got = {k: getattr(p, k) for k in expect}
+    assert got == expect, p
+    assert phrase in p.why[decision], p.why
+    if warning is None:
+        assert p.unmet == ()
+    else:
+        assert any(warning in w for w in p.unmet), p.unmet
+    # a decision that took its kernel path gives no reason
+    for key, value in (("partition", "pallas"), ("search", "pallas"),
+                       ("mega", "pallas"), ("hist_state", "flat")):
+        assert (getattr(p, key) == value) == (key not in p.why), (key, p)
+
+
+@pytest.mark.parametrize("spec", ["0", "-3", "bogus"])
+def test_frontier_k_must_be_auto_or_positive(spec):
+    with pytest.raises(ValueError, match="tpu_frontier_k"):
+        plan.resolve(facts(tpu_frontier_k=spec))
+
+
+def test_plan_module_stays_off_jax():
+    """The plan can be read where no backend can start."""
+    import ast
+    import inspect
+    tree = ast.parse(inspect.getsource(plan))
+    imported = {a.name.split(".")[0] for n in ast.walk(tree)
+                if isinstance(n, ast.Import) for a in n.names}
+    imported |= {(n.module or "").split(".")[0] for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom) and n.level == 0}
+    assert not imported & {"jax", "numpy"}, imported
+
+
+# ---------------------------------------------------------------------------
+# three real toy trainings against facts written by hand
+# ---------------------------------------------------------------------------
+TOY = dict(backend="cpu", rows=1500, F=8, G=8, B=255, num_leaves=15)
+TRAININGS = {
+    "serial_cpu": ({}, TOY),
+    "serial_interpret": (
+        {"tpu_kernel_interpret": True, "tpu_row_chunk": 256},
+        dict(TOY, interpret=True, tpu_row_chunk="256")),
+    # conftest.py's eight virtual devices: 188 rows a shard
+    "data_parallel": (
+        {"tree_learner": "data"},
+        dict(TOY, rows=188, parallel_mode="data", axis_name=True,
+             num_shards=8)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TRAININGS))
+def test_kernel_plan_is_the_projection_of_resolve(name):
+    import lightgbm_tpu as lgb
+    params, by_hand = TRAININGS[name]
+    rng = np.random.RandomState(0)
+    X = rng.normal(size=(1500, 8))
+    y = (2 * X[:, 0] + X[:, 1] - X[:, 2] > 0).astype(float)
+    bst = lgb.train({"objective": "binary", "num_leaves": 15,
+                     "verbosity": -1, **params},
+                    lgb.Dataset(X, label=y), num_boost_round=1)
+    g = bst._gbdt
+    expect = plan.resolve(plan.PlanFacts(**{**OPTIONS, **by_hand}))
+    assert g._tree_learner().plan == expect
+    assert g.kernel_plan() == {
+        **expect.kernel_plan(), "fused": "on",
+        "tree_learner": by_hand.get("parallel_mode", "serial")}

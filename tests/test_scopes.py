@@ -325,7 +325,7 @@ def test_frontier_loop_carries_one_snapshot_row(monkeypatch):
     bst = _booster({"tpu_frontier_k": K, "max_bin": 15})
     g = bst._gbdt
     Np = g.learner.N_pad
-    assert g.learner.frontier_k == K and Np > 4 * 128
+    assert g.learner.plan.frontier_k == K and Np > 4 * 128
     pb, ghi = g._init_phys(g.learner._part0, g.scores)
     text = g._fused_phys.lower(
         pb, ghi, g._feature_mask(0), 1,

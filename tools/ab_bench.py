@@ -304,7 +304,7 @@ def _frontier_smoke(args, guard):
                 times[n].append((time.time() - t0) / args.frontier_iters)
         if trees(boosters["A"]) != trees(boosters["B"]):
             mismatch.append(rows)
-        kb = boosters["B"]._gbdt.learner.frontier_k
+        kb = boosters["B"]._gbdt.learner.plan.frontier_k
         per_rows[rows] = {
             "A_s_per_iter": round(float(np.median(times["A"])), 5),
             "B_s_per_iter": round(float(np.median(times["B"])), 5),
@@ -335,11 +335,7 @@ def _frontier_smoke(args, guard):
         "min_reduction_pct": args.frontier_min_pct,
         "trees_identical": not mismatch,
     }
-    report["kernels_B"] = {
-        "_use_mega": getattr(
-            boosters["B"]._gbdt.learner, "_use_mega", None),
-        "frontier_k": int(boosters["B"]._gbdt.learner.frontier_k),
-    }
+    report["kernels_B"] = boosters["B"]._gbdt.kernel_plan()
     print(json.dumps(report))
     _write_obs(guard, args, "ab_bench.frontier",
                {"rows": rows_list, "k": K,
@@ -377,15 +373,13 @@ def _chunk_smoke(args, guard):
     (adaptive bands are a no-op there — every leaf covers base chunks).
     Each regime also appends a `chunk_sweep` trajectory entry (winning
     base width + measured adaptive speedup under the knob-free
-    host/shape fingerprint) that `tpu_row_chunk=auto` /
-    `tpu_chunk_policy=auto` consult (ops/chunkpolicy.py).  Exits
+    host/shape fingerprint); the program reads none of them.  Exits
     non-zero on any tree mismatch, when the small-leaf speedup
     undercuts `--chunk-min-x`, or when the uniform regime regresses
     past the noise floor."""
     import jax.numpy as jnp
     import lightgbm_tpu as lgb
     from lightgbm_tpu.obs import regress
-    from lightgbm_tpu.ops import chunkpolicy
 
     rows_list = [int(r) for r in args.chunk_rows.split(",") if r]
     if len(rows_list) < 2:
@@ -450,19 +444,19 @@ def _chunk_smoke(args, guard):
             "adaptive_engaged": bool(pol.adaptive),
             "trees_identical": key not in mismatch,
         }
-        # the measured verdict tpu_row_chunk=auto / tpu_chunk_policy=
-        # auto consult: keyed by the knob-free host/shape fingerprint.
-        # A regime that failed bit-identity must NOT feed the auto
-        # modes a speedup verdict for a broken path — its entry is
-        # recorded aborted (evidence kept, detector and consult skip).
+        # the measured verdict, keyed by the knob-free host/shape
+        # fingerprint (hardware + shape band: the sweep's job is to
+        # choose the knob, so the knob must not fork its series).  A
+        # regime that failed bit-identity is recorded aborted
+        # (evidence kept, the detector skips it).
         regress.append_entry(
-            chunkpolicy.SWEEP_TOOL,
+            "chunk_sweep",
             {"best_row_chunk": int(pol.base),
              "adaptive_speedup": tf / ta if ta > 0 else 0.0},
             config={"rows": rows, "features": args.features,
                     "leaves": leaves},
-            fingerprint_doc=chunkpolicy.sweep_fingerprint(
-                rows, args.features),
+            fingerprint_doc=regress.fingerprint(
+                config={}, rows=rows, features=args.features),
             aborted=key in mismatch)
 
     rr = np.asarray(rows_list, np.float64)

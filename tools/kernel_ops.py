@@ -97,7 +97,7 @@ def _partition(args):
     def fn(pb, pg, sp, s):
         return partition_leaf_pallas(
             pb, pg, sp, s, row_chunk=args.chunk, ghi_live=args.ghi_live,
-            pack_rowid=args.pack_rowid, compact_radix=args.radix)
+            pack_rowid=args.pack_rowid)
     return "lgbm_partition", fn, _operands(args)
 
 
@@ -109,7 +109,7 @@ def _split_mega(args):
         return split_megakernel_pallas(
             pb, pg, sp, s, row_chunk=args.chunk, num_bins=args.bins,
             num_groups=args.g32 - 4, ghi_live=args.ghi_live,
-            pack_rowid=args.pack_rowid, compact_radix=args.radix)
+            pack_rowid=args.pack_rowid)
     return "lgbm_split_mega", fn, _operands(args)
 
 
@@ -161,8 +161,6 @@ def main(argv=None):
     ap.add_argument("--chunk", type=int, default=4096, help="row_chunk")
     ap.add_argument("--ghi-live", type=int, default=3)
     ap.add_argument("--pack-rowid", action="store_true")
-    ap.add_argument("--radix", action="store_true",
-                    help="the radix-4 network (tpu_compact_radix)")
     ap.add_argument("--bins", type=int, default=255,
                     help="split_mega: histogram bins")
     ap.add_argument("--json", action="store_true")

@@ -37,7 +37,7 @@ NOOP_REASONS = {
     "machine_list_filename": "cluster bootstrap belongs to jax.distributed, not a machine file",
     "gpu_platform_id": "no OpenCL platform concept on TPU",
     "gpu_device_id": "device selection via JAX platform config",
-    "gpu_use_dp": "histograms are f32 (bf16 pair mode covers the half-precision analog)",
+    "gpu_use_dp": "histograms are f32",
     "num_gpu": "multi-chip via jax.sharding Mesh, not a device count knob",
 }
 

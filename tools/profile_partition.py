@@ -8,8 +8,7 @@ Variants:
   full / onenet / nonet — the partition kernel with both / one / zero
     compaction networks (the ablations produce WRONG layouts by design;
     they exist only here, for attribution);
-  radix                 — partition kernel, radix-4 compaction network;
-  mega / mega-radix     — the split mega-kernel (partition + BOTH
+  mega                  — the split mega-kernel (partition + BOTH
     children's histograms in one program): its per-chunk delta over
     "full" is the in-kernel histogram cost the e2e paired A/B
     (tools/ab_bench.py --b tpu_megakernel=pallas) trades against the
@@ -66,8 +65,7 @@ def run(C, variant):
     sc = np.zeros((sc_rows_for(G32), Npad), np.int32)
     scal = make_scalars(jnp.int32(C), jnp.int32(N), 3, 0, 0, 255, 0, 0,
                         128, 1)
-    mega = variant.startswith("mega")
-    radix = variant.endswith("radix")
+    mega = variant == "mega"
 
     _set_variant(variant if variant in ("full", "onenet", "nonet")
                  else "full")
@@ -79,11 +77,10 @@ def run(C, variant):
                 split_megakernel_pallas)
             pb, pg, sp, nl, acc = split_megakernel_pallas(
                 pb, pg, sp, scal, row_chunk=C, num_bins=255,
-                num_groups=28, ghi_live=GHL, compact_radix=radix)
+                num_groups=28, ghi_live=GHL)
             return (pb, pg, sp), nl[0, 0] + jnp.sum(acc).astype(jnp.int32)
         pb, pg, sp, nl = partition_leaf_pallas(
-            pb, pg, sp, scal, row_chunk=C, ghi_live=GHL,
-            compact_radix=radix)
+            pb, pg, sp, scal, row_chunk=C, ghi_live=GHL)
         return (pb, pg, sp), nl[0, 0]
 
     @jax.jit
@@ -116,8 +113,7 @@ if __name__ == "__main__":
                              {"rows": N, "reps": REPS}) as guard:
         metrics = {}
         for C in (4096, 2048, 8192):
-            for variant in ("full", "onenet", "nonet", "radix", "mega",
-                            "mega-radix"):
+            for variant in ("full", "onenet", "nonet", "mega"):
                 try:
                     metrics[f"C{C}_{variant}_per_chunk_us"] = \
                         run(C, variant)

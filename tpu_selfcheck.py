@@ -4,15 +4,14 @@ non-zero when the backend is not a TPU).
 
 Covers, in order:
   1. partition kernel vs the NumPy oracle (bit-exact, incl. rowid rows);
-  2. radix-4 compaction network vs the same oracle (tpu_compact_radix);
-  3. split-search kernel vs the XLA fast search;
-  4. rowid-row integrity through a full build_tree (the bitcast rowid row
+  2. split-search kernel vs the XLA fast search;
+  3. rowid-row integrity through a full build_tree (the bitcast rowid row
      must come back a permutation of the row ids);
-  5. hist-state RMW kernel vs numpy;
-  6. split mega-kernel vs the NumPy partition oracle (bit-exact) + the
+  4. hist-state RMW kernel vs numpy;
+  5. split mega-kernel vs the NumPy partition oracle (bit-exact) + the
      XLA both-children histogram oracle (f32 rounding, incl. the
      zero-count trash-slot call);
-  7. end-to-end train parity: Pallas kernels vs the XLA path
+  6. end-to-end train parity: Pallas kernels vs the XLA path
      (tpu_megakernel=off), then mega-pallas vs mega-xla.  Every arm
      asserts the kernel plan it asked for is the one that engaged.
 
@@ -79,30 +78,6 @@ def check_partition(rng):
         np.testing.assert_array_equal(np.asarray(rpg)[:nliv].view(np.int32),
                                       epg[:nliv].view(np.int32))
     return "partition kernel vs oracle (incl pack_rowid)"
-
-
-def check_radix(rng):
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops.partition_pallas import (
-        make_scalars, partition_leaf_pallas, sc_rows_for)
-    for trial in range(3):
-        pb = rng.randint(0, 250, (G32, NP)).astype(np.uint8)
-        pg = rng.randn(8, NP).astype(np.float32)
-        start = int(rng.randint(C, 5*C)); cnt = int(rng.randint(0, 4*C))
-        col = int(rng.randint(0, 28)); nb = int(rng.randint(10, 250))
-        thr = int(rng.randint(0, nb)); dl = int(rng.rand() < 0.5)
-        epb, epg, enl = _oracle(pb, pg, start, cnt, col, 0, 0, nb, 0, 0,
-                                thr, dl)
-        sc = make_scalars(start, cnt, col, 0, 0, nb, 0, 0, thr, dl)
-        rpb, rpg, _, rnl = partition_leaf_pallas(
-            jnp.asarray(pb), jnp.asarray(pg),
-            jnp.zeros((sc_rows_for(G32), NP), jnp.int32), sc, row_chunk=C,
-            compact_radix=True)
-        assert int(np.asarray(rnl)[0, 0]) == enl, trial
-        np.testing.assert_array_equal(np.asarray(rpb), epb)
-        np.testing.assert_array_equal(np.asarray(rpg)[:3].view(np.int32),
-                                      epg[:3].view(np.int32))
-    return "radix-4 compaction network vs oracle"
 
 
 def check_search(rng):
@@ -206,14 +181,13 @@ def check_megakernel(rng):
         col = int(rng.randint(0, G)); nb = int(rng.randint(10, 250))
         mtype = int(rng.randint(0, 3)); dbin = int(rng.randint(0, nb))
         thr = int(rng.randint(0, nb)); dl = int(rng.rand() < 0.5)
-        radix = trial == 2
         epb, epg, enl = _oracle(pb, pg, start, cnt, col, 0, 0, nb, dbin,
                                 mtype, thr, dl)
         sc = make_scalars(start, cnt, col, 0, 0, nb, dbin, mtype, thr, dl)
         rpb, rpg, _, rnl, acc = split_megakernel_pallas(
             jnp.asarray(pb), jnp.asarray(pg),
             jnp.zeros((sc_rows_for(G32), NP), jnp.int32), sc, row_chunk=C,
-            num_bins=B, num_groups=G, compact_radix=radix)
+            num_bins=B, num_groups=G)
         assert int(np.asarray(rnl)[0, 0]) == enl, trial
         np.testing.assert_array_equal(np.asarray(rpb), epb)
         np.testing.assert_array_equal(np.asarray(rpg)[:3].view(np.int32),
@@ -253,23 +227,18 @@ def check_e2e(rng):
     assert np.array_equal(flat, xstate)
     d1 = float(np.abs(flat - ref).max())
     mega_ref = train({"mega": "xla"}, tpu_megakernel="xla")
-    mega = {"partition": "pallas", "mega": "pallas"}
-    mega_bin = train({**mega, "compaction": "binary"},
-                     tpu_megakernel="pallas")
-    mega_rad = train({**mega, "compaction": "radix4"},
-                     tpu_megakernel="pallas", tpu_compact_radix=True)
-    # the radix-4 network produces bit-identical layouts
-    assert np.array_equal(mega_bin, mega_rad)
-    d2 = float(np.abs(mega_bin - mega_ref).max())
+    mega = train({"partition": "pallas", "mega": "pallas"},
+                 tpu_megakernel="pallas")
+    d2 = float(np.abs(mega - mega_ref).max())
     d3 = float(np.abs(mega_ref - ref).max())
     assert max(d1, d2, d3) < 1e-4, (d1, d2, d3)
     return (f"e2e pallas vs xla ({d1:.2e}), mega vs mega-oracle ({d2:.2e}), "
-            f"mega vs subtraction path ({d3:.2e}); layout variants "
-            "bit-identical, plans asserted")
+            f"mega vs subtraction path ({d3:.2e}); histogram-state "
+            "layouts bit-identical, plans asserted")
 
 
-STEPS = (check_partition, check_radix, check_search, check_rowid,
-         check_hist_rmw, check_megakernel, check_e2e)
+STEPS = (check_partition, check_search, check_rowid, check_hist_rmw,
+         check_megakernel, check_e2e)
 
 
 def main() -> int:
@@ -283,7 +252,7 @@ def main() -> int:
     rng = np.random.RandomState(7)
     for i, step in enumerate(STEPS, 1):
         print(f"[{i}/{len(STEPS)}] {step(rng)}: OK", flush=True)
-    print("TPU SELF-CHECK: ALL OK", flush=True)
+    print(f"TPU SELF-CHECK: ALL OK ({len(STEPS)}/{len(STEPS)})", flush=True)
     return 0
 
 
