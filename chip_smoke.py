@@ -43,10 +43,12 @@ TOY = {"rows": 20_000, "holdout": 5_000, "leaves": 31, "timed": 3,
        "contrib_rows": 512}
 
 # what `auto` must resolve to for this shape (all-numerical, u8 bins, serial)
-TPU_PLAN = {"partition": "pallas", "search": "pallas", "mega": "pallas",
-            "frontier_k": 4, "fused": "on", "tree_learner": "serial"}
-CPU_PLAN = {"partition": "xla", "search": "xla", "mega": "off",
-            "frontier_k": 1, "fused": "on", "tree_learner": "serial"}
+TPU_PLAN = {"partition": "pallas", "hist": "pallas", "search": "pallas",
+            "mega": "pallas", "frontier_k": 4, "fused": "on",
+            "tree_learner": "serial"}
+CPU_PLAN = {"partition": "xla", "hist": "xla", "search": "xla",
+            "mega": "off", "frontier_k": 1, "fused": "on",
+            "tree_learner": "serial"}
 
 
 def say(msg):

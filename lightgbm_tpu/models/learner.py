@@ -47,6 +47,7 @@ from ..obs import scopes
 from ..ops import split as split_ops
 from ..ops.chunkpolicy import ChunkPolicy
 from ..ops.histogram import leaf_hist_slice
+from ..ops.histogram_pallas import leaf_hist_pallas
 from ..ops.partition import split_decision
 from ..utils import log
 from . import plan as plan_mod
@@ -424,6 +425,7 @@ class SerialTreeLearner:
             linear_gain_requested=(
                 bool(config.linear_tree)
                 and config.linear_tree_mode == "leafwise_gain"),
+            quantized=bool(config.use_quantized_grad),
             parallel_mode=parallel_mode, axis_name=axis_name is not None,
             num_shards=num_shards,
             **{k: getattr(config, k) for k in plan_mod.OPTION_FIELDS}))
@@ -628,6 +630,10 @@ class SerialTreeLearner:
     # ------------------------------------------------------------------
     @scopes.phase("histogram")
     def _hist_leaf(self, part_bins, part_ghi, start, cnt, scale=None):
+        if self.plan.hist == "pallas":
+            # f32 gradients only: the plan keeps quantized carriers on
+            # the XLA loop below
+            return self._hist_leaf_kernel(part_bins, part_ghi, start, cnt)
         # quantized training rides INTEGER gradient carriers: the one-hot
         # matmuls run in bfloat16 (exact for the small int grid, double
         # MXU rate — the int16-histogram analog).  The histogram stays
@@ -654,6 +660,14 @@ class SerialTreeLearner:
                                vary=self._pvary, num_groups=self.G,
                                dtype=dtype)
 
+    def _hist_leaf_kernel(self, part_bins, part_ghi, start, cnt,
+                          flat_geom=None):
+        """One ``lgbm_histogram`` launch for the leaf (plan.hist=pallas)."""
+        return leaf_hist_pallas(
+            part_bins, part_ghi, start, cnt, num_bins=self.B,
+            row_chunk=self.row_chunk, num_groups=self.G,
+            flat_geom=flat_geom, interpret=self._interp)
+
     @staticmethod
     def _scale_hist(h, scale):
         """Integer-domain quantized histogram -> gain domain at a
@@ -667,6 +681,9 @@ class SerialTreeLearner:
     def _hist_leaf_flat(self, part_bins, part_ghi, start, cnt):
         """Smaller-child histogram directly in the lane-flattened (8, WL)
         slot layout of the Pallas hist-state RMW kernel."""
+        if self.plan.hist == "pallas":
+            return self._hist_leaf_kernel(part_bins, part_ghi, start, cnt,
+                                          flat_geom=self._flat_geom)
         return leaf_hist_slice(part_bins, part_ghi, start, cnt,
                                num_bins=self.B, row_chunk=self.row_chunk,
                                vary=self._pvary, num_groups=self.G,

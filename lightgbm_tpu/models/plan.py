@@ -59,6 +59,7 @@ class PlanFacts:
     l1: float = 0.0
     max_delta_step: float = 0.0
     linear_gain_requested: bool = False   # linear_tree_mode=leafwise_gain
+    quantized: bool = False       # use_quantized_grad: integer carriers
     parallel_mode: str = "serial"
     axis_name: bool = False       # runs inside shard_map
     num_shards: int = 1
@@ -81,6 +82,7 @@ OPTION_FIELDS = tuple(f.name for f in fields(PlanFacts)
 @dataclass(frozen=True, kw_only=True)
 class SplitPlan:
     partition: str          # "pallas" | "xla"
+    hist: str               # leaf histograms: "pallas" (one kernel) | "xla"
     fast_search: bool       # op-packed XLA search (else the general one)
     search: str             # "pallas" (pair kernel) | "xla"
     mega: str               # "pallas" | "xla" | "off"
@@ -98,7 +100,8 @@ class SplitPlan:
 
     def kernel_plan(self) -> Dict[str, Any]:
         """The printable record ``GBDT.kernel_plan`` extends."""
-        return {"partition": self.partition, "search": self.search,
+        return {"partition": self.partition, "hist": self.hist,
+                "search": self.search,
                 "hist_state": self.hist_state, "mega": self.mega,
                 "frontier_k": self.frontier_k}
 
@@ -143,6 +146,18 @@ def resolve(f: PlanFacts) -> SplitPlan:
     if block:
         why["partition"] = _said("xla", block)
     partition_xla = _given((not pallas_part, "partition=xla"))
+
+    # ---- Pallas leaf histogram (ops/histogram_pallas.py): reads the
+    # sublane-padded buffers the partition kernel's DMA tiling asks for,
+    # and splits f32 weights into bf16 limbs ----
+    block = _given(
+        (not pallas_part, "partition=xla: the row buffers are not "
+                          "sublane-padded for window DMAs"),
+        (f.quantized, "use_quantized_grad: the integer carriers are "
+                      "exact in one bf16 pass of the XLA loop"))
+    hist = "xla" if block else "pallas"
+    if block:
+        why["hist"] = _said("xla", block)
 
     # ---- op-packed fast search ----
     not_fast = _given(
@@ -326,7 +341,8 @@ def resolve(f: PlanFacts) -> SplitPlan:
             why["pack_rowid"] = _said("off", block)
 
     return SplitPlan(
-        partition="pallas" if pallas_part else "xla", fast_search=fast,
+        partition="pallas" if pallas_part else "xla", hist=hist,
+        fast_search=fast,
         search=search, mega=mega, frontier_k=frontier_k,
         hist_state=hist_state, row_chunk=row_chunk,
         chunk_adaptive=policy.adaptive, pack_rowid=pack_rowid,

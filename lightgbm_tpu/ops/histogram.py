@@ -18,10 +18,18 @@ loop, ~60% of its wall clock.  (G, N) row-major is the same physical bytes
 as (N, G) column-major, so every consumer now agrees with the layout XLA
 wants and the copies vanish.
 
-``leaf_hist_slice`` is a pure-XLA chunked einsum that runs everywhere (on
-the TPU default path the split mega-kernel accumulates both children's
-histograms itself; a stand-alone Pallas histogram kernel was deleted in PR 21
-— the installed Mosaic refuses its u8 mid-axis DMA slice).
+``leaf_hist_slice`` is a pure-XLA chunked einsum that runs everywhere: it
+is what the CPU, the parallel learners, categorical data, quantized
+gradients and the banded chunk policy run, and the oracle of the kernels.
+Where the Pallas partition kernel runs (TPU, u8 bins, serial, numerical
+features) and the gradients are f32, ``models/plan.py`` gives a leaf's
+histogram to ONE Pallas kernel launch instead (``lgbm_histogram``,
+ops/histogram_pallas.py, PR 32): compiled for the v5e this loop is a serial
+chain of 10-13 device operations per 4096-row chunk, each a round trip
+through VMEM, and with its matmuls taken out it still costs 9.9 of its
+19.1 us a chunk at 255 bins and 7.7 of 10.7 at 63 (PERF.md section 6).  The mega-kernel (ops/split_megakernel_pallas.py),
+below 2^24 rows, accumulates both children's histograms itself and leaves
+only the root to either form.  ``hist_tail`` is shared by both.
 
 The contraction layout batches ``gblock`` feature groups into the matmul N
 dimension — out[(j),(g,b)] = sum_c gh[j,c] * (bins[g,c]==b) — because the
@@ -147,15 +155,22 @@ def leaf_hist_slice(part_bins, part_ghi, start, cnt, *,
     acc = vary(jnp.zeros((nblk, gblock, 2 * BH, 16), jnp.float32))
     acc = jax.lax.fori_loop(0, n_chunks, body, acc)
     per = acc.reshape(Gp, 2 * BH, 16)[:G]               # block-major == G
-    per = per.reshape(G, 2, Bp)                         # b = hi*16 + lo
+    return hist_tail(per.reshape(G, 2, Bp), B, flat_geom)  # b = hi*16 + lo
+
+
+def hist_tail(per, num_bins: int, flat_geom=None):
+    """The (G, 2, Bp) planes of an accumulator (Bp >= num_bins, the bin
+    axis flattened row-major over its digits) -> the (G, B, 2) histogram,
+    or with ``flat_geom`` the (8, WL) lane-flattened (2, Gf, Bf) slot of
+    the Pallas hist-state RMW kernel (ops/hist_state_pallas.py).  Shared
+    by the XLA chunk loop above and ops/histogram_pallas.py."""
+    G = per.shape[0]
     if flat_geom is not None:
-        # (8, WL) lane-flattened (2, Gf, Bf) slot for the Pallas
-        # hist-state RMW kernel (ops/hist_state_pallas.py)
         Gf, Bf, WL = flat_geom
-        jg = jnp.moveaxis(per, 1, 0)                    # (2, G, Bp)
-        jg = jnp.pad(jg, ((0, 0), (0, Gf - G), (0, Bf - Bp)))
+        jg = jnp.moveaxis(per[:, :, :Bf], 1, 0)         # (2, G, <=Bf)
+        jg = jnp.pad(jg, ((0, 0), (0, Gf - G), (0, Bf - jg.shape[2])))
         return jg.reshape(8, WL)
-    return jnp.moveaxis(per[:, :, :B], 1, 2)            # (G, B, 2)
+    return jnp.moveaxis(per[:, :, :num_bins], 1, 2)     # (G, B, 2)
 
 
 def leaf_hist_banded(part_bins, part_ghi, start, cnt, *, num_bins: int,

@@ -95,3 +95,29 @@ def test_partition_pass1_vector_ops_ceiling():
     assert 0 < rotates <= 1043, pass1
     assert pass1["vector_ops"] <= 11295, pass1
     assert pass2["vector_ops"] <= 2370, pass2
+
+
+@pytest.mark.parametrize("bins, chunk_ops, step_ops, matmuls",
+                         [(255, 1350, 14855, 224), (63, 1320, 8618, 112)],
+                         ids=["b255", "b63"])
+def test_histogram_step_vector_ops_ceiling(bins, chunk_ops, step_ops,
+                                           matmuls):
+    """``lgbm_histogram`` at the cells' geometry (28 features in 32 u8
+    sublanes, C=4096).  Body 0 is what a chunk pays once (the u8 rows to
+    i32, the limbs of grad and hess, their planes), body 1 one inner step
+    of 2048 lanes, two a chunk: building the operands (selects, compares,
+    packs to bf16) and one matmul per feature group and 128 lanes of the
+    step, each streaming P*R rows through the MXU (48 at 255 bins, 64 at
+    63: 21,504 and 14,336 rows a chunk).  The kernel is bound by those
+    rows and by its matmul count, not by the vector work (PERF.md section
+    6, PR 32), so the matmul count is exact: one more is a feature group
+    more, or a narrower tile.  Read on the installed libtpu 0.0.34; the
+    ceilings are 5% above.  The kernel rolls nothing: a lane rotate here
+    would be a relayout Mosaic slipped in (PR 30 on what those cost)."""
+    out = _count("histogram", "--bins", str(bins))
+    per_chunk, per_step = out["bodies"]
+    assert per_chunk["vector_ops"] <= chunk_ops * 1.05, per_chunk
+    assert per_step["vector_ops"] <= step_ops * 1.05, per_step
+    assert per_step["by_kind"].get("tpu.matmul", 0) == matmuls, per_step
+    for body in out["bodies"]:
+        assert "tpu.dynamic_rotate" not in body["by_kind"], body

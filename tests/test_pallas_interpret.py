@@ -305,3 +305,90 @@ def test_partition_kernel_pack_rowid_interpreted(trial):
         np.testing.assert_array_equal(
             np.asarray(rpg)[:ghi_live].view(np.int32),
             epg[:ghi_live].view(np.int32))
+
+
+# ---------------------------------------------------------------------------
+# lgbm_histogram (ops/histogram_pallas.py) against the XLA chunk loop
+# ---------------------------------------------------------------------------
+_HC = 256                              # row_chunk of these cases
+_HIST_CNT = {"zero": 0, "one": 1, "chunk_less_1": _HC - 1, "chunk": _HC,
+             "three_chunks_17": 3 * _HC + 17}
+
+
+@functools.lru_cache(maxsize=None)
+def _hist_pair(B, G, flat):
+    """(kernel, XLA loop) jitted once per shape: start and cnt are traced,
+    as in the tree loop."""
+    from lightgbm_tpu.ops.hist_state_pallas import flat_geometry
+    from lightgbm_tpu.ops.histogram import leaf_hist_slice
+    from lightgbm_tpu.ops.histogram_pallas import leaf_hist_pallas
+    kw = dict(num_bins=B, row_chunk=_HC, num_groups=G,
+              flat_geom=flat_geometry(G, B) if flat else None)
+    return (jax.jit(functools.partial(leaf_hist_pallas, interpret=True,
+                                      **kw)),
+            jax.jit(functools.partial(leaf_hist_slice, **kw)))
+
+
+def _hist_case(B, G, cnt, flat=False):
+    """Rows outside [start, start + cnt) hold gradients 1e30 times the
+    leaf's: a single one that leaked would swamp every bin.  ``start`` is
+    unaligned to 128, so the kernel's cover begins before the leaf.
+
+    Not bit-equal by construction: the kernel sums the three bf16 limbs of
+    a weight separately, over the chunks of the 128-aligned cover, and adds
+    the limb sums last; the XLA loop sums f32 products over chunks that
+    begin at ``start``.  Every product is exact in both, so the two differ
+    by f32 reassociation only: 1e-6 of the plane's largest bin (measured
+    1.7e-7 at most)."""
+    start = _HC + 37
+    Np = 8 * _HC
+    rng = np.random.RandomState(7 * B + G)
+    pb = np.zeros((32, Np), np.uint8)
+    pb[:G] = rng.randint(0, B, (G, Np))
+    pg = (rng.randn(8, Np) * 1e30).astype(np.float32)
+    pg[:2, start:start + cnt] = rng.randn(2, cnt)
+    kernel, loop = _hist_pair(B, G, flat)
+    args = (jnp.asarray(pb), jnp.asarray(pg), jnp.int32(start),
+            jnp.int32(cnt))
+    got, ref = np.asarray(kernel(*args)), np.asarray(loop(*args))
+    assert got.shape == ref.shape and np.isfinite(got).all()
+    if cnt == 0:
+        assert not got.any()
+    np.testing.assert_allclose(got, ref, rtol=1e-6,
+                               atol=1e-6 * max(np.abs(ref).max(), 1e-30))
+
+
+@pytest.mark.parametrize("cnt", sorted(_HIST_CNT))
+@pytest.mark.parametrize("G", [28, 5])
+@pytest.mark.parametrize("B", [255, 63, 16])
+def test_histogram_kernel_interpreted(B, G, cnt):
+    _hist_case(B, G, _HIST_CNT[cnt])
+
+
+@pytest.mark.parametrize("B", [255, 63])
+def test_histogram_kernel_flat_slot_interpreted(B):
+    """The (8, WL) slot of the hist-state RMW kernel: the same tail as the
+    XLA loop's (ops/histogram.py:hist_tail)."""
+    _hist_case(B, 28, 3 * _HC + 17, flat=True)
+
+
+def test_histogram_kernel_follows_the_dot_precision(monkeypatch):
+    """benchmark/control.py plants its fault by lowering F32_DOT_PRECISION
+    in every module that has it: the kernel then runs ONE bf16 limb, and
+    the histogram is off by bf16's rounding, 2^-9 of a weight, not f32's."""
+    from lightgbm_tpu.ops import histogram_pallas as hp
+    from lightgbm_tpu.ops.histogram import leaf_hist_slice
+    rng = np.random.RandomState(3)
+    Np, G, B = 8 * _HC, 28, 255
+    pb = np.zeros((32, Np), np.uint8)
+    pb[:G] = rng.randint(0, B, (G, Np))
+    pg = rng.randn(8, Np).astype(np.float32)
+    args = (jnp.asarray(pb), jnp.asarray(pg), _HC + 37, 3 * _HC)
+    kw = dict(num_bins=B, row_chunk=_HC, num_groups=G)
+    ref = np.asarray(leaf_hist_slice(*args, **kw))
+    sound = np.asarray(hp.leaf_hist_pallas(*args, interpret=True, **kw))
+    monkeypatch.setattr(hp, "F32_DOT_PRECISION", jax.lax.Precision.DEFAULT)
+    low = np.asarray(hp.leaf_hist_pallas(*args, interpret=True, **kw))
+    scale = np.abs(ref).max()
+    assert np.abs(sound - ref).max() < 1e-6 * scale
+    assert 1e-4 * scale < np.abs(low - ref).max() < 1e-2 * scale

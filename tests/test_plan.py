@@ -17,7 +17,8 @@ OPTIONS = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
 # benchmark/configs/higgs-l255-b255.json at rows42m on the chip
 CELL = dict(backend="tpu", rows=42_000_000, F=28, G=28, B=255,
             num_leaves=255)
-CELL_PLAN = dict(partition="pallas", fast_search=False, search="xla",
+CELL_PLAN = dict(partition="pallas", hist="pallas", fast_search=False,
+                 search="xla",
                  mega="off", frontier_k=4, hist_state="xla",
                  row_chunk=4096, chunk_adaptive=False, pack_rowid=False,
                  scatter_groups=False, linear_gain=False)
@@ -56,6 +57,23 @@ CASES = {
                   dict(fast_search=False, search="xla", mega="off",
                        frontier_k=4),
                   ("fast_search", "rows 16,777,216 >= 2^24"), None),
+    # the leaf-histogram kernel goes where the partition kernel goes, f32
+    # gradients only
+    "hist_cpu": (TOY_CPU, dict(partition="xla", hist="xla"),
+                 ("hist", "partition=xla"), None),
+    "hist_categorical": (dict(has_categorical=True),
+                         dict(partition="xla", hist="xla"),
+                         ("hist", "partition=xla"), None),
+    "hist_data_parallel": (DATA4, dict(partition="xla", hist="xla"),
+                           ("hist", "partition=xla"), None),
+    "hist_quantized": (dict(quantized=True),
+                       dict(partition="pallas", hist="xla"),
+                       ("hist", "use_quantized_grad"), None),
+    "hist_smoke_mega": (SMOKE, dict(mega="pallas", hist="pallas"),
+                        ("hist_state", "mega=pallas"), None),
+    "hist_interpret": (dict(TOY_CPU, interpret=True),
+                       dict(partition="pallas", hist="pallas"),
+                       ("frontier_k", "auto is 1 on backend cpu"), None),
     "cpu": (TOY_CPU, dict(partition="xla", fast_search=True, search="xla",
                           mega="off", frontier_k=1, hist_state="xla",
                           row_chunk=2048, chunk_adaptive=True),
@@ -161,8 +179,9 @@ def test_plan_table(name):
     else:
         assert any(warning in w for w in p.unmet), p.unmet
     # a decision that took its kernel path gives no reason
-    for key, value in (("partition", "pallas"), ("search", "pallas"),
-                       ("mega", "pallas"), ("hist_state", "flat")):
+    for key, value in (("partition", "pallas"), ("hist", "pallas"),
+                       ("search", "pallas"), ("mega", "pallas"),
+                       ("hist_state", "flat")):
         assert (getattr(p, key) == value) == (key not in p.why), (key, p)
 
 

@@ -10,7 +10,8 @@ device_phase.py).
 * every kernel plan the CPU reaches yields a table that names the histogram,
   the search and the partition;
 * the benchmark's reader on a synthetic ``op_seconds``;
-* the Pallas partition kernel compiles for ``v5e:2x2`` under its own name.
+* the Pallas partition and histogram kernels compile for ``v5e:2x2`` under
+  their own names, and the toy step's table gives them their phases.
 """
 
 import contextlib
@@ -482,8 +483,32 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
+@contextlib.contextmanager
+def _compile_cache_off():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _assert_only_kernel(text, name, phase):
+    """Every Pallas call of the compiled text is ``name`` under ``phase``."""
+    table = scopes.phase_of(text)
+    kernels = [n for n in table if n.startswith(name)]
+    assert kernels and all(table[n] == phase for n in kernels)
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and set(calls) == set(kernels)
+
+
+def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
     from lightgbm_tpu.ops.partition_pallas import (make_scalars,
                                                    partition_leaf_pallas,
                                                    sc_rows_for)
@@ -503,24 +528,38 @@ def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without one
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         text = jax.jit(splits).lower(
             sds((G32, Np), jnp.uint8), sds((8, Np), jnp.float32),
             sds((sc_rows_for(G32), Np), jnp.int32)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-    table = scopes.phase_of(text)
-    kernels = [n for n in table if n.startswith("lgbm_partition")]
-    assert kernels and all(table[n] == "partition" for n in kernels)
-    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
-    assert calls and set(calls) == set(kernels)
+    _assert_only_kernel(text, "lgbm_partition", "partition")
+
+
+@pytest.mark.parametrize("B", [255, 63])
+def test_histogram_kernel_compiles_for_v5e_under_its_name(one_chip, B):
+    """The cells' geometry (28 features in 32 u8 sublanes, 4096-row
+    chunks): what interpret mode cannot show is whether Mosaic takes the
+    window DMAs, the bf16 operands and the VMEM the kernel asks for."""
+    from lightgbm_tpu.ops.histogram_pallas import leaf_hist_pallas
+    C, G32, Np = 4096, 32, 64 * 4096
+
+    def leaves(pb, pg):
+        def body(i, acc):
+            with scopes.scope("histogram"):
+                return acc + leaf_hist_pallas(
+                    pb, pg, C + 37 + i, 20 * C, num_bins=B, row_chunk=C,
+                    num_groups=28)
+        return jax.lax.fori_loop(0, 3, body,
+                                 jnp.zeros((28, B, 2), jnp.float32))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _compile_cache_off():
+        text = jax.jit(leaves).lower(
+            sds((G32, Np), jnp.uint8),
+            sds((8, Np), jnp.float32)).compile().as_text()
+    _assert_only_kernel(text, "lgbm_histogram", "histogram")
 
 
 def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
@@ -534,7 +573,6 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
     write.  (The snapshot reads rows that the partition kernel then
     overwrites in place; unordered, the compiler keeps them alive in two
     payload copies per split.)"""
-    from jax.experimental.compilation_cache import compilation_cache
     from lightgbm_tpu.models import learner as learner_mod
     monkeypatch.setattr(learner_mod, "_SNAP_WINDOW", 128)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -549,17 +587,11 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         text = g._fused_phys.lower(
             sds((lr._pb_rows, Np), jnp.uint8), sds((8, Np), jnp.float32),
             sds((lr.F,), jnp.bool_), 1,
             sds((lr.F,), jnp.bool_)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
     _, computations, ops = _frontier_loop(text)
     wide_bookkeeping = 0
     for line, phase, typ, dims in ops:
@@ -572,3 +604,10 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
             wide_bookkeeping += 1
     assert "lgbm_partition" in text
     assert wide_bookkeeping >= 1
+    # the leaf histograms are one kernel each, and the step's scope table
+    # gives every launch of it (the root's, the smaller children's) the
+    # phase the benchmark reads as histogram_s_per_iter
+    assert kp["hist"] == "pallas"
+    table = scopes.phase_of(text)
+    hist = [n for n in table if n.startswith("lgbm_histogram")]
+    assert len(hist) >= 2 and {table[n] for n in hist} == {"histogram"}

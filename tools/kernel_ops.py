@@ -113,7 +113,19 @@ def _split_mega(args):
     return "lgbm_split_mega", fn, _operands(args)
 
 
-KERNELS = {"partition": _partition, "split_mega": _split_mega}
+def _histogram(args):
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram_pallas import leaf_hist_acc_pallas
+
+    def fn(pb, pg, start, cnt):
+        return leaf_hist_acc_pallas(
+            pb, pg, start, cnt, num_bins=args.bins, row_chunk=args.chunk,
+            num_groups=args.g32 - 4)
+    return "lgbm_histogram", fn, _operands(args)[:2] + [((), jnp.int32)] * 2
+
+
+KERNELS = {"partition": _partition, "split_mega": _split_mega,
+           "histogram": _histogram}
 
 
 def compile_and_count(args) -> dict:
@@ -162,7 +174,7 @@ def main(argv=None):
     ap.add_argument("--ghi-live", type=int, default=3)
     ap.add_argument("--pack-rowid", action="store_true")
     ap.add_argument("--bins", type=int, default=255,
-                    help="split_mega: histogram bins")
+                    help="split_mega, histogram: histogram bins")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
     out = compile_and_count(args)

@@ -1,6 +1,16 @@
-"""Standalone cost of the XLA histogram formulation on a live TPU, with
-A/B variants of the one-hot generation.  Times R accumulations of a full
-N-row leaf."""
+"""Stand-alone cost of one leaf's histogram on a live TPU: the form the
+plan chooses there (``lgbm_histogram``, ops/histogram_pallas.py) and the XLA
+chunk loop (ops/histogram.py) beside it, on one synthetic leaf.
+
+    python tools/profile_hist.py [rows] [bins] [leaves]
+
+Times one pass over ONE leaf of ``rows`` rows (ms, us a 4096-row chunk),
+then a pass over ``leaves`` equal leaves in one program (what a launch
+costs), and prints the largest gap between the two forms relative to the
+largest bin.  The geometry is the benchmark cells': 28 features in 32 u8
+sublanes, 4096-row chunks.  Off the TPU the kernel runs interpreted at a
+toy size: the times then say nothing.
+"""
 
 import os
 import sys
@@ -12,87 +22,70 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-N = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
-REPS = int(sys.argv[2]) if len(sys.argv) > 2 else 50
-G, B, C = 32, 255, 4096
+G, G32 = 28, 32
 
 
-def variant_current(part_bins, ghi, start, cnt):
+def forms(B, C, interpret):
     from lightgbm_tpu.ops.histogram import leaf_hist_slice
-    return leaf_hist_slice(part_bins, ghi, start, cnt,
-                           num_bins=B, row_chunk=C)
+    from lightgbm_tpu.ops.histogram_pallas import leaf_hist_pallas
+    kw = dict(num_bins=B, row_chunk=C, num_groups=G)
+    return {"pallas": lambda *a: leaf_hist_pallas(*a, interpret=interpret,
+                                                  **kw),
+            "xla": lambda *a: leaf_hist_slice(*a, **kw)}
 
 
-def variant_fusedgen(part_bins, ghi, start, cnt):
-    """Weighted high-digit one-hots generated directly via where (no raw
-    oh_hi materialization)."""
-    Np = part_bins.shape[1]
-    BH = (B + 15) // 16
-    gblock = max(1, (4 * 1024 * 1024) // (C * (16 + 2 * BH) * 4))
-    nblk = (G + gblock - 1) // gblock
-    Gp = nblk * gblock
-    n_chunks = (cnt + C - 1) // C
-    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (1, 1, BH), 2)
-    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 16), 2)
+def run(rows, B, leaves):
+    on_tpu = jax.default_backend() == "tpu"
+    C = 4096 if on_tpu else 256
+    Np = C + ((rows + C - 1) // C + 2) * C
+    key = jax.random.PRNGKey(B)
+    pb = jax.random.randint(key, (G32, Np), 0, B,
+                            dtype=jnp.int32).astype(jnp.uint8)
+    pg = jax.random.normal(jax.random.fold_in(key, 1), (8, Np), jnp.float32)
+    per = rows // leaves
+    out, metrics = {}, {}
+    for name, fn in forms(B, C, not on_tpu).items():
+        one = jax.jit(fn)
 
-    def body(ci, acc):
-        row0 = start + ci * C
-        bins = jax.lax.dynamic_slice(part_bins, (0, row0),
-                                     (G, C)).astype(jnp.int32)
-        gh3 = jax.lax.dynamic_slice(ghi, (0, row0), (ghi.shape[0], C))
-        valid = (ci * C + jax.lax.iota(jnp.int32, C)) < cnt
-        gv = (gh3[0] * valid)[None, :, None]
-        hv = (gh3[1] * valid)[None, :, None]
-        if Gp > G:
-            bins = jnp.pad(bins, ((0, Gp - G), (0, 0)), constant_values=-1)
-        out = []
-        for i in range(nblk):
-            blk = bins[i * gblock:(i + 1) * gblock, :]
-            m_hi = (blk >> 4)[:, :, None] == iota_hi
-            oh_lo = ((blk & 15)[:, :, None] == iota_lo).astype(jnp.float32)
-            wg = jnp.concatenate([jnp.where(m_hi, gv, 0.0),
-                                  jnp.where(m_hi, hv, 0.0)], axis=2)
-            out.append(jax.lax.dot_general(
-                wg, oh_lo, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32))
-        return acc + jnp.stack(out)
+        @jax.jit
+        def many(pb, pg):
+            def leaf(i, acc):
+                return acc + fn(pb, pg, C + 37 + i * per, jnp.int32(per))
+            return jax.lax.fori_loop(0, leaves, leaf,
+                                     jnp.zeros((G, B, 2), jnp.float32))
 
-    acc = jnp.zeros((nblk, gblock, 2 * BH, 16), jnp.float32)
-    acc = jax.lax.fori_loop(0, n_chunks, body, acc)
-    per = acc.reshape(Gp, 2 * BH, 16)[:G].reshape(G, 2, BH * 16)
-    return jnp.moveaxis(per[:, :, :B], 1, 2)
-
-
-def run(name, fn):
-    Npad = ((N + 2 * C + 127) // 128) * 128 + 2 * C
-    rng = np.random.RandomState(0)
-    bins = jnp.asarray(rng.randint(0, B, size=(G, Npad)).astype(np.uint8))
-    ghi = jnp.asarray(rng.normal(size=(8, Npad)).astype(np.float32))
-
-    @jax.jit
-    def many(b, g):
-        def one(i, acc):
-            return acc + fn(b, g, jnp.int32(C), jnp.int32(N))[0, 0, 0]
-        return jax.lax.fori_loop(0, REPS, one, jnp.float32(0.0))
-
-    float(many(bins, ghi))
-    t0 = time.time()
-    float(many(bins, ghi))
-    wall = time.time() - t0 - 0.105
-    per_pass_ms = wall / REPS * 1e3
-    print(f"{name:12s} per-pass={per_pass_ms:.2f} ms/Mrow-pass")
-    return per_pass_ms
+        args = (pb, pg, jnp.int32(C + 37), jnp.int32(rows))
+        out[name] = np.asarray(one(*args))             # compile and warm
+        jax.block_until_ready(many(pb, pg))
+        t0 = time.time()
+        jax.block_until_ready(one(*args))
+        whole = time.time() - t0
+        t0 = time.time()
+        jax.block_until_ready(many(pb, pg))
+        split = time.time() - t0
+        metrics[f"{name}_pass_ms"] = whole * 1e3
+        metrics[f"{name}_us_per_chunk"] = whole / rows * 4096e6
+        metrics[f"{name}_{leaves}_leaves_ms"] = split * 1e3
+        print(f"{name:7s} one leaf {whole * 1e3:9.2f} ms "
+              f"({whole / rows * 4096e6:6.2f} us a 4096-row chunk)   "
+              f"{leaves} leaves {split * 1e3:9.2f} ms", flush=True)
+    gap = float(np.abs(out["pallas"] - out["xla"]).max()
+                / np.abs(out["xla"]).max())
+    print(f"largest gap, relative to the largest bin: {gap:.2e}")
+    metrics["rel_gap"] = gap
+    return metrics
 
 
 if __name__ == "__main__":
-    print(f"N={N} reps={REPS} {jax.devices()}")
+    rows = int(sys.argv[1]) if len(sys.argv) > 1 else 4_000_000
+    bins = int(sys.argv[2]) if len(sys.argv) > 2 else 255
+    leaves = int(sys.argv[3]) if len(sys.argv) > 3 else 256
+    print(f"rows={rows} bins={bins} {jax.devices()}")
     from lightgbm_tpu.obs import benchio
-    # trajectory wiring: one fingerprinted entry per run (aborted=true
-    # if a variant dies, e.g. off-TPU), so on-hardware rounds of this
-    # harness are regression-gated like every other producer
+    # one fingerprinted entry per run (aborted=true if a form dies), so
+    # on-hardware rounds of this harness are regression-gated like every
+    # other producer
     with benchio.abort_guard("profile_hist",
-                             {"rows": N, "reps": REPS}) as guard:
-        metrics = {f"{name}_per_pass_ms": run(name, fn)
-                   for name, fn in (("current", variant_current),
-                                    ("fusedgen", variant_fusedgen))}
-        guard.write(dict(metrics), metrics=metrics, rows=N)
+                             {"rows": rows, "bins": bins}) as guard:
+        metrics = run(rows, bins, leaves)
+        guard.write(dict(metrics), metrics=metrics, rows=rows)

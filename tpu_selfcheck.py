@@ -11,7 +11,11 @@ Covers, in order:
   5. split mega-kernel vs the NumPy partition oracle (bit-exact) + the
      XLA both-children histogram oracle (f32 rounding, incl. the
      zero-count trash-slot call);
-  6. end-to-end train parity: Pallas kernels vs the XLA path
+  6. leaf-histogram kernel vs a float64 NumPy oracle and vs the XLA chunk
+     loop, at 255 and 63 bins (unaligned starts, zero and one row, foreign
+     rows holding huge gradients), and one precision step down: with
+     F32_DOT_PRECISION lowered it must be off by bf16's rounding;
+  7. end-to-end train parity: Pallas kernels vs the XLA path
      (tpu_megakernel=off), then mega-pallas vs mega-xla.  Every arm
      asserts the kernel plan it asked for is the one that engaged.
 
@@ -204,6 +208,52 @@ def check_megakernel(rng):
     return "mega-kernel vs partition+hist oracles"
 
 
+def check_histogram(rng):
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import histogram_pallas as hp
+    from lightgbm_tpu.ops.histogram import leaf_hist_slice
+    G = 28
+    worst = 0.0
+    for B in (255, 63):
+        pb = np.zeros((G32, NP), np.uint8)
+        pb[:G] = rng.randint(0, B, (G, NP))
+        kw = dict(num_bins=B, row_chunk=C, num_groups=G)
+        for start, cnt in ((C + 37, 0), (C + 37, 1), (2 * C - 1, C),
+                           (C + 300, 5 * C + 17), (3 * C, 2 * C)):
+            pg = (rng.randn(8, NP) * 1e30).astype(np.float32)
+            pg[:2, start:start + cnt] = rng.randn(2, cnt)
+            ref = np.zeros((G, B, 2))
+            rows = slice(start, start + cnt)
+            for g in range(G):
+                for j in range(2):
+                    np.add.at(ref[g, :, j], pb[g, rows],
+                              pg[j, rows].astype(np.float64))
+            args = (jnp.asarray(pb), jnp.asarray(pg), jnp.int32(start),
+                    jnp.int32(cnt))
+            got = np.asarray(hp.leaf_hist_pallas(*args, **kw))
+            loop = np.asarray(leaf_hist_slice(*args, **kw))
+            scale = max(np.abs(ref).max(), 1e-30)
+            assert np.isfinite(got).all()
+            if cnt == 0:
+                assert not got.any()
+            for other in (ref, loop):
+                gap = float(np.abs(got - other).max() / scale)
+                worst = max(worst, gap)
+                assert gap < 5e-6, (B, start, cnt, gap)
+        # one precision step down (benchmark/control.py's fault), on the
+        # last range above
+        high = hp.F32_DOT_PRECISION
+        hp.F32_DOT_PRECISION = jax.lax.Precision.DEFAULT
+        try:
+            low = np.asarray(hp.leaf_hist_pallas(*args, **kw))
+        finally:
+            hp.F32_DOT_PRECISION = high
+        gap = float(np.abs(low - ref).max() / scale)
+        assert 1e-4 < gap < 1e-2, (B, gap)
+    return f"histogram kernel vs f64 oracle and XLA loop ({worst:.2e})"
+
+
 def check_e2e(rng):
     import lightgbm_tpu as lgb
     X, y = _e2e_data(rng)
@@ -217,9 +267,11 @@ def check_e2e(rng):
             f"asked for {expect}, resolved {plan}"
         return b.predict(X[:3000], raw_score=True)
 
-    ref = train({"partition": "xla", "search": "xla", "mega": "off"},
+    ref = train({"partition": "xla", "hist": "xla", "search": "xla",
+                 "mega": "off"},
                 tpu_partition_kernel="xla", tpu_megakernel="off")
-    pallas = {"partition": "pallas", "search": "pallas", "mega": "off"}
+    pallas = {"partition": "pallas", "hist": "pallas", "search": "pallas",
+              "mega": "off"}
     flat = train({**pallas, "hist_state": "flat"}, tpu_megakernel="off")
     xstate = train({**pallas, "hist_state": "xla"}, tpu_megakernel="off",
                    tpu_hist_state="xla")
@@ -238,7 +290,7 @@ def check_e2e(rng):
 
 
 STEPS = (check_partition, check_search, check_rowid, check_hist_rmw,
-         check_megakernel, check_e2e)
+         check_megakernel, check_histogram, check_e2e)
 
 
 def main() -> int:
