@@ -343,14 +343,14 @@ def parallel_phase(lgb, X, y, Xh, size, modes):
         if mode != "serial":
             sb = g.sharded_builder
             assert sb is not None
-            rows = [s.data.shape[0]
+            rows = [s.data.shape[1]
                     for s in sb.binned_sharded.addressable_shards]
-            # data/voting shard the rows (+1 sentinel row per device);
-            # feature-parallel replicates them by design
+            # data/voting shard the rows, each device's block padded as
+            # the learner reads it; feature-parallel replicates them
             want = n if mode == "feature" else -(-n // ndev)
             say(f"{mode}: binned shard rows per device={rows} of {n}")
-            assert len(rows) == ndev and all(r == want + 1 for r in rows), \
-                (rows, want)
+            assert sb.local_n == want and len(rows) == ndev \
+                and all(r == sb.learner.N_pad for r in rows), (rows, want)
         say(f"{mode}: peak_bytes_in_use per device={peak_bytes()}")
         g._flush_pending()
         models[mode] = (g.models[0], bst.predict(Xh, raw_score=True))

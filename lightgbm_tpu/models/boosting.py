@@ -268,6 +268,19 @@ def _phys_leaf_delta(rec, Npad: int):
     return (within + carry[:, None]).reshape(Npad)
 
 
+def _rows_to_host(holder, num_data: int) -> None:
+    """Hand every per-row device array of an objective or a metric back
+    to the host.  The fused step over a device mesh carries the payload
+    rows sharded with the bins, so the copies their ``init`` made (label,
+    weights, their signed product: each sized by every shard's rows, all
+    on the first device) serve no step; an eager gradient pass or a
+    metric's evaluation still reads them, as host arrays."""
+    for name, value in list(vars(holder).items()):
+        if (isinstance(value, jax.Array) and value.ndim >= 1
+                and value.shape[0] == num_data):
+            setattr(holder, name, np.asarray(value))
+
+
 def _learner_memory_arrays(lr):
     """Telemetry memory provider: the learner's resident device
     buffers (master binned partition buffer + helper tables)."""
@@ -281,7 +294,7 @@ def _gbdt_memory_arrays(g):
     visible here: the live ``_phys`` carrier or the retired
     ``_phys_carrier`` (bins + rowid row) IS the training copy of the
     binned matrix once the fused path adopts the master buffer."""
-    out = [g._scores_arr]
+    out = [] if g._scores_arr is None else [g._scores_arr]
     phys = getattr(g, "_phys", None)
     if phys is not None:
         out.extend(phys)
@@ -362,6 +375,7 @@ class GBDT:
         self._init_phys_adopt = None
         self._init_phys_perm = None
         self._scores_arr = None
+        self._scores_read_sharded = None
         # programs whose executable obs/scopes.py already holds for this
         # booster (each is registered at its first call)
         self._scope_registered = set()
@@ -403,6 +417,12 @@ class GBDT:
                     # folded on the host: no program to register
                     self._scores_arr = _scores_from_phys_multiproc(
                         ghi, self.num_data, sb)
+                elif self._scores_read_sharded is not None:
+                    # each shard folds its own rows: the result stays
+                    # cut over the mesh
+                    self._scores_arr = self._scores_read_sharded(ghi)
+                    self._register_once("train.scores_read",
+                                        self._scores_read_sharded, ghi)
                 else:
                     self._scores_arr = lgbm_scores_read(ghi, self.num_data)
                     self._register_once("train.scores_read",
@@ -450,6 +470,8 @@ class GBDT:
         p0 = getattr(self.learner, "_part0", None)
         if p0 is not None and not p0.is_deleted():
             return self._traverse_part0_fn(nodes, p0)
+        if sb is not None and sb.nproc == 1:
+            return self._traverse_sharded_fn(nodes, sb.binned_sharded)
         binned = self.train_data.binned
         if binned is None:
             binned = self.train_data.host_binned()
@@ -500,6 +522,8 @@ class GBDT:
         lr = self.learner
         if getattr(lr, "_part0", None) is not None:
             return
+        if self.sharded_builder is not None:
+            return               # its eager build reads the mesh's own bins
         if self._phys is None and self._phys_carrier is None:
             return
         _ = self.scores          # materialize pending fused scores first
@@ -517,7 +541,6 @@ class GBDT:
     # ------------------------------------------------------------------
     def _setup_training(self, train_data: BinnedDataset) -> None:
         cfg = self.config
-        self.learner = SerialTreeLearner(train_data, cfg)
         self.sharded_builder = None
         if cfg.tree_learner != "serial":
             import jax as _jax
@@ -535,6 +558,12 @@ class GBDT:
             else:
                 log.warning("tree_learner=%s requested but only one device is "
                             "visible; training serially", cfg.tree_learner)
+        # the sharded builder's learner is THE learner: a second, serial
+        # one would put a master buffer sized by every shard's rows on
+        # the first device
+        self.learner = (self.sharded_builder.learner
+                        if self.sharded_builder is not None
+                        else SerialTreeLearner(train_data, cfg))
         self.num_data = train_data.num_data
         self.max_feature_idx = train_data.num_total_features - 1
         self.feature_names = list(train_data.feature_names)
@@ -650,6 +679,20 @@ class GBDT:
         self._unpermute_fn = jax.jit(scopes.phase("scores_read")(
             functools.partial(_unpermute_bins, N=_N, C=_C,
                               Npad=self.learner.N_pad)))
+        sb = self.sharded_builder
+        if sb is not None:
+            _Np = self.learner.N_pad
+
+            @scopes.phase("scores_read")
+            def _tr_sharded(nodes, pb):
+                # the mesh's pristine blocks, each [C pad][rows][pad]
+                leaf = predict_leaf_binned_t(pb[:_G], nodes)
+                if sb.mode == "feature":
+                    return leaf[_C:_C + _N]
+                return leaf.reshape(sb.ndev, _Np)[
+                    :, _C:_C + sb.local_n].reshape(-1)[:_N]
+
+            self._traverse_sharded_fn = jax.jit(_tr_sharded)
 
         # ---- fused training step ----
         # One jitted program per boosting iteration: gradients -> tree build
@@ -725,19 +768,15 @@ class GBDT:
                      "objective lacks gradients_from_payload")
         log.info("kernel plan: %s", " ".join(
             f"{k}={v}" for k, v in self.kernel_plan().items()))
-        for k, v in self._tree_learner().plan.why.items():
+        for k, v in self.learner.plan.why.items():
             log.info("  %s: %s", k, v)
-
-    def _tree_learner(self) -> SerialTreeLearner:
-        sb = self.sharded_builder
-        return sb.learner if sb is not None else self.learner
 
     def kernel_plan(self) -> Dict[str, Any]:
         """What actually builds the trees: the tree-building learner's
         resolved kernels (learner.kernel_plan) plus whether the whole
         iteration runs as one fused program."""
         sb = self.sharded_builder
-        plan = self._tree_learner().kernel_plan()
+        plan = self.learner.kernel_plan()
         plan["fused"] = "on" if self._fused is not None else "off"
         plan["tree_learner"] = sb.mode if sb is not None else "serial"
         return plan
@@ -1276,11 +1315,10 @@ class GBDT:
 
         @scopes.phase("layout_init")
         def init_shard(binned, scores, counts, *payloads):
-            # binned (rows+1, G); scores/payloads (rows,); counts (1,)
-            pb = jnp.pad(
-                binned.T,
-                ((0, lr_._pb_rows - binned.shape[1]),
-                 (C, Npad - C - binned.shape[0])))
+            # binned: this shard's pristine (pb_rows, N_pad) block, in the
+            # layout the step reads (the step donates its carrier, so the
+            # carrier is a copy of it); scores/payloads (rows,); counts (1,)
+            pb = binned
             iota = jax.lax.iota(jnp.int32, Npad)
             li = iota - C
             valid = (li >= 0) & (li < counts[0])
@@ -1306,11 +1344,13 @@ class GBDT:
         # decisions are synced by the build's all-gather), but the vma
         # checker can't see through the varying intermediates — disable
         # the static check for the replicated layout only
-        smap = functools.partial(jax.shard_map, mesh=mesh,
-                                 check_vma=not repl_rows)
+        # ... and for interpreted Pallas kernels (see the builder)
+        smap = functools.partial(
+            jax.shard_map, mesh=mesh,
+            check_vma=not (repl_rows or sb.interpreted_kernels))
         init_sharded = jax.jit(smap(
             init_shard,
-            in_specs=(row_spec, row_spec, cnt_spec) + (row_spec,) * n_pay,
+            in_specs=(state_spec, row_spec, cnt_spec) + (row_spec,) * n_pay,
             out_specs=(state_spec, state_spec)))
 
         def init_fn():
@@ -1319,10 +1359,33 @@ class GBDT:
             counts = (sb._put(np.asarray([N], np.int32),
                               NamedSharding(mesh, P()))
                       if repl_rows else sb.local_counts)
-            return init_sharded(sb.binned_sharded, scores_sh,
+            phys = init_sharded(sb.binned_sharded, scores_sh,
                                 counts, *pays)
+            # the carrier's score row is the state from here on: a copy in
+            # original order, sized by every shard's rows, stays on no
+            # device (a read of `.scores` makes one anew)
+            self._scores_arr = None
+            return phys
 
         self._init_phys_fn = init_fn
+
+        if not repl_rows and ndev * local_n == N:
+            # rows cut evenly: shard d's scores are rows [d*local_n,
+            # (d+1)*local_n) of the original order, so each shard scatters
+            # its own score row by rowid and the pieces ARE the scores,
+            # cut over the mesh: no device sorts or holds every shard's
+            # rows.  (An uneven cut, and replicated rows, take the one
+            # scatter of lgbm_scores_read.)
+            @scopes.phase("scores_read")
+            def lgbm_scores_read_shard(ghi):
+                rowid = jax.lax.bitcast_convert_type(ghi[2], jnp.int32)
+                li = rowid - jax.lax.axis_index(AXIS) * local_n
+                return jnp.zeros((local_n,), jnp.float32).at[li].set(
+                    ghi[3], mode="drop")
+
+            self._scores_read_sharded = jax.jit(smap(
+                lgbm_scores_read_shard, in_specs=(state_spec,),
+                out_specs=P(AXIS)))
 
         use_bag = self.need_bagging and not self.balanced_bagging
         bag_key = jax.random.PRNGKey(cfg.bagging_seed)
@@ -1354,7 +1417,8 @@ class GBDT:
                     h = h * sf
                     bag_cnt = jnp.sum(sel.astype(jnp.int32))
             else:
-                bag_cnt = jnp.sum(vf).astype(jnp.int32)
+                with scopes.scope("gradients"):
+                    bag_cnt = jnp.sum(vf).astype(jnp.int32)
             if mode == "feature":
                 d = jax.lax.axis_index(AXIS)
                 per = (F + ndev - 1) // ndev
@@ -1380,7 +1444,8 @@ class GBDT:
                                         AXIS).astype(jnp.bool_)
                 return jax.lax.pmax(x, AXIS)
 
-            small = jax.tree.map(replicate, small)
+            with scopes.scope("hist_sync"):
+                small = jax.tree.map(replicate, small)
             return rec["part_bins"], ghi_out, small
 
         self._fused_phys = jax.jit(smap(
@@ -1389,6 +1454,8 @@ class GBDT:
             out_specs=(state_spec, state_spec, P())),
             donate_argnums=(0, 1))
         self._fused = self._fused_phys
+        for holder in (obj, *self.train_metrics):
+            _rows_to_host(holder, self.num_data)
         log.info("fused sharded iteration ENABLED (%s-parallel over %d "
                  "devices)", mode, ndev)
 
@@ -1532,6 +1599,7 @@ class GBDT:
         self._health_record_tree(host_record, num_nodes)
         self._telemetry_chunk_waste(host_record, num_nodes)
         self._telemetry_pruned_splits(host_record, num_nodes)
+        self._telemetry_hist_sync(num_nodes)
         self.models.append(tree)
         self.device_trees.append({
             "nodes": nodes, "leaf_value": delta_leaf,
@@ -1599,6 +1667,21 @@ class GBDT:
         if pruned > 0:
             obs.counter("train.frontier.pruned_splits", pruned)
             obs.counter("train.frontier.undo_trees")
+
+    # -- data-parallel histogram sync (obs/telemetry.py) ----------------
+    def _telemetry_hist_sync(self, num_nodes: int) -> None:
+        """``train.parallel.hist_sync_bytes``: the bytes one shard hands
+        to the tree's histogram sums (the root's and each split's smaller
+        child's ``(G, B, 2)`` f32 histogram), from shapes alone;
+        ``train.parallel.shards``: the mesh's size.  tree_learner=data
+        only (voting sums elected features, feature sums nothing)."""
+        sb = self.sharded_builder
+        if obs.get().mode == "off" or sb is None or sb.mode != "data":
+            return
+        lr = self.learner
+        obs.counter("train.parallel.hist_sync_bytes",
+                    (num_nodes + 1) * lr.G * lr.B * 2 * 4)
+        obs.gauge("train.parallel.shards", sb.ndev)
 
     # -- chunk-policy padding-waste gauges (obs/telemetry.py) -----------
     def _telemetry_chunk_waste(self, host_record, num_nodes: int) -> None:
@@ -2190,6 +2273,7 @@ class GBDT:
             self._health_record_tree(host_record, num_nodes)
             self._telemetry_chunk_waste(host_record, num_nodes)
             self._telemetry_pruned_splits(record, num_nodes)
+            self._telemetry_hist_sync(num_nodes)
             self.models.append(tree)
             self.device_trees.append({
                 "nodes": nodes, "leaf_value": delta_leaf,

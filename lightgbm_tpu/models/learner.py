@@ -210,7 +210,8 @@ class SerialTreeLearner:
                  axis_name: Optional[str] = None,
                  parallel_mode: str = "serial",
                  num_shards: int = 1,
-                 local_num_data: Optional[int] = None):
+                 local_num_data: Optional[int] = None,
+                 global_num_data: Optional[int] = None):
         self.ds = dataset
         self.cfg = config
         self.axis_name = axis_name
@@ -410,7 +411,8 @@ class SerialTreeLearner:
         # ---- which split-step program: decided in models/plan.py ----
         self.plan = plan_mod.resolve(plan_mod.PlanFacts(
             backend=jax.default_backend(), interpret=self._interp,
-            rows=self.N, F=self.F, G=self.G, B=self.B,
+            rows=self.N, global_rows=global_num_data,
+            F=self.F, G=self.G, B=self.B,
             num_leaves=self.L, host_bin_dtype=str(host_bin_dtype),
             has_bins=(dataset.binned is not None
                       or self._ingest is not None),
@@ -1141,7 +1143,8 @@ class SerialTreeLearner:
         # the replicated tree state silently diverges
         if self.axis_name is not None and self.parallel_mode in ("data",
                                                                  "voting"):
-            counts = jax.lax.psum(counts, self.axis_name)
+            with scopes.scope("hist_sync"):
+                counts = jax.lax.psum(counts, self.axis_name)
         return counts
 
     def _lazy_mark(self, part_aux, start, cnt, f_enum):
@@ -1351,8 +1354,9 @@ class SerialTreeLearner:
         # refresh (no _sync_best needed for a replicated computation)
         mask0 = feature_mask
         if self.axis_name is not None and self.parallel_mode == "feature":
-            mask0 = jax.lax.pmax(
-                feature_mask.astype(jnp.int32), self.axis_name) > 0
+            with scopes.scope("hist_sync"):
+                mask0 = jax.lax.pmax(
+                    feature_mask.astype(jnp.int32), self.axis_name) > 0
         masks = jnp.broadcast_to(mask0, (L, self.F))
         if "leaf_fmask" in st:
             masks = masks & st["leaf_fmask"][:L]
@@ -1580,7 +1584,8 @@ class SerialTreeLearner:
         topv, topi = jax.lax.top_k(gains_loc, k)
         votes = jnp.zeros((self.F,), jnp.int32).at[topi].add(
             jnp.isfinite(topv).astype(jnp.int32))
-        votes_g = jax.lax.psum(votes, ax)
+        with scopes.scope("hist_sync"):
+            votes_g = jax.lax.psum(votes, ax)
         # elect 2k features by vote count; smaller feature index breaks ties
         ek = min(2 * self.top_k, self.F)
         fiota = jnp.arange(self.F, dtype=jnp.int32)
@@ -1590,7 +1595,8 @@ class SerialTreeLearner:
         # sync ONLY the elected features' groups: ek is static, so the
         # collective payload is (ek, B, 2) regardless of G
         eg = self.f_group[elected]                      # (ek,) group ids
-        sub_glob = jax.lax.psum(jnp.take(hist_local, eg, axis=0), ax)
+        with scopes.scope("hist_sync"):
+            sub_glob = jax.lax.psum(jnp.take(hist_local, eg, axis=0), ax)
         hist_glob = jnp.zeros_like(hist_local).at[eg].set(sub_glob)
         feat_hist = self._feat_view(hist_glob, sum_g, sum_h)
         best = self._find_best(feat_hist, sum_g, sum_h, cnt, depth,
@@ -1613,6 +1619,7 @@ class SerialTreeLearner:
 
         return jax.tree.map(mark, x)
 
+    @scopes.phase("hist_sync")
     def _psum(self, x):
         """Histogram sync: global sums only in data-parallel mode (voting
         keeps leaf histograms LOCAL and syncs only elected features at
@@ -1641,6 +1648,7 @@ class SerialTreeLearner:
             return jax.lax.psum(x, self.axis_name)
         return x
 
+    @scopes.phase("hist_sync")
     def _psum_scalar(self, x):
         """Row-statistic sync (counts, grad/hess totals): rows are sharded
         in both data- and voting-parallel modes."""
@@ -1649,6 +1657,7 @@ class SerialTreeLearner:
             return jax.lax.psum(x, self.axis_name)
         return x
 
+    @scopes.phase("hist_sync")
     def _sync_best(self, best):
         """Agree on the global best split across feature-sharded devices
         (reference: SyncUpGlobalBestSplit, parallel_tree_learner.h:209-232).

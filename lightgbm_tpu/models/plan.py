@@ -39,6 +39,9 @@ class PlanFacts:
     backend: str                  # jax.default_backend(), passed in
     interpret: bool = False       # tpu_kernel_interpret
     rows: int                     # rows this learner holds (local shard)
+    # rows of every shard together, where SUMMED counts must stay exact
+    # integers in f32 (None: one shard, the same as ``rows``)
+    global_rows: int | None = None
     F: int                        # used features
     G: int                        # feature groups
     B: int                        # bins of the widest group
@@ -124,6 +127,7 @@ def resolve(f: PlanFacts) -> SplitPlan:
     serial = f.parallel_mode == "serial"
     parallel = _given(
         (not serial or f.axis_name, f"tree_learner={f.parallel_mode}"))
+    global_rows = f.rows if f.global_rows is None else f.global_rows
     no_features = _given((f.F == 0, "no usable features"))
     u8_bins = _given(
         (not f.has_bins, "no binned matrix or device ingest"),
@@ -140,7 +144,12 @@ def resolve(f: PlanFacts) -> SplitPlan:
          "categorical features: the kernel decides numerical splits only"),
         (f.cegb_lazy, "cegb_penalty_feature_lazy: the kernel does not "
                       "carry its per-row bitset"),
-        (not serial, f"tree_learner={f.parallel_mode}"),
+        (f.parallel_mode in ("feature", "voting"),
+         f"tree_learner={f.parallel_mode}: "
+         + ("every chip holds every row, the search is what is sharded"
+            if f.parallel_mode == "feature" else
+            "leaf histograms stay device-local for the vote")
+         + "; the kernels run per shard under tree_learner=data only"),
     ) + no_features + u8_bins
     pallas_part = not block
     if block:
@@ -165,9 +174,9 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (f.use_mc, "monotone constraints"),
         (f.has_cegb, "CEGB penalties"),
         (f.path_smooth > 0.0, "path_smooth > 0"),
-        (f.rows >= FAST_SEARCH_MAX_ROWS,
-         f"rows {f.rows:,} >= 2^24: the f32 count cumsum of the fast "
-         "search is exact only below it"))
+        (global_rows >= FAST_SEARCH_MAX_ROWS,
+         f"rows {global_rows:,} >= 2^24: the f32 count cumsum of the "
+         "fast search is exact only below it"))
     fast = not not_fast
     if not_fast:
         why["fast_search"] = _said("off", not_fast)
@@ -206,7 +215,8 @@ def resolve(f: PlanFacts) -> SplitPlan:
              f"{f.F} features < {f.num_shards} shards"))
         scatter = not block
         if block:
-            why["scatter_groups"] = _said("off", block)
+            why["scatter_groups"] = _said(
+                "off", block + ["the histogram sync is the plain psum"])
 
     # ---- what the pair-search kernel and the mega-kernel share: the
     # plain all-numerical fast path, whose 13-scalar split tile carries
@@ -219,8 +229,12 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (f.extra_trees, "extra_trees"),
         (f.feature_contri, "feature_contri"))
 
-    # ---- Pallas pair search (ops/split_pallas.py) ----
-    block = partition_xla + not_plain
+    # ---- Pallas pair search (ops/split_pallas.py): the flat state it
+    # feeds on is updated in place from the LOCAL smaller child, so the
+    # shards' histograms would never be summed ----
+    block = partition_xla + not_plain + _given(
+        (bool(parallel), "parallel tree learners: the histogram sync "
+                         "lives on the XLA search's path"))
     search = "xla" if block else "pallas"
     if block:
         why["search"] = _said("xla", block)

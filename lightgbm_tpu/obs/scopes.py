@@ -38,10 +38,18 @@ PHASES = (
     "gradients", "sampling", "quantize",          # row passes before the tree
     "histogram", "hist_state", "search",          # per split
     "partition", "split_mega", "bookkeeping",
+    "hist_sync",                                  # collectives of the tree
     "leaf_renew", "score_update",                 # row passes after the tree
     "layout_init", "scores_read",                 # outside the step
 )
 PROGRAMS = ("train.fused_step", "train.scores_read", "train.layout_init")
+# the compiler combines a program's collectives into new instructions
+# that carry no op_name; such a one takes its program's phase for them:
+# the step's only collectives are the tree's syncs, the other two
+# programs are one phase each
+_COLLECTIVE_PHASE = {"train.fused_step": "hist_sync",
+                     "train.scores_read": "scores_read",
+                     "train.layout_init": "layout_init"}
 
 _PREFIX = "lgbm."
 _SCOPED = re.compile(r"(?:^|/)lgbm\.([A-Za-z0-9_]+)(?=/|$)")
@@ -51,6 +59,9 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%([^\s,}]+)")
 _TO_APPLY = re.compile(r"\bto_apply=%([^\s,}]+)")
 _MODULE = re.compile(r"HloModule ([^\s,]+)")
+_COLLECTIVE = re.compile(
+    r"[\])}] (?:all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start|-done)?\(")
 
 
 def _scope_name(name: str) -> str:
@@ -82,7 +93,8 @@ def phase(name: str) -> Callable:
     return wrap
 
 
-def phase_of(hlo_text: str) -> Dict[str, Optional[str]]:
+def phase_of(hlo_text: str,
+             collectives: Optional[str] = None) -> Dict[str, Optional[str]]:
     """``{instruction name: phase}`` of one compiled module's text
     (``Compiled.as_text()``): the innermost ``lgbm.*`` component of each
     instruction's op_name, ``None`` where it has none.  A fusion that the
@@ -90,7 +102,9 @@ def phase_of(hlo_text: str) -> Dict[str, Optional[str]]:
     of its body carry (parameters and constants aside: constants are shared
     across scopes), if they carry exactly one.  Instructions inside fusion
     bodies and scalar ``to_apply`` regions are left out themselves: they
-    never run as operations of their own, so a trace never names them."""
+    never run as operations of their own, so a trace never names them.
+    A collective that carries no scope (the compiler's combined
+    all-reduce) takes the phase ``collectives``, where one is given."""
     lines = hlo_text.splitlines()
     inner = set()
     for line in lines:
@@ -118,6 +132,8 @@ def phase_of(hlo_text: str) -> Dict[str, Optional[str]]:
                     and " parameter(" not in line:
                 body_phases.setdefault(computation, set()).add(phase)
             continue
+        if phase is None and collectives and _COLLECTIVE.search(line):
+            phase = collectives
         out[m.group(1)] = phase
         if phase is None and " fusion(" in line:
             unscoped_fusions.append((m.group(1), _CALLS.findall(line)))
@@ -166,7 +182,8 @@ def _parsed() -> Dict[str, Any]:
                 text = "\n".join(m.to_string() for m in exe.hlo_modules())
                 module = _MODULE.match(text)
                 _tables[program] = (module.group(1) if module else "",
-                                    phase_of(text))
+                                    phase_of(
+                                        text, _COLLECTIVE_PHASE[program]))
         return dict(_tables)
 
 

@@ -11,3 +11,13 @@ import jax
 # sums within f32 reassociation of a serial scan.  (bf16 operands — the
 # quantized integer carriers — are exact at any setting.)
 F32_DOT_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def varying_like(shape, dtype, *operands):
+    """``ShapeDtypeStruct`` of a ``pallas_call`` output that varies over
+    the mesh axes any of ``operands`` varies over: inside ``shard_map``
+    (tree_learner=data runs the kernels on each shard's own rows and its
+    own start/count) the output's type has to say so; outside, the set is
+    empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

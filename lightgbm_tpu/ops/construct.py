@@ -101,7 +101,10 @@ def resolve_mode(config, is_reference: bool, is_distributed: bool
       device buffer on demand).
     Validation datasets (``is_reference``) and multi-process
     construction never device-ingest: their consumers want row-major
-    host bins / rank-local shards.
+    host bins / rank-local shards.  Under ``auto`` a parallel
+    ``tree_learner`` does not either: the mesh trainer cuts each device's
+    block from the host matrix (parallel/trainer.py), and the ingest's
+    master buffer would put every shard's rows on the first device.
     """
     mode = str(getattr(config, "construct_device", "auto") or "auto").lower()
     if mode not in ("auto", "on", "off"):
@@ -117,7 +120,8 @@ def resolve_mode(config, is_reference: bool, is_distributed: bool
                     else "multi-process")
     if mode == "on" and ingest_ok:
         return True, True, False
-    return True, ingest_ok, True
+    parallel = str(getattr(config, "tree_learner", "serial")) != "serial"
+    return True, ingest_ok and not parallel, True
 
 
 # ---------------------------------------------------------------------------

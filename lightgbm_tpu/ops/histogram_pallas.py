@@ -50,7 +50,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import F32_DOT_PRECISION
+from . import F32_DOT_PRECISION, varying_like
 from .partition_pallas import _cdiv
 
 # lanes built and contracted per inner step: the accumulator is read and
@@ -221,7 +221,8 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
                          jnp.asarray(cnt, jnp.int32)])
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((NG, P * R, 128), jnp.float32),
+        out_shape=varying_like((NG, P * R, 128), jnp.float32,
+                               scalars, part_bins, part_ghi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),

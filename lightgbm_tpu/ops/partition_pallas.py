@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import varying_like
+
 
 # scalar-operand layout (prefetched i32 vector)
 S_A0B = 0       # start >> 7  (128-block index of the aligned cover base)
@@ -564,19 +566,18 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             pltpu.SemaphoreType.DMA((2, 4)),
         ],
     )
+    operands = (scalars, part_bins, part_ghi, sc_packed)
     out = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct(part_bins.shape, part_bins.dtype),
-            jax.ShapeDtypeStruct(part_ghi.shape, part_ghi.dtype),
-            jax.ShapeDtypeStruct(sc_packed.shape, sc_packed.dtype),
-            jax.ShapeDtypeStruct((8, 128), jnp.int32),
-        ],
+            varying_like(a.shape, a.dtype, *operands)
+            for a in (part_bins, part_ghi, sc_packed)
+        ] + [varying_like((8, 128), jnp.int32, *operands)],
         grid_spec=grid_spec,
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
         name="lgbm_partition",
-    )(scalars, part_bins, part_ghi, sc_packed)
+    )(*operands)
     return out
 
 

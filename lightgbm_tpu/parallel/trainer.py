@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import Config
 from ..dataset import BinnedDataset
 from ..models.learner import SerialTreeLearner
+from ..obs import scopes
 from ..utils import log
 
 AXIS = "data"
@@ -42,8 +43,8 @@ AXIS = "data"
 class ShardedTreeBuilder:
     """Builds trees SPMD over an N-device mesh.
 
-    Rows are padded to a multiple of the mesh size; each device holds a
-    ``(local_rows + 1, G)`` block whose last row is its sentinel.
+    Rows are padded to a multiple of the mesh size; each device holds its
+    rows as the ``(pb_rows, N_pad_local)`` block the tree learner reads.
     """
 
     def __init__(self, dataset: BinnedDataset, config: Config,
@@ -76,22 +77,24 @@ class ShardedTreeBuilder:
             return jax.device_put(arr, sharding)
         self._put = _put
 
-        # The builder needs its own mesh-sharded layout, not the serial
-        # learner's (G, N_pad) pad.  With a live/recoverable device
-        # ingest the relayout runs ON DEVICE: one jitted
-        # slice-transpose-reshape from the (G, N_pad) master buffer to
-        # the per-device (local_n+1, G) blocks, placed by out_shardings
-        # — startup never round-trips the matrix through the host.
-        # Without one (host-resident dataset; or multi-process, where
-        # each rank's dataset holds only ITS row shard and
-        # make_array_from_process_local_data wants host blocks), the
-        # host path packs rank-local blocks from host_binned(), which
-        # now streams in bounded row blocks.
+        # The mesh-resident bins are ``(pb_rows, ndev * N_pad_local)``
+        # sharded ``P(None, AXIS)``: each device holds its rows as the
+        # ``(pb_rows, N_pad_local)`` block the tree learner reads (rows on
+        # the lane axis, front and tail pad in place, bins sublane-padded
+        # where the partition kernel runs), so neither the build nor a
+        # layout init pads or transposes anything.  Where the host holds
+        # the binned matrix, each block is cut from it and put on its own
+        # device: nothing sized by the global row count visits a device.
+        # A dataset that lives only in its device ingest (construct_device
+        # =on, streamed sketch construction) is relaid on the device from
+        # the ``(G, N_pad)`` master buffer instead.  Multi-process: each
+        # rank's dataset holds only ITS rows and places its own devices'
+        # blocks of the global array.
         di = getattr(dataset, "device_ingest", None)
-        self._used_device_reshard = di is not None and self.nproc == 1
+        self._used_device_reshard = (di is not None and self.nproc == 1
+                                     and dataset.binned is None)
         if self._used_device_reshard:
             N, G = di.N, di.G           # geometry without materializing
-            bin_dtype = np.dtype(di.dtype)
             binned = None
         else:
             binned = dataset.host_binned()
@@ -99,8 +102,6 @@ class ShardedTreeBuilder:
                 raise ValueError(
                     "dataset has no binned data (construct it first)")
             N, G = binned.shape         # local rows when multi-process
-            bin_dtype = binned.dtype
-        sent = np.zeros((1, G), dtype=bin_dtype)
         sharding = NamedSharding(self.mesh, P(AXIS))
         if self.nproc > 1:
             from . import network
@@ -121,56 +122,36 @@ class ShardedTreeBuilder:
                 float(-(-N // self.local_ndev))))
         else:
             self.N = N
-            self.local_n = ((N + self.ndev - 1) // self.ndev
-                            if self.mode != "feature" else N)
+            self.local_n = (N + self.ndev - 1) // self.ndev
         if self.mode == "feature":
             self.local_n = self.N
-            if self._used_device_reshard:
-                self.binned_sharded = self._device_reshard(
-                    di, N, G, feature=True)
-            else:
-                host_binned = np.concatenate([binned, sent])
-                self.binned_sharded = _put(host_binned,
-                                           NamedSharding(self.mesh, P()))
+        self.learner = SerialTreeLearner(
+            dataset, config, axis_name=AXIS, parallel_mode=mode,
+            num_shards=self.ndev, local_num_data=self.local_n,
+            global_num_data=self.N)
+        if self.mode == "feature":
             counts = [self.N] * self.local_ndev
-        elif self._used_device_reshard:
-            self.binned_sharded = self._device_reshard(
-                di, N, G, feature=False)
+        else:
             counts = [min(self.local_n, max(0, N - d * self.local_n))
                       for d in range(self.local_ndev)]
+        if self._used_device_reshard:
+            self.binned_sharded = self._device_reshard(di, N, G)
         else:
-            # blocked binned: (local_ndev * (local_n + 1), G) per process;
-            # per-device sentinel row
-            blocks = []
-            counts = []
-            for d in range(self.local_ndev):
-                blk = binned[d * self.local_n:(d + 1) * self.local_n]
-                counts.append(len(blk))
-                if len(blk) < self.local_n:
-                    blk = np.concatenate(
-                        [blk,
-                         np.zeros((self.local_n - len(blk), G), binned.dtype)])
-                blocks.append(np.concatenate([blk, sent]))
-            host_binned = np.concatenate(blocks, axis=0)
-            self.binned_sharded = _put(host_binned, sharding)
+            self.binned_sharded = self._host_blocks(binned, counts)
         self.local_counts = _put(np.asarray(counts, dtype=np.int32), sharding)
         from ..obs import memory as obs_memory
         obs_memory.register(
             "parallel.binned_sharded", self,
             lambda sb: [sb.binned_sharded, sb.local_counts])
-        self.learner = SerialTreeLearner(
-            dataset, config, axis_name=AXIS, parallel_mode=mode,
-            num_shards=self.ndev, local_num_data=self.local_n)
 
         lr = self.learner
 
         def build_shard(binned, grad, hess, bag_cnt, feature_mask, seed,
                         feat_used, lazy_aux):
-            # binned: (local_n+1, G); grad/hess: (local_n,); bag_cnt: (1,)
-            # local in-bag rows (== local valid rows without sampling)
-            C = lr.row0
-            part_bins = jnp.pad(
-                binned.T, ((0, 0), (C, lr.N_pad - C - binned.shape[0])))
+            # binned: this shard's (pb_rows, N_pad) block; grad/hess:
+            # (local_n,); bag_cnt: (1,) local in-bag rows (== local valid
+            # rows without sampling)
+            part_bins = binned
             grad_l = grad[: lr.N]
             hess_l = hess[: lr.N]
             if self.mode == "feature":
@@ -187,10 +168,11 @@ class ShardedTreeBuilder:
                                   aux0)
 
         row_spec = P() if self.mode == "feature" else P(AXIS)
+        bins_spec = P() if self.mode == "feature" else P(None, AXIS)
         has_lazy = lr.cegb_lazy is not None
         aux_spec = (P(None, AXIS) if self.mode != "feature" else P()) \
             if has_lazy else None
-        in_specs = (row_spec, row_spec, row_spec, P(AXIS), P(), P(), P()) \
+        in_specs = (bins_spec, row_spec, row_spec, P(AXIS), P(), P(), P()) \
             + ((aux_spec,) if has_lazy else ())
         out_specs = (P(), aux_spec) if has_lazy else P()
 
@@ -226,7 +208,8 @@ class ShardedTreeBuilder:
                     return jax.lax.pmax(x.astype(jnp.int32), AXIS).astype(jnp.bool_)
                 return jax.lax.pmax(x, AXIS)
 
-            rec = jax.tree.map(replicate, rec)
+            with scopes.scope("hist_sync"):
+                rec = jax.tree.map(replicate, rec)
             if has_lazy:
                 if self.mode == "feature":
                     # rows replicated: the aux is identical on every device
@@ -234,43 +217,74 @@ class ShardedTreeBuilder:
                 return rec, aux_out
             return rec
 
+        # the interpreter runs a kernel's body as plain operations whose
+        # constants shard_map's varying-type check takes for unvarying:
+        # the check is off for interpreted kernels only (the compiled
+        # kernels declare their outputs varying, ops.varying_like)
         self._build_sharded = jax.jit(jax.shard_map(
             wrapper, mesh=self.mesh,
-            in_specs=in_specs, out_specs=out_specs))
+            in_specs=in_specs, out_specs=out_specs,
+            check_vma=not self.interpreted_kernels))
 
     # ------------------------------------------------------------------
-    def _device_reshard(self, di, N: int, G: int, feature: bool):
+    @property
+    def interpreted_kernels(self) -> bool:
+        lr = self.learner
+        return lr._interp and lr.plan.partition == "pallas"
+
+    def _bins_sharding(self) -> NamedSharding:
+        return NamedSharding(
+            self.mesh, P() if self.mode == "feature" else P(None, AXIS))
+
+    def _host_blocks(self, binned: np.ndarray, counts) -> jax.Array:
+        """One ``(pb_rows, N_pad_local)`` block per local device, cut from
+        the host-binned rows and put on that device, assembled into the
+        global mesh array: the bins reach a device only as its own
+        block."""
+        lr = self.learner
+        C, n_pad, G = lr.row0, lr.N_pad, binned.shape[1]
+        sharding = self._bins_sharding()
+        shape = (lr._pb_rows,
+                 n_pad if self.mode == "feature" else self.ndev * n_pad)
+        mine = [d for d in self.mesh.devices.flat
+                if d.process_index == jax.process_index()]
+        placed = []
+        for i, dev in enumerate(mine):
+            block = np.zeros((lr._pb_rows, n_pad), binned.dtype)
+            rows = (binned[:counts[i]] if self.mode == "feature" else
+                    binned[i * self.local_n:i * self.local_n + counts[i]])
+            block[:G, C:C + len(rows)] = rows.T
+            placed.append(jax.device_put(block, dev))
+            del block
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding, placed)
+
+    def _device_reshard(self, di, N: int, G: int) -> jax.Array:
         """On-device relayout of the ingest master buffer to the mesh
-        layout: ``(G, N_pad)`` column-major rows → per-device
-        ``(local_n+1, G)`` blocks (zero row pad + zero sentinel row),
-        bit-identical to the host blocked packing.  One jitted program;
+        layout: ``(G, N_pad)`` -> per-device ``(pb_rows, N_pad_local)``
+        blocks, bit-identical to ``_host_blocks``.  One jitted program;
         ``out_shardings`` places the blocks, so the matrix never visits
         the host and no (N, G) host copy materializes."""
-        C = di.row0
+        lr = self.learner
+        C0 = di.row0
+        C, n_pad, pb = lr.row0, lr.N_pad, lr._pb_rows
         buf = di.live_buffer()
         ndev, local_n = self.ndev, self.local_n
-        if feature:
-            spec = P()                    # rows replicated per device
-
+        if self.mode == "feature":
             def relay(b):
-                bt = b[:G, C:C + N].T
-                return jnp.concatenate(
-                    [bt, jnp.zeros((1, G), bt.dtype)], axis=0)
+                return jnp.pad(b[:G, C0:C0 + N],
+                               ((0, pb - G), (C, n_pad - C - N)))
         else:
-            spec = P(AXIS)
-            total = ndev * local_n
-
             def relay(b):
-                bt = b[:G, C:C + N].T                      # (N, G)
-                bt = jnp.pad(bt, ((0, total - N), (0, 0)))
-                bt = bt.reshape(ndev, local_n, G)
-                bt = jnp.concatenate(
-                    [bt, jnp.zeros((ndev, 1, G), bt.dtype)], axis=1)
-                return bt.reshape(ndev * (local_n + 1), G)
+                rows = jnp.pad(b[:G, C0:C0 + N],
+                               ((0, pb - G), (0, ndev * local_n - N)))
+                rows = jnp.pad(rows.reshape(pb, ndev, local_n),
+                               ((0, 0), (0, 0), (C, n_pad - C - local_n)))
+                return rows.reshape(pb, ndev * n_pad)
         # once-per-startup relayout: the trace is the product (shapes
         # differ per dataset, nothing to rebind)
         return jax.jit(relay,                    # jaxlint: ok=JL002
-                       out_shardings=NamedSharding(self.mesh, spec))(buf)
+                       out_shardings=self._bins_sharding())(buf)
 
     def pad_rows(self, arr: np.ndarray) -> jnp.ndarray:
         """Pad a per-row array (process-local rows when multi-process) to

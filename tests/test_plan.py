@@ -25,6 +25,7 @@ CELL_PLAN = dict(partition="pallas", hist="pallas", fast_search=False,
 SMOKE = dict(rows=10_500_000)          # chip_smoke.py's shape (PR 21)
 TOY_CPU = dict(backend="cpu", rows=2000, num_leaves=31)
 DATA4 = dict(TOY_CPU, parallel_mode="data", axis_name=True, num_shards=4)
+DP4_CHIP = dict(parallel_mode="data", axis_name=True, num_shards=4)
 
 
 def facts(**kw):
@@ -118,7 +119,45 @@ CASES = {
                     ("search", "path_smooth > 0"), None),
     "data_scatter": (DATA4, dict(partition="xla", scatter_groups=True,
                                  frontier_k=1, chunk_adaptive=False),
-                     ("partition", "tree_learner=data"), None),
+                     ("partition", "backend cpu"), None),
+    # tree_learner=data runs the kernels on each shard's own rows; the
+    # count-exactness gate sees the rows of all shards together
+    "dp4_10m5_a_shard": (dict(DP4_CHIP, rows=10_500_000,
+                              global_rows=42_000_000),
+                         dict(partition="pallas", hist="pallas",
+                              fast_search=False, search="xla", mega="off",
+                              frontier_k=1, scatter_groups=False),
+                         ("fast_search", "rows 42,000,000 >= 2^24"), None),
+    # benchmark/configs/higgs-l255-b255-dp4.json at rows84m: the cells'
+    # plan at K=1, the sync the plain psum
+    "dp4_cell": (dict(DP4_CHIP, rows=21_000_000, global_rows=84_000_000),
+                 dict(CELL_PLAN, frontier_k=1),
+                 ("scatter_groups", "the histogram sync is the plain psum"),
+                 None),
+    "dp4_cell_k1_why": (dict(DP4_CHIP, rows=21_000_000,
+                             global_rows=84_000_000),
+                        dict(mega="off", frontier_k=1),
+                        ("frontier_k", "parallel tree learners"), None),
+    "dp4_small": (dict(DP4_CHIP, rows=500_000, global_rows=2_000_000),
+                  dict(partition="pallas", hist="pallas", fast_search=True,
+                       search="xla", mega="off", frontier_k=1,
+                       hist_state="xla", scatter_groups=True),
+                  ("search", "parallel tree learners"), None),
+    "dp4_interpret": (dict(DATA4, interpret=True),
+                      dict(partition="pallas", hist="pallas", search="xla",
+                           mega="off", frontier_k=1, hist_state="xla"),
+                      ("mega", "tree_learner=data"), None),
+    "feature_keeps_xla": (dict(DP4_CHIP, parallel_mode="feature"),
+                          dict(partition="xla", hist="xla", search="xla",
+                               mega="off", frontier_k=1),
+                          ("partition", "tree_learner=feature: every chip "
+                                        "holds every row"), None),
+    "voting_keeps_xla": (dict(DP4_CHIP, parallel_mode="voting"),
+                         dict(partition="xla", hist="xla", search="xla",
+                              mega="off", frontier_k=1),
+                         ("partition", "tree_learner=voting: leaf "
+                                       "histograms stay device-local"),
+                         None),
     "data_few_features": (dict(DATA4, F=2, G=2),
                           dict(scatter_groups=False),
                           ("scatter_groups", "2 features < 4 shards"),
@@ -232,7 +271,7 @@ def test_kernel_plan_is_the_projection_of_resolve(name):
                     lgb.Dataset(X, label=y), num_boost_round=1)
     g = bst._gbdt
     expect = plan.resolve(plan.PlanFacts(**{**OPTIONS, **by_hand}))
-    assert g._tree_learner().plan == expect
+    assert g.learner.plan == expect
     assert g.kernel_plan() == {
         **expect.kernel_plan(), "fused": "on",
         "tree_learner": by_hand.get("parallel_mode", "serial")}

@@ -471,16 +471,20 @@ def test_dump_scope_table_names_the_modules(tmp_path):
 # the kernel's name on the chip's compiler (no chip: the described topology)
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @contextlib.contextmanager
@@ -611,3 +615,76 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
     table = scopes.phase_of(text)
     hist = [n for n in table if n.startswith("lgbm_histogram")]
     assert len(hist) >= 2 and {table[n] for n in hist} == {"histogram"}
+
+
+def test_sharded_step_compiles_for_v5e_2x2_with_both_kernels_in_place(
+        v5e_2x2, monkeypatch):
+    """tree_learner=data on the described four chips: the fused sharded
+    step (the four-chip cell's plan, reached at toy size through
+    path_smooth) compiles with shard_map's varying-type check on, runs
+    lgbm_partition and lgbm_histogram on each shard, holds its bins only
+    with the rows on the lane axis (no (rows, G) u8 buffer, which a TPU
+    pads to 128 lanes), copies neither the payload nor the bins beside the
+    partition's in-place write, and gives its collectives the phase
+    hist_sync."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    cpus = jax.devices()[:4]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: cpus)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X, y = _toy(n=1600)                     # 400 rows a chip: an even cut
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 15,
+                       "verbosity": -1, "tree_learner": "data",
+                       "path_smooth": 1.0}, lgb.Dataset(X, label=y))
+    g = bst._gbdt
+    sb, lr = g.sharded_builder, g.learner
+    kp = g.kernel_plan()
+    assert (kp["partition"], kp["hist"], kp["search"], kp["frontier_k"],
+            kp["tree_learner"]) == ("pallas", "pallas", "xla", 1, "data")
+    assert not sb.interpreted_kernels
+    # the same step over the described chips (nothing is placed on them)
+    sb.mesh = mesh = Mesh(np.asarray(v5e_2x2.devices), ("data",))
+    g._setup_fused_sharded()
+    Np = lr.N_pad
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    with _compile_cache_off():
+        text = g._fused_phys.lower(
+            sds((lr._pb_rows, 4 * Np), jnp.uint8, P(None, "data")),
+            sds((8, 4 * Np), jnp.float32, P(None, "data")),
+            sds((lr.F,), jnp.bool_, P()), 1,
+            sds((lr.F,), jnp.bool_, P())).compile().as_text()
+        # the read of an even cut: each chip folds its own rows
+        read = g._scores_read_sharded.lower(
+            sds((8, 4 * Np), jnp.float32, P(None, "data"))
+        ).compile().as_text()
+    assert "all-" not in read and f"f32[{sb.local_n}]" in read \
+        and f"[{4 * sb.local_n}]" not in read
+    table = scopes.phase_of(text, "hist_sync")
+    for kernel, phase in (("lgbm_partition", "partition"),
+                          ("lgbm_histogram", "histogram")):
+        found = [n for n in table if n.startswith(kernel)]
+        assert found and {table[n] for n in found} == {phase}, kernel
+    sync = [n for n in table if n.startswith("all-reduce")]
+    assert sync and {table[n] for n in sync} == {"hist_sync"}
+    for d0, d1 in re.findall(r"u8\[(\d+),(\d+)\]", text):
+        assert int(d0) * int(d1) < sb.local_n or int(d1) == Np, (d0, d1)
+    # the split loop: the one that launches the partition kernel, and
+    # whatever it calls (outside it the compiler may stage a toy buffer
+    # into faster memory once a step)
+    computations = _computations(text)
+    body = [c for c, lines in computations.items()
+            if any("lgbm_partition" in ln and "custom-call(" in ln
+                   for ln in lines)]
+    assert len(body) == 1
+    copies = 0
+    for name in _reachable(computations, body):
+        for line in computations[name]:
+            if re.search(r"[\])}] copy(-start)?\(", line):
+                copies += 1
+                assert not re.search(
+                    rf"(f32\[8|u8\[{lr._pb_rows}),{Np}\]",
+                    line.split(" = ")[1]), line[:200]
+    assert any("lgbm_histogram" in ln for ln in computations[body[0]])

@@ -22,7 +22,8 @@ differ (they usually don't: every rank reports its own os.getpid).
 ``device`` reads a ``jax.profiler`` trace (``*.xplane.pb``) and the scope
 table that ``lightgbm_tpu.obs.scopes.dump_scope_table(path)`` wrote in the
 traced process, and prints, per ``lgbm.*`` phase, the device seconds, the
-share of the window and the top operations as ``phase/instruction``; then
+share of the window and the top operations as ``phase/instruction`` (on a
+mesh the collectives of the tree are the phase ``hist_sync``); then
 every idle gap over 1 ms, put down to the innermost host span (``train.*``
 with ``LIGHTGBM_TPU_TELEMETRY=trace``) that covers it.
 
