@@ -455,7 +455,7 @@ _PARAMS: List[_Param] = [
     # "fixed" = the base grid everywhere; "adaptive" = force on
     _p("tpu_chunk_policy", "auto", str),
     # ride the rowid row inside the spare packed-bin bytes when G <= G32-4
-    # (one fewer payload sublane through the partition roll networks)
+    # (one fewer payload sublane through the partition's compaction)
     _p("tpu_pack_rowid", False, bool),
     # disable the fused single-program iteration (A/B + debugging; the
     # eager per-stage dispatch path is the fallback)

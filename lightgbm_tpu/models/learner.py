@@ -449,8 +449,8 @@ class SerialTreeLearner:
         self.N_pad = C + ((self.N + C - 1) // C + 2) * C
         # ---- Pallas partition kernel ----
         # The Pallas kernel (ops/partition_pallas.py) streams aligned
-        # window DMAs through an in-VMEM shift-network compaction
-        # (about 1 ms per 1M rows on the v5e, PERF.md section 6, PR 30;
+        # window DMAs through an in-VMEM compaction (about 0.6 ms per
+        # 1M rows on the v5e, PERF.md section 6, PR 34;
         # the XLA formulation has not been timed there at the cells'
         # size).  DMA tiling requires sublane-padded row buffers: bins
         # to a multiple of 32 (u8 tile), grad/hess/rowid to 8 f32 rows.

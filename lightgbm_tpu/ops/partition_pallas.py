@@ -4,15 +4,18 @@ TPU-native replacement for the reference DataPartition::Split
 (src/treelearner/data_partition.hpp:118-149) and the CUDA
 bitvector + AggregateBlockOffset + SplitInner pipeline
 (src/treelearner/cuda/cuda_data_partition.cu:288-907): aligned window
-DMAs and an in-VMEM roll-network compaction.  On the v5e the kernel is
-bound by the network's lane rotates, not by DMA: a 4096-row chunk of the
-benchmark's geometry took 6.2 us before PR 30 trimmed the network's
-step and takes 4.0 us since, against 0.2 us for its 36 B a row at the
-HBM peak (PERF.md sections 5 and 6).  The XLA formulation of the same
-partition (models/learner.py:_partition_leaf) is kept as the CPU /
-fallback path and as the correctness oracle — both produce bit-identical
-layouts (lefts forward-packed in original order, rights behind them in
-original order).
+DMAs and an in-VMEM compaction, one lane permutation per 128-lane block
+and a shift network over whole blocks.  Until PR 34 the compaction was a
+twelve-step roll network and the kernel was bound by its lane rotates
+(994 a 4096-row chunk, 4.0 us a chunk at the benchmark's geometry).
+Since, nothing in pass 1 rotates: a chunk takes 2.5 us, 2.0 of them
+without any compaction (staging, flushes, pass 2 and what the DMAs leave
+uncovered), against 0.2 us for its 36 B a row at the HBM peak (PERF.md
+sections 5 and 6; tools/kernel_ops.py --bundles shows which unit's issue
+slots a form fills).  The XLA formulation of the same partition
+(models/learner.py:_partition_leaf) is kept as the CPU / fallback path
+and as the correctness oracle — both produce bit-identical layouts (lefts
+forward-packed in original order, rights behind them in original order).
 
 Design notes (all constraints below were probed on the live toolchain):
   * Window DMAs compile only with provably 128-aligned dynamic lane
@@ -22,24 +25,31 @@ Design notes (all constraints below were probed on the live toolchain):
     rows before ``start`` ride as unconditional LEFTS, rows at/after
     ``start + cnt`` as unconditional RIGHTS.  Stable compaction then
     returns them to exactly their original positions.
-  * No sort / gather / cumsum lower inside Pallas TPU kernels.  Prefix
-    sums are computed with strictly-lower-triangular one-hot matmuls on
-    the MXU; the stable two-way compaction is a log2(C)-step binary shift
-    network built from ``pltpu.roll`` (bool rolls don't lower — all
-    masks stay i32).
+  * No sort / cumsum lowers inside Pallas TPU kernels, and a gather only
+    along the lanes of one vreg.  Prefix sums are strictly-lower-
+    triangular one-hot matmuls on the MXU; the stable two-way compaction
+    (``_compact``) is, per 128-lane block, one permutation of the block's
+    lanes (lefts to their final lanes, rights mirrored into the lanes
+    that are left), inverted by a one-hot matmul on the MXU and applied
+    by the XLU's lane gather, then a log2(C / 128)-step binary shift
+    network over whole blocks, which renames vregs and rotates nothing
+    (bool values don't lower — all masks stay i32).
   * The compaction payload is PACKED: 4 u8 bin rows ride per i32 row
-    (row r of the packed block holds storage rows {r, W+r, 2W+r, 3W+r},
-    W = G32/4) and only the 3 live grad/hess/rowid rows of the f32
-    payload are carried, so the shift network moves (W+3, C) lanes
-    instead of (G32+8, C) — the network's cost is proportional to
-    sublane count and dominated the unpacked kernel (~4x the data).
-  * Pass 1 streams the cover once: lefts are unpacked and flushed
-    forward IN PLACE from the cover base (the left write frontier
-    provably trails the read frontier), rights are flushed forward
-    STILL PACKED into a (16, N_pad) i32 scratch.  Pass 2 slides the
-    staged rights into their final windows with a two-window
-    roll-select on the packed payload, unpacking only at the final
-    write and read-modify-writing only the partial edge windows.
+    (a bitcast of the u8 tile: word w holds storage rows 4w..4w+3) and
+    only the live grad/hess/rowid/... rows of the f32 payload are
+    carried, so the block network moves (W + live, C) lanes instead of
+    (G32 + 8, C) — its cost is proportional to sublane tiles.
+  * Pass 1 streams the cover once: each side's chunk is appended to a
+    two-window staging buffer by a masked store of whole blocks
+    (``_stage``; the compaction already put it at the buffer's lane
+    offset); lefts are unpacked and flushed forward IN PLACE from the
+    cover base (the left write frontier provably trails the read
+    frontier), rights are flushed forward STILL PACKED, and mirrored
+    inside each block, into a (16, N_pad) i32 scratch.  Pass 2 slides
+    the staged rights into their final windows (``_slide``: one lane
+    gather a vreg sets the mirror straight and rotates by the window's
+    offset), unpacking only at the final write and read-modify-writing
+    only the partial edge windows.
 """
 
 from __future__ import annotations
@@ -98,40 +108,155 @@ def _excl_prefix_rights(flag_l, C):
     return (within + carry.reshape(nb, 1)).reshape(1, C).astype(jnp.int32)
 
 
-def _compact(payload, flag, shift0, C, logc):
-    """Stable compaction of flagged lanes to the front: binary shift
-    network, moving each flagged lane left by its deficit (the number of
-    unflagged lanes before it).  Monotone deficits make every step
-    collision-free; unflagged lanes are treated as holes.
+def _compact(payload, left, pnr, C, block=128, lead=0):
+    """Stable two-way compaction of a chunk: ``(lcomp, rcomp)``, each
+    ``(P, C + block)``.  The lanes with ``left != 0`` come back packed in
+    order from position ``lead`` of ``lcomp`` on, the others from
+    position ``-lead % block`` of ``rcomp`` on, where ``rcomp`` is
+    MIRRORED inside each block: position q sits at lane
+    ``q ^ (block - 1)``.  ``0 <= lead < block`` is where the lefts'
+    staging buffer stands inside its block, and the rights' buffer stands
+    at ``-lead % block`` because the two fills add up to whole chunks;
+    ``_stage`` then stores whole blocks and rotates nothing.  ``pnr`` is
+    the number of rights before each lane, so a left moves to position
+    ``d = lead + lane - pnr`` and a right to ``d = -lead % block + pnr``.
+    Two stages, for ``C // block`` blocks of ``block`` lanes (128 in the
+    kernels: one vreg's lanes, one MXU tile):
 
-    The kernel is bound by the lane rotates of this loop (a step under 128
-    lanes rotates every vreg it rolls; PERF.md section 6, PR 30; counted
-    by tools/kernel_ops.py), so a step is ONE roll and one select:
-      * The deficit row rides the payload's last sublane tile, which has
-        room for it whenever P is no multiple of 8 (11 of 16 at the
-        benchmark's geometry), and is tested after the roll: a lane takes
-        its right neighbour when the deficit that arrives has the bit.
-      * A lane that an element has left is not cleared.  Before step b the
-        stale copies of element k sit at o_k - s, for o_k its first lane
-        and s the strict subsets of the bits of its deficit below b, that
-        is less than 2^b lanes to the right of k itself.  A step moves
-        every copy along with k, so the one lane a copy can overwrite lies
-        strictly between k's new lane and its old one, where a network
-        that keeps the elements' order has no element to lose.  Holes
-        carry deficit 0 and never move.  (Every flag row of C = 16:
-        tests/test_pallas_interpret.py.)
-    Lanes past the flagged count come back holding stale copies: callers
-    mask them off (``stage``)."""
+      1. In the block, one permutation.  The lefts of block k take the
+         lanes ``[a, a + n)`` (mod ``block``), ``a`` their first
+         position's lane and ``n`` their number; the rights, mirrored,
+         take what is left, ``[a + n, a + block)``: their positions start
+         at ``-a`` (the two sides' positions before a block add up to
+         whole blocks) and the mirror runs them downwards from ``a - 1``.
+         So ``dest = left ? d : ~d`` (mod ``block``) sends every lane of
+         the block to a lane of its own.  The MXU inverts it: the one-hot
+         ``Q[j, i] = (dest[i] == j)`` against the lane numbers (exact in
+         bf16), contracted over the lanes of both, gives for every output
+         lane the lane it reads, and the XLU's lane gather
+         (``take_along_axis`` inside a vreg) moves the payload, which
+         never meets a rounding.  Each side's *block deficit* rides in
+         the payload's spare sublanes, zero on the other side's lanes:
+         source block k is written to output block k + 1 (``lead`` can
+         push an element one block to the right), so the deficit is
+         ``k + 1 - d // block``, between 0 and ``C // block``.
+      2. Across blocks, on the VPU, once a side and each from the same
+         permuted chunk.  In each lane column a side's elements have
+         increasing source blocks and consecutive destination blocks, so
+         their block deficits are monotone: a binary shift network over
+         whole blocks (a shift by a multiple of 128 lanes renames vregs
+         and rotates nothing), one test of the shifted deficit and one
+         select a step.  The other side's elements carry deficit 0 here:
+         they are holes, which never move and are written over.  A lane
+         that an element has left is not cleared: before step b its
+         stale copies sit less than 2^b blocks behind it in its column
+         and move along with it, so the one lane a copy can overwrite
+         lies strictly between the element's new lane and its old one,
+         where a network that keeps the elements' order has no element
+         to lose; nothing real wraps around, a deficit being at most its
+         element's block.  (Every flag row of 4 x 4 lanes:
+         tests/test_pallas_interpret.py.)
+
+    Stage 1 takes the place of the seven network steps under 128 lanes,
+    whose lane rotates bound the kernel (PERF.md section 6, PRs 30 and
+    34; tools/kernel_ops.py counts what is left).  Everything is static
+    slices of values: the blocks are unrolled, which lets the scheduler
+    overlap the products' fill and drain (a loop over the blocks was
+    three times slower).  Positions outside ``[lead, lead + count)`` of a
+    side come back holding the other side or stale copies: callers mask
+    them off (``_stage``)."""
     P = payload.shape[0]
-    aug = jnp.concatenate([payload, jnp.where(flag != 0, shift0, 0)], axis=0)
-    for b in range(logc):
-        bit = 1 << b
-        rolled = pltpu_roll(aug, C - bit)
-        # the mask goes to the tiles as i32: an i1 row is broadcast over
-        # sublanes through an extui and a second compare
-        take = jnp.broadcast_to(rolled[P:P + 1] & bit, aug.shape) != 0
-        aug = jnp.where(take, rolled, aug)
-    return aug[0:P]
+    R8 = (P + 2 + 7) // 8 * 8            # + the two block-deficit rows
+    logb = block.bit_length() - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    d_left = lead + lane - pnr
+    d_right = ((-lead) & (block - 1)) + pnr
+    out_blk = (lane >> logb) + 1
+    is_left = left != 0
+    aug = jnp.concatenate(
+        [payload,
+         jnp.where(is_left, out_blk - (d_left >> logb), 0),
+         jnp.where(is_left, 0, out_blk - (d_right >> logb)),
+         jnp.zeros((R8 - P - 2, C), jnp.int32)], axis=0)
+    dest = jnp.where(is_left, d_left, ~d_right) & (block - 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (8, block), 1).astype(
+        jnp.float32).astype(jnp.bfloat16)
+    two23 = jnp.float32(1 << 23)
+    out = [jnp.zeros((R8, block), jnp.int32)]
+    for k in range(C // block):
+        at = slice(k * block, (k + 1) * block)
+        q = jnp.where(jnp.broadcast_to(dest[:, at], sub.shape) == sub,
+                      jnp.float32(1.0), jnp.float32(0.0))
+        src = jax.lax.dot_general(
+            lanes, q.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)     # (8, block), rows equal
+        # an exact small integer plus 2^23 has it in its low bits
+        src = jax.lax.bitcast_convert_type(src + two23, jnp.int32) \
+            & (block - 1)
+        out.append(jnp.take_along_axis(
+            aug[:, at], jnp.concatenate([src] * (R8 // 8), axis=0), axis=1))
+    both = jnp.concatenate(out, axis=1)                       # (R8, C + block)
+    comp = []
+    for row in (P, P + 1):
+        side = both
+        for b in range((C // block).bit_length()):    # deficit <= C // block
+            s = block << b
+            ahead = jnp.concatenate([side[:, s:], side[:, :s]], axis=1)
+            # the mask goes to the tiles as i32: an i1 row is broadcast
+            # over sublanes through an extui and a second compare
+            take = jnp.broadcast_to(ahead[row:row + 1] & (1 << b),
+                                    side.shape) != 0
+            side = jnp.where(take, ahead, side)
+        comp.append(side[0:P])
+    return comp
+
+
+def _stage(stg, comp, fill, n_add, C, mirrored=False):
+    """Append one side of a chunk to its ``(P, 2 * C)`` staging buffer:
+    ``comp``'s positions ``[fill % 128, + n_add)`` (``_compact`` with
+    that lead) go to the staging positions ``[fill, + n_add)``, a masked
+    store of whole 128-lane blocks at a dynamic block offset, which
+    rotates nothing.  ``mirrored`` (the rights): position q of ``comp``
+    and of the buffer sits at lane ``q ^ 127``.  Returns (the new fill,
+    whether the first window filled: the caller flushes it and moves the
+    second window down)."""
+    from jax.experimental import pallas as pl
+
+    lead = fill & 127
+    win = pl.ds(pl.multiple_of(fill - lead, 128), C + 128)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, C + 128), 1)
+    if mirrored:
+        pos = pos ^ 127
+    keep = (pos >= lead) & (pos < lead + n_add)
+    stg[:, win] = jnp.where(keep, comp, stg[:, win])
+    new_fill = fill + n_add
+    flushed = (new_fill >= C).astype(jnp.int32)
+    return new_fill - flushed * C, flushed
+
+
+def _slide(prev, cur, r0, C):
+    """The staged rights' window ``cur`` (mirrored inside each 128-lane
+    block, as ``_compact`` leaves them) set straight and slid right by
+    ``r0`` lanes (0 <= r0 < 128), with ``prev``'s last ``r0`` positions
+    in front: lane l holds position ``l - r0`` of ``cur``, or position
+    ``C - r0 + l`` of ``prev`` for l < r0.  Block by block, because r0
+    stays inside a block: one lane gather a vreg undoes the mirror and
+    rotates (lane l reads lane ``(127 + r0 - l) % 128``), and a block
+    takes its first r0 lanes from its left neighbour (a roll of the whole
+    window by a dynamic amount would also pay for shifts by whole blocks
+    that cannot happen)."""
+    from_left = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) < r0
+    read = (127 + r0 - jax.lax.broadcasted_iota(
+        jnp.int32, (prev.shape[0], 128), 1)) & 127
+    left = jnp.take_along_axis(prev[:, C - 128:C], read, axis=1)
+    out = []
+    for k in range(C // 128):
+        here = jnp.take_along_axis(cur[:, k * 128:(k + 1) * 128], read,
+                                   axis=1)
+        out.append(jnp.where(from_left, left, here))
+        left = here
+    return jnp.concatenate(out, axis=1)
 
 
 def payload_codecs(G32: int, ghi_live: int, pack_rowid: bool):
@@ -139,64 +264,52 @@ def payload_codecs(G32: int, ghi_live: int, pack_rowid: bool):
     the split mega-kernel (ops/split_megakernel_pallas.py).
 
     Returns (P, W, pack_bins, unpack_bins, make_payload, split_payload):
-    W = G32 // 4 packed bin words; P = compaction payload sublanes.  All
-    row picks are STATIC sublane slices — masked row selects/reductions
-    take a per-tile slow path in Mosaic (round-5 measurement: an
-    iota-compare formulation of the rowid packing ran 15x slower).
+    W = G32 // 4 packed bin words; P = compaction payload sublanes.
+    Packing and unpacking the bins are bitcasts of the u8 tile: word w
+    holds storage rows 4w..4w+3, row 4w in its low byte (Mosaic makes
+    them of sub-element unpacks and packs, cheaper than the shifts and
+    ors they replace: about 110 bundles a chunk in pass 1 and 170 a
+    window in pass 2, tools/kernel_ops.py --bundles, PR 34).  All row
+    picks are STATIC sublane slices — masked row
+    selects/reductions take a per-tile slow path in Mosaic (round-5
+    measurement: an iota-compare formulation of the rowid packing ran
+    15x slower).
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     W = G32 // 4
     P = W + ghi_live - (1 if pack_rowid else 0)
 
-    def pack_bins(bins_i32):
-        """(G32, C) i32 byte values -> (W, C) packed words."""
-        return (bins_i32[0:W] | (bins_i32[W:2 * W] << 8) |
-                (bins_i32[2 * W:3 * W] << 16) | (bins_i32[3 * W:4 * W] << 24))
+    def pack_bins(bins_u8):
+        """(G32, C) u8 tile -> (W, C) packed words."""
+        return pltpu.bitcast(bins_u8, jnp.int32)
 
     def unpack_bins(packed):
-        """(W, C) packed words -> (G32, C) i32 byte values."""
-        return jnp.concatenate(
-            [packed & 255, (packed >> 8) & 255,
-             (packed >> 16) & 255, (packed >> 24) & 255], axis=0)
+        """(W, C) packed words -> (G32, C) u8 tile."""
+        return pltpu.bitcast(packed, jnp.uint8)
 
     def make_payload(packed, ghi_i):
         """(P, C) compaction payload from packed words + live ghi rows;
-        with pack_rowid the rowid bytes overwrite the zero byte-3 slots
-        of words W-4..W-1 and ghi row 2 is dropped."""
+        with pack_rowid the rowid row takes the place of word W-1 (the
+        zero pad rows G32-4..G32-1) and ghi row 2 is dropped."""
         if not pack_rowid:
             return jnp.concatenate([packed, ghi_i], axis=0)
-        rowid = ghi_i[2:3]                               # (1, C) i32
-        top = [packed[W - 4 + j:W - 3 + j] |
-               ((jax.lax.shift_right_logical(
-                   rowid, jnp.broadcast_to(8 * j, rowid.shape)) & 255)
-                << 24)
-               for j in range(4)]
         extra = [ghi_i[3:ghi_live]] if ghi_live > 3 else []
         return jnp.concatenate(
-            [packed[0:W - 4]] + top + [ghi_i[0:2]] + extra, axis=0)
+            [packed[0:W - 1], ghi_i[2:3], ghi_i[0:2]] + extra, axis=0)
 
     def split_payload(pay):
         """(P, C) payload -> ((W, C) clean packed words, (ghi_live, C)
-        ghi rows in storage order), reconstructing the rowid row."""
+        ghi rows in storage order), the pad rows zero again."""
         if not pack_rowid:
             return pay[0:W], pay[W:P]
-        rowid = None
-        for j in range(4):
-            byte_j = (jax.lax.shift_right_logical(
-                pay[W - 4 + j:W - 3 + j],
-                jnp.broadcast_to(24, (1, pay.shape[1]))) & 255) << (8 * j)
-            rowid = byte_j if rowid is None else rowid | byte_j
         packed = jnp.concatenate(
-            [pay[0:W - 4], pay[W - 4:W] & 0x00FFFFFF], axis=0)
+            [pay[0:W - 1], jnp.zeros((1, pay.shape[1]), jnp.int32)], axis=0)
         tail = [pay[W + 2:P]] if P > W + 2 else []
-        ghi = jnp.concatenate([pay[W:W + 2], rowid] + tail, axis=0)
+        ghi = jnp.concatenate([pay[W:W + 2], pay[W - 1:W]] + tail, axis=0)
         return packed, ghi
 
     return P, W, pack_bins, unpack_bins, make_payload, split_payload
-
-
-def pltpu_roll(x, shift):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.roll(x, shift, 1)
 
 
 def _cdiv(a, c):
@@ -238,13 +351,13 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         (models/boosting.py _setup_fused_step).
       sc_packed: (SC_ROWS, N_pad) i32 scratch staging the packed rights
       scalars: (N_SCALARS,) i32.
-      pack_rowid: ride the rowid-bits ghi row (row 2) inside the 4 spare
-        byte slots of the packed bin words (byte 3 of words W-4..W-1 —
-        the zero pad rows G..G32) instead of as its own payload sublane.
-        The roll network's cost is proportional to payload sublanes
-        (PERF.md), so this drops P by one for free when G <= G32-4.
-        Kernel-internal only: the HBM layout of part_ghi is unchanged
-        and the pad bin rows come back zeroed.
+      pack_rowid: ride the rowid-bits ghi row (row 2) in the place of
+        packed bin word W-1 (the zero pad rows G32-4..G32-1) instead of
+        as its own payload sublane: P drops by one for free when
+        G <= G32-4 (the block network's cost goes by sublane tiles, so it
+        pays where it saves a tile).  Kernel-internal only: the HBM
+        layout of part_ghi is unchanged and the pad bin rows come back
+        zeroed.
     Returns (part_bins', part_ghi', sc_packed', nl) with the first three
     aliased in place; nl is an (8, 128) i32 tile whose [0, 0] element is
     the left count.
@@ -260,10 +373,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             and sc_packed.dtype == jnp.int32)
     C = row_chunk
     assert C >= 256 and (C & (C - 1)) == 0 and Np % 128 == 0
-    logc = C.bit_length() - 1
     assert 3 <= ghi_live <= GH
-    if pack_rowid:
-        assert G32 // 4 >= 4, "pack_rowid needs >= 4 packed words"
     # payload sublanes: bins words + live ghi rows (minus the rowid row
     # when it rides inside the spare bin bytes)
     P, W, pack_bins, unpack_bins, make_payload, split_payload = \
@@ -280,10 +390,9 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         n_chunks = jnp.where(cnt > 0, _cdiv(total, C), 0)
 
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-        # split column lives at byte (col // W) of packed word (col % W)
-        col_k = jax.lax.div(col, W)
-        col_w = col - col_k * W
-        col_sh = col_k * 8
+        # split column lives at byte (col % 4) of packed word (col // 4)
+        col_w = jax.lax.shift_right_logical(col, 2)
+        col_sh = (col & 3) * 8
         word_oh = (jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0) == col_w
                    ).astype(jnp.int32)
 
@@ -316,8 +425,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                 start_read(ci + 1, 1 - slot)
             wait_read(slot)
 
-            bins_i = rb[slot].astype(jnp.int32)               # (G32, C)
-            packed = pack_bins(bins_i)                        # (W, C)
+            packed = pack_bins(rb[slot])                      # (W, C)
             ghi_i = jax.lax.bitcast_convert_type(
                 rg[slot], jnp.int32)[0:ghi_live]
             payload = make_payload(packed, ghi_i)             # (P, C)
@@ -343,22 +451,11 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             nl_cnt = nl_cnt + nlc
             nrc = C - nlc
 
-            lcomp = _compact(payload, left, pnr, C, logc)
-            rcomp = _compact(payload, 1 - left, lane - pnr, C, logc)
+            lcomp, rcomp = _compact(payload, left, pnr, C,
+                                    lead=fill_l & 127)
 
-            def stage(stg, comp, fill, n_add):
-                # place comp[0:n_add) at staging positions [fill, +n_add)
-                rolled = pltpu.roll(comp, fill, 1)
-                m1 = (lane >= fill) & (lane < fill + n_add)
-                stg[:, 0:C] = jnp.where(m1, rolled, stg[:, 0:C])
-                m2 = (lane + C) < (fill + n_add)
-                stg[:, C:2 * C] = jnp.where(m2, rolled, stg[:, C:2 * C])
-                new_fill = fill + n_add
-                flushed = (new_fill >= C).astype(jnp.int32)
-                return new_fill - flushed * C, flushed
-
-            fill_l, fl_l = stage(stgl, lcomp, fill_l, nlc)
-            fill_r, fl_r = stage(stgr, rcomp, fill_r, nrc)
+            fill_l, fl_l = _stage(stgl, lcomp, fill_l, nlc, C)
+            fill_r, fl_r = _stage(stgr, rcomp, fill_r, nrc, C, mirrored=True)
 
             # lefts: unpack and flush in place to the row buffers.
             # Flush DMAs are NOT waited inline: the wait happens just
@@ -376,7 +473,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                     pltpu.make_async_copy(
                         wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
                 pk_l, gl_l = split_payload(stgl[:, 0:C])
-                wb[:] = unpack_bins(pk_l).astype(jnp.uint8)
+                wb[:] = unpack_bins(pk_l)
                 wg[:] = jax.lax.bitcast_convert_type(
                     jnp.concatenate(
                         [gl_l,
@@ -431,7 +528,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         @pl.when(fill_l > 0)
         def _():
             pk_f, gl_f = split_payload(stgl[:, 0:C])
-            wb[:] = unpack_bins(pk_f).astype(jnp.uint8)
+            wb[:] = unpack_bins(pk_f)
             wg[:] = jax.lax.bitcast_convert_type(
                 jnp.concatenate(
                     [gl_f,
@@ -505,11 +602,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
 
             cur_p = rs[slot][0:P]                    # packed payload
             prv_p = rs[1 - slot][0:P]
-            take_prev = lane < r0
-            out_p = jnp.where(take_prev, pltpu.roll(prv_p, r0, 1),
-                              pltpu.roll(cur_p, r0, 1))
+            out_p = _slide(prv_p, cur_p, r0, C)
             pk_2, out_gl = split_payload(out_p)      # clean words + ghi
-            out_b = unpack_bins(pk_2)                # (G32, C)
             valid = (lane >= lo) & (lane < hi)
             # wait the PREVIOUS window's deferred write before reusing
             # the staging buffers (destination windows are disjoint, so
@@ -521,8 +615,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                 pltpu.make_async_copy(
                     wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
             exg_i = jax.lax.bitcast_convert_type(exg[:], jnp.int32)
-            wb[:] = jnp.where(valid, out_b,
-                              exb[:].astype(jnp.int32)).astype(jnp.uint8)
+            wb[:] = unpack_bins(jnp.where(valid, pk_2, pack_bins(exb[:])))
             wg[:] = jax.lax.bitcast_convert_type(
                 jnp.concatenate(
                     [jnp.where(valid, out_gl, exg_i[0:ghi_live]),

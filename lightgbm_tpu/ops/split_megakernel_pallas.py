@@ -2,8 +2,8 @@
 Pallas program per split.
 
 The round-5 cost model (PERF.md) pinned the remaining e2e slope on
-per-row INSTRUCTION count: the partition kernel's compaction networks
-are VPU-issue-bound, the smaller-child histogram hides behind them, and
+per-row INSTRUCTION count: the partition kernel's compaction
+is VPU-issue-bound, the smaller-child histogram hides behind them, and
 the per-split fixed work (histogram dispatch, smaller/larger selection,
 parent-histogram subtraction, the flat hist-state RMW pass, and the two
 contextual f32[L+1, G, B, 2] state copies XLA materializes around the
@@ -15,8 +15,9 @@ resident in fast memory.
 
 This kernel extends the proven partition program
 (ops/partition_pallas.py — identical pass-1/pass-2 structure, DMA
-discipline and compaction networks, built strictly from the
-probe-proven Mosaic subset) with an in-VMEM accumulation of BOTH
+discipline and compaction: its ``_compact``, ``_stage`` and ``_slide``,
+built strictly from the probe-proven Mosaic subset) with an in-VMEM
+accumulation of BOTH
 children's histograms while each chunk's rows are already loaded for
 the compaction:
 
@@ -54,7 +55,7 @@ import jax.numpy as jnp
 from .partition_pallas import (S_A0B, S_REM, S_CNT, S_COL, S_BSTART, S_ISB,
                                S_NB, S_DBIN, S_MTYPE, S_THR, S_DL,
                                _decide_left, _excl_prefix_rights, _cdiv,
-                               payload_codecs, pltpu_roll)
+                               payload_codecs)
 from . import F32_DOT_PRECISION
 from . import partition_pallas as _pp
 
@@ -230,7 +231,6 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             and sc_packed.dtype == jnp.int32)
     C = row_chunk
     assert C >= 256 and (C & (C - 1)) == 0 and Np % 128 == 0
-    logc = C.bit_length() - 1
     G = num_groups
     assert 0 < G <= G32
     BH, _ = hist_geometry(num_bins)
@@ -238,8 +238,8 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     P, W, pack_bins, unpack_bins, make_payload, split_payload = \
         payload_codecs(G32, ghi_live, pack_rowid)
     assert P <= SCR
-    # late-bound so tools/profile_partition.py's network-ablation
-    # monkeypatch applies here too
+    # late-bound so tools/profile_partition.py's ablation of the
+    # compaction applies here too
     compact = _pp._compact
 
     def kernel(s_ref, pb_in, pg_in, sp_in, pb, pg, sp, nl_ref, hist_ref,
@@ -254,10 +254,9 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
         iota_hi = jax.lax.broadcasted_iota(jnp.int32, (BH, C), 0)
         iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, C), 0)
-        # split column lives at byte (col // W) of packed word (col % W)
-        col_k = jax.lax.div(col, W)
-        col_w = col - col_k * W
-        col_sh = col_k * 8
+        # split column lives at byte (col % 4) of packed word (col // 4)
+        col_w = jax.lax.shift_right_logical(col, 2)
+        col_sh = (col & 3) * 8
         word_oh = (jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0) == col_w
                    ).astype(jnp.int32)
 
@@ -293,7 +292,7 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             wait_read(slot)
 
             bins_i = rb[slot].astype(jnp.int32)               # (G32, C)
-            packed = pack_bins(bins_i)                        # (W, C)
+            packed = pack_bins(rb[slot])                      # (W, C)
             ghi_i = jax.lax.bitcast_convert_type(
                 rg[slot], jnp.int32)[0:ghi_live]
             payload = make_payload(packed, ghi_i)             # (P, C)
@@ -334,21 +333,11 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             nl_cnt = nl_cnt + nlc
             nrc = C - nlc
 
-            lcomp = compact(payload, left, pnr, C, logc)
-            rcomp = compact(payload, 1 - left, lane - pnr, C, logc)
-
-            def stage(stg, comp, fill, n_add):
-                rolled = pltpu.roll(comp, fill, 1)
-                m1 = (lane >= fill) & (lane < fill + n_add)
-                stg[:, 0:C] = jnp.where(m1, rolled, stg[:, 0:C])
-                m2 = (lane + C) < (fill + n_add)
-                stg[:, C:2 * C] = jnp.where(m2, rolled, stg[:, C:2 * C])
-                new_fill = fill + n_add
-                flushed = (new_fill >= C).astype(jnp.int32)
-                return new_fill - flushed * C, flushed
-
-            fill_l, fl_l = stage(stgl, lcomp, fill_l, nlc)
-            fill_r, fl_r = stage(stgr, rcomp, fill_r, nrc)
+            lcomp, rcomp = compact(payload, left, pnr, C,
+                                   lead=fill_l & 127)
+            fill_l, fl_l = _pp._stage(stgl, lcomp, fill_l, nlc, C)
+            fill_r, fl_r = _pp._stage(stgr, rcomp, fill_r, nrc, C,
+                                      mirrored=True)
 
             # lefts: unpack and flush in place (deferred-wait DMA
             # discipline identical to partition_leaf_pallas)
@@ -361,7 +350,7 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                     pltpu.make_async_copy(
                         wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
                 pk_l, gl_l = split_payload(stgl[:, 0:C])
-                wb[:] = unpack_bins(pk_l).astype(jnp.uint8)
+                wb[:] = unpack_bins(pk_l)
                 wg[:] = jax.lax.bitcast_convert_type(
                     jnp.concatenate(
                         [gl_l,
@@ -414,7 +403,7 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         @pl.when(fill_l > 0)
         def _():
             pk_f, gl_f = split_payload(stgl[:, 0:C])
-            wb[:] = unpack_bins(pk_f).astype(jnp.uint8)
+            wb[:] = unpack_bins(pk_f)
             wg[:] = jax.lax.bitcast_convert_type(
                 jnp.concatenate(
                     [gl_f,
@@ -477,11 +466,8 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
 
             cur_p = rs[slot][0:P]
             prv_p = rs[1 - slot][0:P]
-            take_prev = lane < r0
-            out_p = jnp.where(take_prev, pltpu.roll(prv_p, r0, 1),
-                              pltpu.roll(cur_p, r0, 1))
+            out_p = _pp._slide(prv_p, cur_p, r0, C)
             pk_2, out_gl = split_payload(out_p)
-            out_b = unpack_bins(pk_2)
             valid = (lane >= lo) & (lane < hi)
 
             @pl.when(j > 0)
@@ -491,8 +477,7 @@ def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                 pltpu.make_async_copy(
                     wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
             exg_i = jax.lax.bitcast_convert_type(exg[:], jnp.int32)
-            wb[:] = jnp.where(valid, out_b,
-                              exb[:].astype(jnp.int32)).astype(jnp.uint8)
+            wb[:] = unpack_bins(jnp.where(valid, pk_2, pack_bins(exb[:])))
             wg[:] = jax.lax.bitcast_convert_type(
                 jnp.concatenate(
                     [jnp.where(valid, out_gl, exg_i[0:ghi_live]),

@@ -121,29 +121,76 @@ def test_partition_kernel_edges_interpreted(case, pack):
         np.asarray(rpg)[:3].view(np.int32), epg[:3].view(np.int32))
 
 
-@pytest.mark.parametrize("P", [3, 11, 19])
-def test_compaction_network_every_flag_row_of_16_lanes(P, monkeypatch):
-    """The network alone, outside any kernel (its rolls as jnp.roll), on
-    all 2^16 flag rows of a 16-lane chunk: the flagged lanes come back at
-    the front in their order, for a payload of one, two and three sublane
-    tiles.  The lanes an element has left are never cleared, so this is
-    the proof by cases that a stale copy reaches no live lane."""
+def _compact_rows(payload, flags, block, lead):
+    """``_compact`` (plain values: no kernel around it) on every row of
+    ``flags`` against the NumPy stable partition: the flagged lanes in
+    order from position ``lead``, the others from position
+    ``-lead % block`` and mirrored inside each block (position q at lane
+    ``q ^ (block - 1)``).  Only a side's own positions are compared: the
+    others hold the other side or stale copies by contract."""
     from lightgbm_tpu.ops import partition_pallas as pp
-    monkeypatch.setattr(pp, "pltpu_roll", lambda x, s: jnp.roll(x, s, 1))
+    n, C = flags.shape
+    rights = 1 - flags
+    pnr = np.cumsum(rights, axis=1) - rights
+    got = jax.jit(jax.vmap(
+        lambda f, d: pp._compact(jnp.asarray(payload, jnp.int32), f[None],
+                                 d[None], C, block, lead)))(
+        jnp.asarray(flags, jnp.int32), jnp.asarray(pnr, jnp.int32))
+    pos = np.arange(C + block)
+    for out, first, off, at in ((got[0], rights, lead, pos),
+                                (got[1], flags, -lead % block,
+                                 pos ^ (block - 1))):
+        order = np.argsort(first, axis=1, kind="stable")         # lanes
+        want = np.zeros((n, payload.shape[0], C + block), np.int32)
+        want[:, :, off:off + C] = payload[:, order].transpose(1, 0, 2)
+        count = C - first.sum(axis=1)
+        kept = ((pos >= off) & (pos < off + count[:, None]))[:, None, :]
+        np.testing.assert_array_equal(
+            np.where(kept, np.asarray(out)[:, :, at], 0),
+            np.where(kept, want, 0))
+
+
+@pytest.mark.parametrize("P, lead", [(3, 0), (11, 1), (19, 3)],
+                         ids=["P3", "P11", "P19"])
+def test_compaction_network_every_flag_row_of_16_lanes(P, lead):
+    """The two-way compaction alone, outside any kernel, at block width 4
+    x 4 blocks on all 2^16 flag rows of a 16-lane chunk: both sides come
+    back packed in their order from their first position on, for a
+    payload of one, two and three sublane tiles.  The block network never
+    clears a lane an element has left and runs each side over the other
+    side's elements, so this is the proof by cases that neither a stale
+    copy nor the other side reaches a live lane, and that one permutation
+    a block puts every element on its final lane."""
     C = 16
     flags = (np.arange(1 << C)[:, None] >> np.arange(C)) & 1     # (2^16, C)
-    holes = 1 - flags
-    deficit = np.cumsum(holes, axis=1) - holes
     payload = np.arange(C)[None, :] + 100 * np.arange(P)[:, None]
-    out = np.asarray(jax.vmap(
-        lambda f, d: pp._compact(jnp.asarray(payload, jnp.int32), f[None],
-                                 d[None], C, 4))(
-        jnp.asarray(flags, jnp.int32), jnp.asarray(deficit, jnp.int32)))
-    order = np.argsort(holes, axis=1, kind="stable")             # lanes
-    want = payload[:, order].transpose(1, 0, 2)                  # (2^16, P, C)
-    kept = np.arange(C)[None, :] < flags.sum(axis=1)[:, None]
-    np.testing.assert_array_equal(np.where(kept[:, None, :], out, 0),
-                                  np.where(kept[:, None, :], want, 0))
+    _compact_rows(payload, flags, 4, lead)
+
+
+@pytest.mark.parametrize("P, lead, share", [
+    (11, 0, 0.5), (13, 77, 0.05), (13, 127, 0.95), (8, 1, 0.5)],
+    ids=["P11_half", "P13_few", "P13_most", "P8_half"])
+def test_compaction_blocks_of_128_lanes(P, lead, share):
+    """The kernels' block width, 128 lanes x 2 blocks: random flag rows
+    at three densities and the edge rows (no flag, every flag, one flag
+    in the last lane, one hole in the first), with payload words that use
+    all 32 bits (the sign bit, 0xFF bytes, the f32 patterns of negative
+    and denormal values): the words move by a lane gather and must come
+    back to the bit."""
+    C = 256
+    rng = np.random.RandomState(P + lead)
+    flags = (rng.rand(64, C) < share).astype(np.int64)
+    flags[0] = 0
+    flags[1] = 1
+    flags[2] = np.arange(C) == C - 1
+    flags[3] = np.arange(C) != 0
+    payload = rng.randint(-2**31, 2**31, (P, C), dtype=np.int64).astype(
+        np.int32)
+    payload[0, :8] = np.array([-2**31, -1, 0x00FF00FF, 0x7FFFFFFF, 0xFF00,
+                               0x00FF0000, 255, 0], np.int64).astype(np.int32)
+    payload[1, :4] = np.array([-1.5, -0.0, 1e-42, -3e-39],
+                              np.float32).view(np.int32)
+    _compact_rows(payload, flags, 128, lead)
 
 
 def test_split_kernel_interpreted():

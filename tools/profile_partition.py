@@ -5,9 +5,9 @@ many-reps-in-one-program + single-materialization discipline (one
 completion barrier per timed program).
 
 Variants:
-  full / onenet / nonet — the partition kernel with both / one / zero
-    compaction networks (the ablations produce WRONG layouts by design;
-    they exist only here, for attribution);
+  full / nonet — the partition kernel with its two-way compaction and
+    with none (the ablation produces a WRONG layout by design; it exists
+    only here, for attribution);
   mega                  — the split mega-kernel (partition + BOTH
     children's histograms in one program): its per-chunk delta over
     "full" is the in-kernel histogram cost the e2e paired A/B
@@ -35,21 +35,18 @@ _REAL_COMPACT = pp._compact
 
 
 def _set_variant(variant):
-    """Monkeypatch the compaction networks for A/B attribution (the
-    ablated kernels produce WRONG partitions by design; they exist only
-    here, never in the shipped kernel)."""
+    """Monkeypatch the compaction for A/B attribution (the ablated kernel
+    produces a WRONG partition by design; it exists only here, never in
+    the shipped kernel)."""
     if variant == "full":
         pp._compact = _REAL_COMPACT
-    elif variant == "onenet":
-        calls = {"n": 0}
-
-        def one(payload, flag, shift0, C, logc):
-            calls["n"] ^= 1
-            return (_REAL_COMPACT(payload, flag, shift0, C, logc)
-                    if calls["n"] else payload)
-        pp._compact = one
     elif variant == "nonet":
-        pp._compact = lambda payload, flag, shift0, C, logc: payload
+        def nonet(payload, left, pnr, C, block=128, lead=0):
+            wide = jnp.concatenate(
+                [payload, jnp.zeros((payload.shape[0], block), jnp.int32)],
+                axis=1)
+            return wide, wide
+        pp._compact = nonet
 
 N = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
 REPS = int(sys.argv[2]) if len(sys.argv) > 2 else 30
@@ -67,8 +64,7 @@ def run(C, variant):
                         128, 1)
     mega = variant == "mega"
 
-    _set_variant(variant if variant in ("full", "onenet", "nonet")
-                 else "full")
+    _set_variant(variant if variant in ("full", "nonet") else "full")
 
     def one(c, _):
         pb, pg, sp = c
@@ -113,7 +109,7 @@ if __name__ == "__main__":
                              {"rows": N, "reps": REPS}) as guard:
         metrics = {}
         for C in (4096, 2048, 8192):
-            for variant in ("full", "onenet", "nonet", "mega"):
+            for variant in ("full", "nonet", "mega"):
                 try:
                     metrics[f"C{C}_{variant}_per_chunk_us"] = \
                         run(C, variant)
