@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Config
+from .. import obs
 from ..dataset import BinnedDataset
 from ..obs import scopes
 from ..ops import split as split_ops
@@ -463,7 +464,16 @@ class SerialTreeLearner:
         self._ghi_rows = 8
         self._ghi_live = 3     # rows the Pallas kernel must carry
         if pallas_part:
-            self._pb_rows = ((self.G + 31) // 32) * 32
+            # whole passes of the kernel (one, of the 32-padded rows,
+            # unless the width is over its VMEM: models/plan.py)
+            self._pb_rows = -(-self.G // plan.pass_rows) * plan.pass_rows
+            # how the width is tiled (Booster.telemetry_report(),
+            # telemetry on): the partition's passes over the payload and
+            # the u8 tiles the histogram kernel's grid walks
+            obs.gauge("train.partition.payload_tiles",
+                      self._pb_rows // plan.pass_rows)
+            if plan.hist == "pallas":
+                obs.gauge("train.hist.feature_tiles", -(-self.G // 32))
         # fused multiclass carries K score rows + label (+ weight) through
         # the partition; the XLA path takes any row count (its per-row
         # gather cost is width-independent), the Pallas kernel is capped
@@ -968,7 +978,8 @@ class SerialTreeLearner:
         pb, pg, sp, nl = partition_leaf_pallas(
             st["part_bins"], st["part_ghi"], st["sc_packed"],
             scalars, row_chunk=self.row_chunk, ghi_live=self._ghi_live,
-            pack_rowid=self.plan.pack_rowid, interpret=self._interp)
+            pack_rowid=self.plan.pack_rowid, pass_rows=self.plan.pass_rows,
+            interpret=self._interp)
         moved = {"part_bins": pb, "part_ghi": pg, "sc_packed": sp}
         return moved, nl[0, 0]
 
@@ -1815,7 +1826,7 @@ class SerialTreeLearner:
         if self.plan.partition == "pallas":
             from ..ops.partition_pallas import sc_rows_for
             state["sc_packed"] = jnp.zeros(
-                (sc_rows_for(self._pb_rows), part_bins.shape[1]),
+                (sc_rows_for(self.plan.pass_rows), part_bins.shape[1]),
                 jnp.int32)
         else:
             state["sc32"] = jnp.zeros((G + self._ghi_rows,
@@ -2075,13 +2086,20 @@ class SerialTreeLearner:
                         moved["part_bins"], moved["part_ghi"],
                         sm_start, sm_cnt, scale=hist_scale))
                     with scopes.scope("hist_state"):
-                        parent_hist = st["hist"][best_leaf]
+                        # the parent's slot is read out before either
+                        # child is written: fused into the second write
+                        # the read keeps the old state alive across the
+                        # first, which costs a copy of the whole state a
+                        # split (1 GB at 2000 features; PERF.md, PR 35)
+                        parent_hist, state_hist = \
+                            jax.lax.optimization_barrier(
+                                (st["hist"][best_leaf], st["hist"]))
                         hist_large = parent_hist - hist_small
                         hist_left = jnp.where(small_is_left, hist_small,
                                               hist_large)
                         hist_right = jnp.where(small_is_left, hist_large,
                                                hist_small)
-                        hist = st["hist"].at[wr_a].set(
+                        hist = state_hist.at[wr_a].set(
                             hist_left).at[wr_b].set(hist_right)
 
                 lsg = pcol[LM_BLSG]
@@ -2549,7 +2567,8 @@ class SerialTreeLearner:
         if self.plan.partition == "pallas":
             from ..ops.partition_pallas import sc_rows_for
             state["sc_packed"] = jnp.zeros(
-                (sc_rows_for(self._pb_rows), part_bins.shape[1]), jnp.int32)
+                (sc_rows_for(self.plan.pass_rows), part_bins.shape[1]),
+                jnp.int32)
         else:
             state["sc32"] = jnp.zeros((G + self._ghi_rows,
                                        part_bins.shape[1]), jnp.int32)

@@ -18,7 +18,12 @@ plan of any shape can be read without building a learner:
 ``SerialTreeLearner.__init__`` gathers the facts, calls ``resolve`` once,
 keeps the result as ``learner.plan`` and builds only the arrays that plan
 needs.  A kernel named in the plan that cannot compile raises from the
-first build: it is never swapped for another.
+first build: it is never swapped for another.  So the plan names no
+kernel that cannot be built at the shape: every Pallas kernel has a pure
+``vmem_bytes`` beside it (ops/*_pallas.py), and a kernel whose VMEM at
+``(G, B, row_chunk)`` is over ``ops.VMEM_LIMIT_BYTES`` is excluded here,
+with the bytes in ``why``.  The partition and histogram kernels tile over
+the width, so theirs do not grow with it (``pass_rows``).
 """
 
 from __future__ import annotations
@@ -26,10 +31,28 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 from typing import Any, Dict, List, Tuple
 
-from ..ops import chunkpolicy
+from ..ops import (VMEM_LIMIT_BYTES, chunkpolicy, hist_state_pallas,
+                   histogram_pallas, partition_pallas, split_megakernel_pallas,
+                   split_pallas)
 
 # the f32 count cumsum of the op-packed fast search is exact below this
 FAST_SEARCH_MAX_ROWS = 1 << 24
+
+# The batched body reads its K parents by one gather over the
+# (L + K, G, B, 2) histogram state and writes their children by one
+# scatter; the K=1 body slices one slot and updates two in place.  On the
+# v5e with this gate lifted (PR 35, PERF.md section 6; s/iter K=4 / K=1
+# at 255 bins and leaves, one run each): 16.8M x 128 features (a state of
+# 68 MB, the general search) 0.6242 / 0.6144; under 2^24 rows, 1M x 256
+# (135 MB) 0.1039 / 0.0964, x 384 (203 MB) 0.1499 / 0.1340, x 512 (271
+# MB) 0.2463 / 0.1803, 600k x 2000 (1.06 GB) 1.035 / 0.459.  From 500
+# features XLA:TPU lays the whole state out anew for the gather at every
+# step (compiled for the v5e: none at 300 and 384); narrower, the body
+# loses by its replay and its undo snapshot.  So K=1 was ahead at every
+# width measured, by 1.6% at the narrowest: 64 MiB keeps the batched
+# body to states under that one (the cells' is 14.8 MB, where PR 27
+# read K=1 1.6% ahead too: ROADMAP C3 queues the body's removal).
+FRONTIER_STATE_MAX_BYTES = 64 << 20
 
 
 @dataclass(frozen=True, kw_only=True)
@@ -92,6 +115,9 @@ class SplitPlan:
     frontier_k: int
     hist_state: str         # "flat" (Pallas RMW) | "xla" ((L+1,G,B,2))
     row_chunk: int          # rows per partition/histogram chunk
+    # u8 bin sublanes one pass of the partition kernel moves; the learner
+    # pads its bin rows to a multiple of it (0 off the kernel)
+    pass_rows: int
     chunk_adaptive: bool
     pack_rowid: bool
     scatter_groups: bool    # ReduceScatter histogram ownership
@@ -121,6 +147,14 @@ def _refused(request: str, reasons: List[str], instead: str) -> str:
     return f"{request} cannot be honoured ({'; '.join(reasons)}); {instead}"
 
 
+def _over_vmem(kernel: str, need: int) -> List[str]:
+    """The reason that excludes ``kernel`` where it would hold ``need``
+    bytes of VMEM, or none."""
+    return _given((need > VMEM_LIMIT_BYTES,
+                   f"{kernel} would hold {need:,} B of VMEM at this shape, "
+                   f"over the {VMEM_LIMIT_BYTES:,} a kernel may take"))
+
+
 def resolve(f: PlanFacts) -> SplitPlan:
     why: Dict[str, str] = {}
     unmet: List[str] = []
@@ -134,7 +168,16 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (f.host_bin_dtype != "uint8",
          f"{f.host_bin_dtype} bins: the kernels' tiles are uint8"))
 
-    # ---- Pallas partition kernel (ops/partition_pallas.py) ----
+    # the base chunk: what the kernels' VMEM is reckoned at (whether the
+    # XLA paths band it by leaf size is decided below)
+    row_chunk, _ = chunkpolicy.resolve(
+        f.tpu_chunk_policy, f.tpu_row_chunk, f.rows, f.num_leaves,
+        eligible=False)
+
+    # ---- Pallas partition kernel (ops/partition_pallas.py): it moves
+    # the bins ``pass_rows`` sublanes a pass, as many as fit its VMEM ----
+    pass_rows = partition_pallas.pass_rows_for(
+        f.G, row_chunk, VMEM_LIMIT_BYTES)
     block = _given(
         (f.tpu_partition_kernel != "pallas",
          f"tpu_partition_kernel={f.tpu_partition_kernel}"),
@@ -150,8 +193,13 @@ def resolve(f: PlanFacts) -> SplitPlan:
             if f.parallel_mode == "feature" else
             "leaf histograms stay device-local for the vote")
          + "; the kernels run per shard under tree_learner=data only"),
-    ) + no_features + u8_bins
+    ) + no_features + u8_bins + _over_vmem(
+        "lgbm_partition", partition_pallas.vmem_bytes(
+            pass_rows or 32, row_chunk,
+            passes=-(-max(f.G, 1) // (pass_rows or 32))))
     pallas_part = not block
+    if not pallas_part:
+        pass_rows = 0
     if block:
         why["partition"] = _said("xla", block)
     partition_xla = _given((not pallas_part, "partition=xla"))
@@ -163,7 +211,9 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (not pallas_part, "partition=xla: the row buffers are not "
                           "sublane-padded for window DMAs"),
         (f.quantized, "use_quantized_grad: the integer carriers are "
-                      "exact in one bf16 pass of the XLA loop"))
+                      "exact in one bf16 pass of the XLA loop"),
+    ) + _over_vmem("lgbm_histogram", histogram_pallas.vmem_bytes(
+        row_chunk, min(f.B, 256), f.G))
     hist = "xla" if block else "pallas"
     if block:
         why["hist"] = _said("xla", block)
@@ -234,7 +284,8 @@ def resolve(f: PlanFacts) -> SplitPlan:
     # shards' histograms would never be summed ----
     block = partition_xla + not_plain + _given(
         (bool(parallel), "parallel tree learners: the histogram sync "
-                         "lives on the XLA search's path"))
+                         "lives on the XLA search's path"),
+    ) + _over_vmem("lgbm_split_search", split_pallas.vmem_bytes(f.F, f.B))
     search = "xla" if block else "pallas"
     if block:
         why["search"] = _said("xla", block)
@@ -252,7 +303,12 @@ def resolve(f: PlanFacts) -> SplitPlan:
             mega = "xla"
             why["mega"] = "xla (tpu_megakernel=xla)"
     elif mode in ("auto", "pallas"):
-        block = partition_xla + block
+        block = partition_xla + block + _over_vmem(
+            "lgbm_split_mega", split_megakernel_pallas.vmem_bytes(
+                row_chunk, min(f.B, 256), f.G)) + _given(
+            (0 < pass_rows < f.G, f"the partition moves {pass_rows} bin "
+                                  "rows a pass: lgbm_split_mega moves "
+                                  "them all at once"))
         if not block:
             mega = "pallas"
     elif mode == "off":
@@ -269,6 +325,7 @@ def resolve(f: PlanFacts) -> SplitPlan:
 
     # ---- frontier-batched growth: order-dependent machinery stays on
     # the K=1 body ----
+    state_bytes = (f.num_leaves + 4) * f.G * f.B * 2 * 4
     block = _given(
         (bool(parallel), "parallel tree learners"),
         (f.forced, "forced splits"),
@@ -281,6 +338,11 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (search == "pallas" and mega == "off",
          "search=pallas with mega=off: the batched body has the pair "
          "search only on the mega path"),
+        (mega == "off" and state_bytes > FRONTIER_STATE_MAX_BYTES,
+         f"a histogram state of {state_bytes:,} B, over "
+         f"{FRONTIER_STATE_MAX_BYTES:,}: the K=1 body was the faster at "
+         "every state that large measured, and from 500 features the "
+         "batched body's gather copies the whole state every step"),
     ) + no_features
     spec = str(f.tpu_frontier_k or "auto").strip().lower()
     if spec == "auto":
@@ -317,7 +379,9 @@ def resolve(f: PlanFacts) -> SplitPlan:
         (search != "pallas", "search=xla"),
         (mega != "off", f"mega={mega}: no histogram state"),
         (frontier_k > 1, f"frontier_k={frontier_k}"),
-        (f.tpu_hist_state == "xla", "tpu_hist_state=xla"))
+        (f.tpu_hist_state == "xla", "tpu_hist_state=xla"),
+    ) + _over_vmem("lgbm_hist_state",
+                   hist_state_pallas.vmem_bytes(f.G, f.B))
     hist_state = "xla" if block else "flat"
     if block:
         why["hist_state"] = _said("xla", block)
@@ -327,7 +391,7 @@ def resolve(f: PlanFacts) -> SplitPlan:
     block = parallel + _given(
         (pallas_part, "partition=pallas: the kernels keep the base "
                       "grid")) + no_features
-    row_chunk, policy = chunkpolicy.resolve(
+    _, policy = chunkpolicy.resolve(
         f.tpu_chunk_policy, f.tpu_row_chunk, f.rows, f.num_leaves,
         eligible=not block)
     chunk_mode = str(f.tpu_chunk_policy or "auto").strip().lower()
@@ -349,7 +413,9 @@ def resolve(f: PlanFacts) -> SplitPlan:
         block = partition_xla + _given(
             (g32 - f.G < 4,
              f"{f.G} groups leave {g32 - f.G} spare rows of {g32}: "
-             "needs 4"))
+             "needs 4"),
+            (pass_rows < g32, f"{g32} bin rows move {pass_rows} a pass: "
+                              "the spare bytes are the last pass's alone"))
         pack_rowid = not block
         if block:
             why["pack_rowid"] = _said("off", block)
@@ -358,7 +424,7 @@ def resolve(f: PlanFacts) -> SplitPlan:
         partition="pallas" if pallas_part else "xla", hist=hist,
         fast_search=fast,
         search=search, mega=mega, frontier_k=frontier_k,
-        hist_state=hist_state, row_chunk=row_chunk,
+        hist_state=hist_state, row_chunk=row_chunk, pass_rows=pass_rows,
         chunk_adaptive=policy.adaptive, pack_rowid=pack_rowid,
         scatter_groups=scatter, linear_gain=linear_gain, why=why,
         unmet=tuple(unmet))

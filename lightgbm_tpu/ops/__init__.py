@@ -12,6 +12,12 @@ import jax
 # quantized integer carriers — are exact at any setting.)
 F32_DOT_PRECISION = jax.lax.Precision.HIGHEST
 
+# Scoped VMEM one Pallas kernel may take on the v5e without asking the
+# compiler for more (its default limit; the kernels ask for none).  Every
+# kernel module has a ``vmem_bytes`` that reckons what its kernel takes at
+# a shape, and models/plan.py names no kernel whose reckoning is over this.
+VMEM_LIMIT_BYTES = 16 << 20
+
 
 def varying_like(shape, dtype, *operands):
     """``ShapeDtypeStruct`` of a ``pallas_call`` output that varies over

@@ -47,6 +47,8 @@ means fewer streamed rows and more one-hot rows to build.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -104,12 +106,47 @@ def layout(num_bins: int, num_groups: int, nl: int):
     return LO, BH, P, R, NG
 
 
+# the kernel walks the features one u8 sublane tile at a time
+TILE = 32
+
+
+def tiles(num_bins: int, num_groups: int):
+    """(T, NGB): the feature tiles the kernel loops over and the MXU
+    groups of one tile's accumulator block.  A tile is the ``TILE`` u8
+    sublanes of one window DMA, ``TILE // P`` groups; a matrix of one
+    tile keeps exactly its own groups (the 28-feature shape: 14 of 16)."""
+    LO, _ = digits(num_bins)
+    P = 128 // LO
+    return -(-num_groups // TILE), min(TILE // P, -(-num_groups // P))
+
+
+def vmem_bytes(row_chunk: int, num_bins: int, num_groups: int) -> int:
+    """Scoped VMEM of ``lgbm_histogram``: the scratch below, the
+    pipelined accumulator block twice, and one inner step's two MXU
+    operands in f32 and bf16.  It does not grow with the number of
+    feature tiles."""
+    LO, BH, P, R, _ = layout(num_bins, num_groups, 3)
+    _, NGB = tiles(num_bins, num_groups)
+    C = row_chunk
+    return (2 * TILE * C + 2 * 8 * C * 4 + TILE * C * 4 + R * C * 4
+            + 2 * NGB * P * R * 128 * 4
+            + (P * R + 128) * min(SUB, C) * 6)
+
+
 def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
                          row_chunk: int, num_groups: int,
                          interpret: bool = False):
-    """The (NG, P*R, 128) f32 accumulator of the leaf range (see
-    ``unpack_acc``).  part_bins: (G32, N_pad) u8, G32 a multiple of 32;
-    part_ghi: (8, N_pad) f32 with grad and hess in rows 0 and 1."""
+    """The (T * NGB, P*R, 128) f32 accumulator of the leaf range (see
+    ``unpack_acc`` and ``tiles``).  part_bins: (G32, N_pad) u8, G32 a
+    multiple of 32; part_ghi: (8, N_pad) f32 with grad and hess in rows
+    0 and 1.
+
+    The grid is the feature tiles: step ``t`` streams the leaf's rows of
+    u8 sublanes ``[32 t, 32 t + 32)`` through the window DMAs, fills that
+    tile's accumulator block and leaves it to the pipeline to write out,
+    so the unrolled body (one tile's groups) and the VMEM held are those
+    of one tile whatever the width; the (grad, hess) rows are read again
+    for every tile.  A single tile is one trip."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -120,6 +157,8 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
     assert C % 128 == 0 and Np % 128 == 0, (C, Np)
     nl = _num_limbs()
     LO, BH, P, R, NG = layout(num_bins, G, nl)
+    T, NGB = tiles(num_bins, G)
+    last = NG - (T - 1) * NGB        # the last tile's groups
     S = min(SUB, C)
     assert C % S == 0
     assert 8 % BH == 0, (num_bins, BH)   # u8 bins: BH <= 4
@@ -127,6 +166,8 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
     per_vreg = 8 // BH               # limbs in one 8-sublane plane
 
     def kernel(s_ref, pb_in, pg_in, acc, rb, rg, bi, lwb, sems):
+        t = pl.program_id(0)
+        row0 = pl.multiple_of(t * TILE, TILE)
         a0b, rem, cnt_ = s_ref[0], s_ref[1], s_ref[2]
         total = rem + cnt_
         n_chunks = jnp.where(cnt_ > 0, _cdiv(total, C), 0)
@@ -134,8 +175,9 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
 
         def copies(ci, slot):
             base = a0b * 128 + ci * C
-            return (pltpu.make_async_copy(pb_in.at[:, pl.ds(base, C)],
-                                          rb.at[slot], sems.at[slot, 0]),
+            return (pltpu.make_async_copy(
+                        pb_in.at[pl.ds(row0, TILE), pl.ds(base, C)],
+                        rb.at[slot], sems.at[slot, 0]),
                     pltpu.make_async_copy(pg_in.at[:, pl.ds(base, C)],
                                           rg.at[slot], sems.at[slot, 1]))
 
@@ -177,35 +219,45 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
                                       jnp.broadcast_to(row, (8, C)), plane)
                 lwb[8 * v:8 * v + 8, :] = plane
 
+            def group(gi, off, nf):
+                """Group ``gi`` of the tile: ``nf`` features' limb rows
+                against their low-digit one-hots, one MXU pass."""
+                lhs, rhs = [], []
+                for f in range(gi * P, gi * P + nf):
+                    b8 = jnp.broadcast_to(bi[f:f + 1, pl.ds(off, S)],
+                                          (8, S))
+                    hi = jax.lax.shift_right_logical(
+                        b8, jnp.broadcast_to(lo_shift, b8.shape))
+                    lo = b8 & (LO - 1)
+                    m_hi = hi == hi_pat
+                    for v in range(R // 8):
+                        lhs.append(jnp.where(
+                            m_hi, lwb[8 * v:8 * v + 8, pl.ds(off, S)],
+                            zero))
+                    for q in lo_pat:
+                        rhs.append(jnp.where(lo == q, jnp.float32(1.0),
+                                             zero))
+                if nf < P:
+                    rhs.append(jnp.zeros(((P - nf) * LO, S), jnp.float32))
+                part = jax.lax.dot_general(
+                    jnp.concatenate(lhs, axis=0).astype(jnp.bfloat16),
+                    jnp.concatenate(rhs, axis=0).astype(jnp.bfloat16),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc[gi, 0:nf * R, :] += part
+
             def sub(si, _):
                 off = pl.multiple_of(si * S, S)
-                for gi in range(NG):
-                    feats = range(gi * P, min(gi * P + P, G))
-                    lhs, rhs = [], []
-                    for f in feats:
-                        b8 = jnp.broadcast_to(bi[f:f + 1, pl.ds(off, S)],
-                                              (8, S))
-                        hi = jax.lax.shift_right_logical(
-                            b8, jnp.broadcast_to(lo_shift, b8.shape))
-                        lo = b8 & (LO - 1)
-                        m_hi = hi == hi_pat
-                        for v in range(R // 8):
-                            lhs.append(jnp.where(
-                                m_hi, lwb[8 * v:8 * v + 8, pl.ds(off, S)],
-                                zero))
-                        for q in lo_pat:
-                            rhs.append(jnp.where(lo == q, jnp.float32(1.0),
-                                                 zero))
-                    nf = len(feats)
-                    if nf < P:
-                        rhs.append(jnp.zeros(((P - nf) * LO, S),
-                                             jnp.float32))
-                    part = jax.lax.dot_general(
-                        jnp.concatenate(lhs, axis=0).astype(jnp.bfloat16),
-                        jnp.concatenate(rhs, axis=0).astype(jnp.bfloat16),
-                        (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc[gi, 0:nf * R, :] += part
+                for gi in range(NGB):
+                    if gi < last:
+                        # one tile: the matrix's last group may be short;
+                        # among several it rides whole (its spare row of
+                        # the u8 tile lands in a block nobody reads)
+                        group(gi, off, min(P, G - gi * P) if T == 1 else P)
+                    else:
+                        # past the last tile's groups: the other tiles'
+                        pl.when(t < T - 1)(
+                            functools.partial(group, gi, off, P))
                 return 0
 
             # the last chunk stops at the leaf's end, not the chunk's
@@ -221,17 +273,18 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
                          jnp.asarray(cnt, jnp.int32)])
     return pl.pallas_call(
         kernel,
-        out_shape=varying_like((NG, P * R, 128), jnp.float32,
+        out_shape=varying_like((T * NGB, P * R, 128), jnp.float32,
                                scalars, part_bins, part_ghi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(1,),
+            grid=(T,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_specs=pl.BlockSpec((NGB, P * R, 128),
+                                   lambda t, s: (t, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, G32, C), jnp.uint8),      # rb
+                pltpu.VMEM((2, TILE, C), jnp.uint8),     # rb
                 pltpu.VMEM((2, GH, C), jnp.float32),     # rg
-                pltpu.VMEM((G32, C), jnp.int32),         # bi
+                pltpu.VMEM((TILE, C), jnp.int32),        # bi
                 pltpu.VMEM((R, C), jnp.float32),         # lwb
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
@@ -241,11 +294,12 @@ def leaf_hist_acc_pallas(part_bins, part_ghi, start, cnt, *, num_bins: int,
 
 
 def unpack_acc(acc, *, num_bins: int, num_groups: int):
-    """(NG, P*R, 128) accumulator -> the (G, 2, Bp) planes
+    """(T * NGB, P*R, 128) accumulator -> the (G, 2, Bp) planes
     ``leaf_hist_slice`` builds before its tail (b = hi * LO + lo): the
     diagonal block of every feature, its limbs summed smallest first."""
     nl = _num_limbs()
-    LO, BH, P, R, NG = layout(num_bins, num_groups, nl)
+    LO, BH, P, R, _ = layout(num_bins, num_groups, nl)
+    NG = acc.shape[0]
     blocks = acc.reshape(NG, P, R, P, LO)
     diag = jnp.stack([blocks[:, p, :2 * nl * BH, p] for p in range(P)],
                      axis=1)                          # (NG, P, 2*nl*BH, LO)

@@ -14,8 +14,13 @@ uncovered), against 0.2 us for its 36 B a row at the HBM peak (PERF.md
 sections 5 and 6; tools/kernel_ops.py --bundles shows which unit's issue
 slots a form fills).  The XLA formulation of the same partition
 (models/learner.py:_partition_leaf) is kept as the CPU / fallback path
-and as the correctness oracle — both produce bit-identical layouts (lefts
-forward-packed in original order, rights behind them in original order).
+and as the correctness oracle.  The kernel's layout is the stable one:
+lefts forward-packed in original order, rights behind them in original
+order.  The XLA form leaves the same lefts, bit for bit, and the same
+rows on the right, but packs each chunk's rights backward from the
+range's end, so over a leaf of several chunks its rights stand in reverse
+chunk order (tests/test_wide_kernels.py holds both to that); the trees do
+not depend on the order of rows inside a leaf.
 
 Design notes (all constraints below were probed on the live toolchain):
   * Window DMAs compile only with provably 128-aligned dynamic lane
@@ -84,6 +89,40 @@ def sc_rows_for(g32: int) -> int:
 
 
 SC_ROWS = sc_rows_for(32)   # the common g32=32 geometry
+
+
+def vmem_bytes(pass_rows: int, row_chunk: int, ghi_live: int = 8,
+               passes: int = 1) -> int:
+    """Scoped VMEM of ``lgbm_partition`` moving ``pass_rows`` u8 sublanes
+    a pass: the scratch it declares, plus what Mosaic keeps of the
+    compaction's values beside it (the permuted chunk and the block
+    network's stages, ``(P + 2, C + 128)`` words each: measured 3.5 to 5.3
+    of them over payloads of 13 to 61 rows, AOT for the v5e, PR 35; 5.5
+    are reckoned)."""
+    C, W = row_chunk, pass_rows // 4
+    P, scr = W + ghi_live, sc_rows_for(pass_rows)
+    declared = (2 * pass_rows * C + 2 * 8 * C * 4 + 2 * scr * C * 4
+                + 2 * P * 2 * C * 4 + 2 * (pass_rows * C + 8 * C * 4)
+                + scr * C * 4 + (2 * 32 * C if passes > 1 else 0))
+    value = (-(-(P + 2) // 8) * 8) * (C + 128) * 4
+    return declared + 11 * value // 2
+
+
+def pass_rows_for(num_groups: int, row_chunk: int, limit: int) -> int:
+    """u8 sublanes a pass of ``lgbm_partition`` moves at this width, a
+    multiple of 32: the whole matrix where one pass fits ``limit`` bytes
+    of VMEM, else the tiles cut evenly into the fewest passes that do
+    (the learner pads its bin rows to a whole number of passes).  0 where
+    not even one tile a pass fits."""
+    T = -(-num_groups // 32)
+    if vmem_bytes(32 * T, row_chunk) <= limit:
+        return 32 * T
+    fit = [tg for tg in range(1, T)
+           if vmem_bytes(32 * tg, row_chunk, passes=2) <= limit]
+    if not fit:
+        return 0
+    passes = -(-T // fit[-1])
+    return 32 * -(-T // passes)
 
 
 def _excl_prefix_rights(flag_l, C):
@@ -338,6 +377,7 @@ def _decide_left(colv, bstart, isb, nb, dbin, mtype, thr, dl):
 def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                           row_chunk: int, ghi_live: int = 3,
                           pack_rowid: bool = False,
+                          pass_rows: int | None = None,
                           interpret: bool = False):
     """Two-way stable partition of the leaf range described by
     ``scalars`` (see the S_* layout above), in place.
@@ -349,8 +389,20 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         trailing pad rows come back zeroed/garbage.  The physical-order
         fused training step rides score and objective payload rows here
         (models/boosting.py _setup_fused_step).
-      sc_packed: (SC_ROWS, N_pad) i32 scratch staging the packed rights
+      sc_packed: (sc_rows_for(pass_rows), N_pad) i32 scratch staging
+        the packed rights of one pass
       scalars: (N_SCALARS,) i32.
+      pass_rows: u8 sublanes of the bins one pass moves (a multiple of
+        32 that divides G32; default G32: one pass).  The grid is the
+        passes: each runs the two-pass compaction below on its own
+        ``pass_rows`` sublanes of the bins, through scratch of that
+        height, so the VMEM held (``vmem_bytes``) follows ``pass_rows``
+        and not the matrix's width.  Every pass decides the rows anew
+        from the split column, read in its ORIGINAL order: the pass that
+        holds the column runs last, and the other passes fetch the
+        column's own u8 tile beside their bins.  The (grad, hess, ...)
+        rows ride in every pass's payload (one traced body) and are
+        written by the last alone.
       pack_rowid: ride the rowid-bits ghi row (row 2) in the place of
         packed bin word W-1 (the zero pad rows G32-4..G32-1) instead of
         as its own payload sublane: P drops by one for free when
@@ -368,20 +420,25 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     G32, Np = part_bins.shape
     GH = part_ghi.shape[0]
     assert GH == 8 and G32 % 32 == 0, (G32, GH)
+    GT = G32 if pass_rows is None else pass_rows
+    assert GT % 32 == 0 and G32 % GT == 0, (G32, GT)
+    NP = G32 // GT
+    assert NP == 1 or not pack_rowid     # the spare bytes are the last pass's
     SCR = sc_packed.shape[0]
     assert (sc_packed.shape[1] == Np and SCR % 8 == 0
             and sc_packed.dtype == jnp.int32)
     C = row_chunk
     assert C >= 256 and (C & (C - 1)) == 0 and Np % 128 == 0
     assert 3 <= ghi_live <= GH
-    # payload sublanes: bins words + live ghi rows (minus the rowid row
-    # when it rides inside the spare bin bytes)
+    # payload sublanes: a pass's bin words + live ghi rows (minus the
+    # rowid row when it rides inside the spare bin bytes)
     P, W, pack_bins, unpack_bins, make_payload, split_payload = \
-        payload_codecs(G32, ghi_live, pack_rowid)
+        payload_codecs(GT, ghi_live, pack_rowid)
     assert P <= SCR
 
-    def kernel(s_ref, pb_in, pg_in, sp_in, pb, pg, sp, nl_ref,
-               rb, rg, rs, stgl, stgr, wb, wg, wp, exb, exg, sems):
+    def kernel(s_ref, pb_in, pg_in, sp_in, pb_all, pg, sp, nl_ref,
+               rb, rg, rs, stgl, stgr, wb, wg, wp, exb, exg, *more):
+        sems = more[-1]
         a0b = s_ref[S_A0B]
         rem = s_ref[S_REM]
         cnt = s_ref[S_CNT]
@@ -391,26 +448,65 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
 
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
         # split column lives at byte (col % 4) of packed word (col // 4)
-        col_w = jax.lax.shift_right_logical(col, 2)
         col_sh = (col & 3) * 8
-        word_oh = (jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0) == col_w
+        if NP == 1:
+            pb_src, pb = pb_in, pb_all
+            col_w = jax.lax.shift_right_logical(col, 2)
+
+            def on_last(fn):
+                fn()
+        else:
+            # the column's pass last: the others read it unpermuted
+            col_pass = jax.lax.div(col, GT)
+            this = jax.lax.rem(pl.program_id(0) + col_pass + 1, NP)
+            rows = pl.ds(pl.multiple_of(this * GT, 32), GT)
+            pb_src, pb = pb_in.at[rows], pb_all.at[rows]
+            rc = more[0]                 # (2, 32, C): the column's u8 tile
+            col_rows = pl.ds(pl.multiple_of(
+                jax.lax.shift_right_logical(col, 5) * 32, 32), 32)
+            col_w = jax.lax.shift_right_logical(col, 2) & 7
+            on_last = pl.when(pl.program_id(0) == NP - 1)
+        word_oh = (jax.lax.broadcasted_iota(
+            jnp.int32, (W if NP == 1 else 8, 1), 0) == col_w
                    ).astype(jnp.int32)
 
         def start_read(ci, slot):
             pltpu.make_async_copy(
-                pb_in.at[:, pl.ds(a0b * 128 + ci * C, C)],
+                pb_src.at[:, pl.ds(a0b * 128 + ci * C, C)],
                 rb.at[slot], sems.at[slot, 0]).start()
             pltpu.make_async_copy(
                 pg_in.at[:, pl.ds(a0b * 128 + ci * C, C)],
                 rg.at[slot], sems.at[slot, 1]).start()
+            if NP > 1:
+                pltpu.make_async_copy(
+                    pb_in.at[col_rows, pl.ds(a0b * 128 + ci * C, C)],
+                    rc.at[slot], sems.at[slot, 4]).start()
 
         def wait_read(slot):
             pltpu.make_async_copy(
-                pb_in.at[:, pl.ds(0, C)], rb.at[slot],
+                pb_src.at[:, pl.ds(0, C)], rb.at[slot],
                 sems.at[slot, 0]).wait()
             pltpu.make_async_copy(
                 pg_in.at[:, pl.ds(0, C)], rg.at[slot],
                 sems.at[slot, 1]).wait()
+            if NP > 1:
+                pltpu.make_async_copy(
+                    pb_in.at[pl.ds(0, 32), pl.ds(0, C)], rc.at[slot],
+                    sems.at[slot, 4]).wait()
+
+        def start_write(at):
+            """The staged window (wb, wg) to the row buffers at lane
+            ``at``; the (grad, hess, ...) rows in the last pass alone."""
+            pltpu.make_async_copy(
+                wb, pb.at[:, pl.ds(at, C)], sems.at[0, 2]).start()
+            on_last(lambda: pltpu.make_async_copy(
+                wg, pg.at[:, pl.ds(at, C)], sems.at[1, 2]).start())
+
+        def wait_write():
+            pltpu.make_async_copy(
+                wb, pb.at[:, pl.ds(0, C)], sems.at[0, 2]).wait()
+            on_last(lambda: pltpu.make_async_copy(
+                wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait())
 
         @pl.when(n_chunks > 0)
         def _():
@@ -432,7 +528,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
 
             # --- decision (numerical splits; see ops/partition.py
             # split_decision and models/learner.py _goes_left) ---
-            word = jnp.sum(packed * word_oh, axis=0,
+            col_words = packed if NP == 1 else pack_bins(rc[slot])
+            word = jnp.sum(col_words * word_oh, axis=0,
                            keepdims=True)                     # (1, C)
             colv = jax.lax.shift_right_logical(
                 word, jnp.broadcast_to(col_sh, word.shape)) & 255
@@ -468,10 +565,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             def _():
                 @pl.when(nfl > 0)
                 def _():
-                    pltpu.make_async_copy(
-                        wb, pb.at[:, pl.ds(0, C)], sems.at[0, 2]).wait()
-                    pltpu.make_async_copy(
-                        wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
+                    wait_write()
                 pk_l, gl_l = split_payload(stgl[:, 0:C])
                 wb[:] = unpack_bins(pk_l)
                 wg[:] = jax.lax.bitcast_convert_type(
@@ -479,12 +573,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                         [gl_l,
                          jnp.zeros((GH - ghi_live, C), jnp.int32)], axis=0),
                     jnp.float32)
-                pltpu.make_async_copy(
-                    wb, pb.at[:, pl.ds(a0b * 128 + nfl * C, C)],
-                    sems.at[0, 2]).start()
-                pltpu.make_async_copy(
-                    wg, pg.at[:, pl.ds(a0b * 128 + nfl * C, C)],
-                    sems.at[1, 2]).start()
+                start_write(a0b * 128 + nfl * C)
                 stgl[:, 0:C] = stgl[:, C:2 * C]
 
             # rights: flush STILL PACKED to the i32 scratch
@@ -512,10 +601,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         # destination regions.
         @pl.when(nfl > 0)
         def _():
-            pltpu.make_async_copy(
-                wb, pb.at[:, pl.ds(0, C)], sems.at[0, 2]).wait()
-            pltpu.make_async_copy(
-                wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
+            wait_write()
 
         @pl.when(nfr > 0)
         def _():
@@ -534,11 +620,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                     [gl_f,
                      jnp.zeros((GH - ghi_live, C), jnp.int32)], axis=0),
                 jnp.float32)
-            cb = pltpu.make_async_copy(
-                wb, pb.at[:, pl.ds(a0b * 128 + nfl * C, C)], sems.at[0, 2])
-            cg = pltpu.make_async_copy(
-                wg, pg.at[:, pl.ds(a0b * 128 + nfl * C, C)], sems.at[1, 2])
-            cb.start(); cg.start(); cb.wait(); cg.wait()
+            start_write(a0b * 128 + nfl * C)
+            wait_write()
 
         @pl.when(fill_r > 0)
         def _():
@@ -592,7 +675,8 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                 cg = pltpu.make_async_copy(
                     pg.at[:, pl.ds(dwb * 128 + j * C, C)], exg,
                     sems.at[1, 3])
-                cb.start(); cg.start(); cb.wait(); cg.wait()
+                cb.start(); on_last(cg.start)
+                cb.wait(); on_last(cg.wait)
 
             @pl.when(read_src)
             def _():
@@ -610,10 +694,7 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
             # the in-flight write never races this window's RMW read)
             @pl.when(j > 0)
             def _():
-                pltpu.make_async_copy(
-                    wb, pb.at[:, pl.ds(0, C)], sems.at[0, 2]).wait()
-                pltpu.make_async_copy(
-                    wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
+                wait_write()
             exg_i = jax.lax.bitcast_convert_type(exg[:], jnp.int32)
             wb[:] = unpack_bins(jnp.where(valid, pk_2, pack_bins(exb[:])))
             wg[:] = jax.lax.bitcast_convert_type(
@@ -622,41 +703,34 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                      exg_i[ghi_live:GH]],
                     axis=0),
                 jnp.float32)
-            pltpu.make_async_copy(
-                wb, pb.at[:, pl.ds(dwb * 128 + j * C, C)],
-                sems.at[0, 2]).start()
-            pltpu.make_async_copy(
-                wg, pg.at[:, pl.ds(dwb * 128 + j * C, C)],
-                sems.at[1, 2]).start()
+            start_write(dwb * 128 + j * C)
             return 0
 
         jax.lax.fori_loop(0, n_d, body2, 0)
 
         @pl.when(n_d > 0)
         def _():
-            pltpu.make_async_copy(
-                wb, pb.at[:, pl.ds(0, C)], sems.at[0, 2]).wait()
-            pltpu.make_async_copy(
-                wg, pg.at[:, pl.ds(0, C)], sems.at[1, 2]).wait()
+            wait_write()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(1,),
+        grid=(NP,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3 +
                   [pl.BlockSpec(memory_space=pltpu.VMEM)],
         scratch_shapes=[
-            pltpu.VMEM((2, G32, C), jnp.uint8),      # rb
+            pltpu.VMEM((2, GT, C), jnp.uint8),       # rb
             pltpu.VMEM((2, GH, C), jnp.float32),     # rg
             pltpu.VMEM((2, SCR, C), jnp.int32),      # rs
             pltpu.VMEM((P, 2 * C), jnp.int32),       # stgl
             pltpu.VMEM((P, 2 * C), jnp.int32),       # stgr
-            pltpu.VMEM((G32, C), jnp.uint8),         # wb
+            pltpu.VMEM((GT, C), jnp.uint8),          # wb
             pltpu.VMEM((GH, C), jnp.float32),        # wg
             pltpu.VMEM((SCR, C), jnp.int32),         # wp
-            pltpu.VMEM((G32, C), jnp.uint8),         # exb
+            pltpu.VMEM((GT, C), jnp.uint8),          # exb
             pltpu.VMEM((GH, C), jnp.float32),        # exg
-            pltpu.SemaphoreType.DMA((2, 4)),
+        ] + ([pltpu.VMEM((2, 32, C), jnp.uint8)] if NP > 1 else []) + [
+            pltpu.SemaphoreType.DMA((2, 5 if NP > 1 else 4)),
         ],
     )
     operands = (scalars, part_bins, part_ghi, sc_packed)

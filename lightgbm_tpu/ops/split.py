@@ -316,12 +316,13 @@ def find_best_split_fast(feat_hist: jnp.ndarray, ctx: SplitContext,
         (FindBestThresholdSequentially, feature_histogram.hpp:830 — the
         reverse scan first, larger thresholds winning reverse ties,
         smaller forward ties, smaller feature index across features)
-        is encoded into the candidate ORDER of one (F, 2*BF) gain
-        matrix — per feature the reverse scan's thresholds descending,
-        then the forward scan's ascending — so a single flat arg-max
-        replaces the per-feature/per-direction arg-max cascade;
-      * the winner's statistics ride one packed (4, F*2*BF) matrix read
-        with a single lane-dynamic slice.
+        is encoded into a rank every candidate carries — per feature
+        the reverse scan's thresholds descending, then the forward
+        scan's ascending — so one min over the ranks of the largest
+        gains replaces the per-feature/per-direction arg-max cascade;
+      * the winner's statistics are read by masked sums over the same
+        (F, BF) grids: nothing is reversed, concatenated or flattened,
+        whose relayouts grow with the width.
 
     Counts ride the f32 cumsum (exact for leaves below 2^24 rows; the
     caller gates on dataset size).
@@ -430,25 +431,36 @@ def find_best_split_fast(feat_hist: jnp.ndarray, ctx: SplitContext,
     else:
         cand_f = jnp.where(valid_f, gain_f, neg)
         cand_r = jnp.where(valid_r, gain_r, neg)
-    # candidate order encodes the tie-breaking (see docstring)
-    gains = jnp.concatenate([cand_r[:, ::-1], cand_f], axis=1)
+    # The winner is the candidate of the largest gain that comes first in
+    # the reference's scan order: feature-major, within a feature the
+    # reverse scan's thresholds descending, then the forward scan's
+    # ascending.  Every candidate carries that rank as a key, and the
+    # winner is the smallest key among the largest gains: elementwise
+    # operations and whole reductions over the two (F, BF) grids, with no
+    # lane reversal, no concatenation and no flattening (at 2000 features
+    # the flat (F * 2 * BF) form took 14 ms a split on the v5e, three
+    # relayouts and one arg-max of a million elements: PERF.md, PR 35).
+    # ops/split_pallas.py ranks its candidates the same way.
+    feat = jax.lax.broadcasted_iota(jnp.int32, (F, BF), 0)
+    per_f = 2 * BF
+    # the two scans side by side on a new MAJOR axis (no lane moves), so
+    # that each step below is one reduction
+    cand = jnp.stack([cand_r, cand_f])                        # (2, F, BF)
+    key = jnp.stack([feat * per_f + (BF - 1 - bins),
+                     feat * per_f + BF + bins])
+    best_gain = jnp.max(cand)
+    widx = jnp.min(jnp.where(cand >= best_gain, key,
+                             jnp.int32(F * per_f)))
     # default_left: reverse scan => True, except single-scan NaN features
     dl_r = jnp.broadcast_to((two_scan | ~is_nan_miss).astype(jnp.float32),
                             (F, BF))
     stats = jnp.stack([
-        jnp.concatenate([left_g_r[:, ::-1], left_g_f], axis=1),
-        jnp.concatenate([left_h_r[:, ::-1], left_h_f], axis=1),
-        jnp.concatenate([left_c_r[:, ::-1], left_c_f], axis=1),
-        jnp.concatenate([dl_r, jnp.zeros((F, BF), jnp.float32)], axis=1),
-    ]).reshape(4, F * 2 * BF)
-
-    flat = gains.reshape(F * 2 * BF)
-    widx = jnp.argmax(flat).astype(jnp.int32)
-    best_gain = flat[widx]
-    picked = jax.lax.dynamic_slice(stats, (0, widx), (4, 1))[:, 0]
+        jnp.stack([left_g_r, left_g_f]), jnp.stack([left_h_r, left_h_f]),
+        jnp.stack([left_c_r, left_c_f]),
+        jnp.stack([dl_r, jnp.zeros((F, BF), jnp.float32)])])  # (4, 2, F, BF)
+    picked = jnp.sum(jnp.where(key == widx, stats, z), axis=(1, 2, 3))
     lg, lh, lc_f32, dl = picked[0], picked[1], picked[2], picked[3]
 
-    per_f = 2 * BF
     best_f = widx // per_f
     r = widx - best_f * per_f
     best_t = jnp.where(r < BF, BF - 1 - r, r - BF)

@@ -202,6 +202,20 @@ def both_children_hist_banded(part_bins, part_ghi, start, cnt, col,
     return acc
 
 
+def vmem_bytes(row_chunk: int, num_bins: int, num_groups: int) -> int:
+    """Scoped VMEM of ``lgbm_split_mega``: one whole-width pass of the
+    partition kernel (it moves every bin row at once) and the
+    (G, 4 * BH, 16) accumulator, whose rows of 16 lanes take rows of 128.
+    (Held against the v5e's compiler, PR 35: at 255 bins and a 4096-row
+    chunk 92 and 124 features compile, in 38 and 51 s, and 137 do not;
+    at a 2048-row chunk 137 and 224, the widest this admits there,
+    compile, in 39 and 72 s.)"""
+    g32 = -(-num_groups // 32) * 32
+    BH, _ = hist_geometry(num_bins)
+    return (_pp.vmem_bytes(g32, row_chunk)
+            + num_groups * (-(-4 * BH // 8) * 8) * 128 * 4)
+
+
 def split_megakernel_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                             row_chunk: int, num_bins: int, num_groups: int,
                             ghi_live: int = 3, pack_rowid: bool = False,

@@ -57,6 +57,20 @@ IN_MASK = 4
 OUT_FIELDS = 13     # lanes of each output row = LM_BGAIN..LM_BISCAT
 
 
+def vmem_bytes(num_features: int, num_bins: int) -> int:
+    """Scoped VMEM of ``lgbm_split_search``, which holds everything
+    whole: the two (2F, BF) histogram planes, the (12F, BF) stack of
+    masked planes and its prefix sums, some twenty (2F, BF) grids of
+    gains, masks and keys, the (BF, BF) triangle and the two (2F, 8)
+    tables (a row of 8 is a row of 128 lanes).  (Held against the v5e's
+    compiler, PR 35: at 255 bins 230 and 232 features compile and 234 do
+    not, this is over the limit from 231; at 63 bins 464 compile and 480
+    do not, this is over from 454.)"""
+    BF = -(-num_bins // 128) * 128
+    plane = 2 * num_features * BF * 4
+    return 34 * plane + BF * BF * 4 + 2 * 2 * num_features * 128 * 4
+
+
 @functools.partial(jax.jit, static_argnames=(
     "l1", "l2", "max_delta_step", "min_gain_to_split", "min_data_in_leaf",
     "min_sum_hessian", "max_depth", "interpret"))

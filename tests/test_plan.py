@@ -20,12 +20,19 @@ CELL = dict(backend="tpu", rows=42_000_000, F=28, G=28, B=255,
 CELL_PLAN = dict(partition="pallas", hist="pallas", fast_search=False,
                  search="xla",
                  mega="off", frontier_k=4, hist_state="xla",
-                 row_chunk=4096, chunk_adaptive=False, pack_rowid=False,
-                 scatter_groups=False, linear_gain=False)
+                 row_chunk=4096, pass_rows=32, chunk_adaptive=False,
+                 pack_rowid=False, scatter_groups=False, linear_gain=False)
 SMOKE = dict(rows=10_500_000)          # chip_smoke.py's shape (PR 21)
 TOY_CPU = dict(backend="cpu", rows=2000, num_leaves=31)
 DATA4 = dict(TOY_CPU, parallel_mode="data", axis_name=True, num_shards=4)
 DP4_CHIP = dict(parallel_mode="data", axis_name=True, num_shards=4)
+# benchmark/configs/epsilon-l255-b255.json at rows600k, and the shape whose
+# default plan did not compile before PR 35 (ISSUE 35's table)
+WIDE = dict(rows=600_000, F=2000, G=2000)
+WIDE_PLAN = dict(partition="pallas", hist="pallas", fast_search=True,
+                 search="xla", mega="off", frontier_k=1, hist_state="xla",
+                 row_chunk=4096, pass_rows=192)
+F137 = dict(rows=1_000_000, F=137, G=137)
 
 
 def facts(**kw):
@@ -50,6 +57,29 @@ CASES = {
                                  hist_state="flat"),
                             ("frontier_k", "search=pallas with mega=off"),
                             None),
+    # width: a kernel whose VMEM at the shape is over the limit is not
+    # named, and the partition moves the bins a few tiles a pass
+    "wide_2000": (WIDE, WIDE_PLAN,
+                  ("mega", "lgbm_split_mega would hold"), None),
+    "wide_2000_search": (WIDE, WIDE_PLAN,
+                         ("search", "lgbm_split_search would hold 143,"),
+                         None),
+    "wide_2000_frontier": (WIDE, WIDE_PLAN,
+                           ("frontier_k", "a histogram state of 1,056,"),
+                           None),
+    "wide_2000_mega_off": (dict(WIDE, tpu_megakernel="off"), WIDE_PLAN,
+                           ("mega", "tpu_megakernel=off"), None),
+    "wide_137": (F137, dict(partition="pallas", hist="pallas",
+                            search="pallas", mega="off", frontier_k=1,
+                            hist_state="flat", pass_rows=160),
+                 ("mega", "lgbm_split_mega would hold 18,"), None),
+    "wide_124": (dict(F137, F=124, G=124),
+                 dict(search="pallas", mega="pallas", frontier_k=4,
+                      pass_rows=128), ("hist_state", "mega=pallas"), None),
+    "wide_pack_rowid": (dict(WIDE, tpu_pack_rowid=True),
+                        dict(WIDE_PLAN, pack_rowid=False),
+                        ("pack_rowid", "2016 bin rows move 192 a pass"),
+                        None),
     "rows_2p24_less_1": (dict(rows=(1 << 24) - 1),
                          dict(fast_search=True, search="pallas",
                               mega="pallas", frontier_k=4),
@@ -222,6 +252,41 @@ def test_plan_table(name):
                        ("search", "pallas"), ("mega", "pallas"),
                        ("hist_state", "flat")):
         assert (getattr(p, key) == value) == (key not in p.why), (key, p)
+
+
+@pytest.mark.parametrize("shape", ["wide_2000", "wide_137", "cell_b255"])
+def test_no_plan_names_a_kernel_over_its_vmem(shape):
+    """Every kernel the plan names at the shape is under the limit by the
+    pure function beside it, the one ``resolve`` excludes by; and the wide
+    cell's one ``tpu_*`` key changes nothing the program runs."""
+    from lightgbm_tpu.ops import (VMEM_LIMIT_BYTES, hist_state_pallas,
+                                  histogram_pallas, partition_pallas,
+                                  split_megakernel_pallas, split_pallas)
+    f = facts(**CASES[shape][0])
+    p = plan.resolve(f)
+    C, B = p.row_chunk, f.B
+    named = {
+        "lgbm_partition": (p.partition == "pallas", lambda:
+                           partition_pallas.vmem_bytes(
+                               p.pass_rows, C,
+                               passes=-(-f.G // p.pass_rows))),
+        "lgbm_histogram": (p.hist == "pallas", lambda:
+                           histogram_pallas.vmem_bytes(C, B, f.G)),
+        "lgbm_split_search": (p.search == "pallas", lambda:
+                              split_pallas.vmem_bytes(f.F, B)),
+        "lgbm_split_mega": (p.mega == "pallas", lambda:
+                            split_megakernel_pallas.vmem_bytes(C, B, f.G)),
+        "lgbm_hist_state": (p.hist_state == "flat", lambda:
+                            hist_state_pallas.vmem_bytes(f.G, B)),
+    }
+    assert named["lgbm_partition"][0] and named["lgbm_histogram"][0]
+    for kernel, (is_named, need) in named.items():
+        assert not is_named or need() <= VMEM_LIMIT_BYTES, (kernel, need())
+    off = plan.resolve(facts(**dict(CASES[shape][0], tpu_megakernel="off")))
+    if shape == "wide_2000":
+        assert off.kernel_plan() == p.kernel_plan()
+        assert {k: v for k, v in vars(off).items() if k != "why"} \
+            == {k: v for k, v in vars(p).items() if k != "why"}
 
 
 @pytest.mark.parametrize("spec", ["0", "-3", "bogus"])

@@ -566,6 +566,163 @@ def test_histogram_kernel_compiles_for_v5e_under_its_name(one_chip, B):
     _assert_only_kernel(text, "lgbm_histogram", "histogram")
 
 
+# the wide shapes: 137 features are five u8 tiles, one pass of the
+# partition; 2000 are 63, which the plan cuts into passes of 192 sublanes
+# (models/plan.py, PR 35).  What is compiled is the cells' 4096-row chunk
+# with seven payload rows live (the cells carry five).
+_WIDE = {137: 160, 2000: 2112}
+
+
+@pytest.mark.parametrize("G", sorted(_WIDE))
+def test_partition_kernel_compiles_for_v5e_at_width(one_chip, G):
+    from lightgbm_tpu.models import plan
+    from lightgbm_tpu.ops import VMEM_LIMIT_BYTES
+    from lightgbm_tpu.ops.partition_pallas import (N_SCALARS,
+                                                   partition_leaf_pallas,
+                                                   pass_rows_for,
+                                                   sc_rows_for, vmem_bytes)
+    C, Np = 4096, 16 * 4096
+    rows = pass_rows_for(G, C, VMEM_LIMIT_BYTES)
+    G32 = -(-G // rows) * rows
+    assert G32 == _WIDE[G] and vmem_bytes(
+        rows, C, passes=G32 // rows) <= VMEM_LIMIT_BYTES
+
+    def split(pb, pg, sp, sc):
+        with scopes.scope("partition"):
+            return partition_leaf_pallas(pb, pg, sp, sc, row_chunk=C,
+                                         ghi_live=7, pass_rows=rows)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _compile_cache_off():
+        text = jax.jit(split).lower(
+            sds((G32, Np), jnp.uint8), sds((8, Np), jnp.float32),
+            sds((sc_rows_for(rows), Np), jnp.int32),
+            sds((N_SCALARS,), jnp.int32)).compile().as_text()
+    _assert_only_kernel(text, "lgbm_partition", "partition")
+
+
+@pytest.mark.parametrize("G", sorted(_WIDE))
+def test_histogram_kernel_compiles_for_v5e_at_width(one_chip, G):
+    from lightgbm_tpu.ops.histogram_pallas import leaf_hist_pallas
+    C, Np, G32 = 4096, 16 * 4096, _WIDE[G]
+
+    def leaf(pb, pg, start, cnt):
+        with scopes.scope("histogram"):
+            return leaf_hist_pallas(pb, pg, start, cnt, num_bins=255,
+                                    row_chunk=C, num_groups=G)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _compile_cache_off():
+        compiled = jax.jit(leaf).lower(
+            sds((G32, Np), jnp.uint8), sds((8, Np), jnp.float32),
+            sds((), jnp.int32), sds((), jnp.int32)).compile()
+    _assert_only_kernel(compiled.as_text(), "lgbm_histogram", "histogram")
+
+
+def _search_at(F, sds):
+    from lightgbm_tpu.ops.split_pallas import best_split_pair_pallas
+
+    def pair(hg, hh, fmeta, info):
+        with scopes.scope("search"):
+            return best_split_pair_pallas(
+                hg, hh, fmeta, info, l1=0.0, l2=0.0, max_delta_step=0.0,
+                min_gain_to_split=0.0, min_data_in_leaf=1,
+                min_sum_hessian=100.0, max_depth=-1)
+    return jax.jit(pair).lower(
+        sds((2 * F, 256), jnp.float32), sds((2 * F, 256), jnp.float32),
+        sds((2 * F, 8), jnp.int32), sds((2 * F, 8), jnp.float32))
+
+
+def _hist_state_at(G, sds):
+    from lightgbm_tpu.ops.hist_state_pallas import (flat_geometry,
+                                                    hist_rmw_pallas)
+    WL = flat_geometry(G, 255)[2]
+
+    def rmw(state, small, idx):
+        with scopes.scope("hist_state"):
+            return hist_rmw_pallas(state, small, idx)
+    return jax.jit(rmw).lower(
+        sds((256, 8, WL), jnp.float32), sds((8, WL), jnp.float32),
+        sds((4,), jnp.int32))
+
+
+# the two kernels that hold everything whole, at 255 bins: the widest
+# shape each formula lets the plan name, and the nearest the v5e's
+# compiler refuses (the formulas stop a little short of it)
+@pytest.mark.parametrize("kernel, width, builds", [
+    ("lgbm_split_search", 230, True), ("lgbm_split_search", 234, False),
+    ("lgbm_hist_state", 2048, True), ("lgbm_hist_state", 2080, False)])
+def test_whole_vmem_kernels_build_as_far_as_their_formulas_say(
+        one_chip, kernel, width, builds):
+    from lightgbm_tpu.ops import (VMEM_LIMIT_BYTES, hist_state_pallas,
+                                  split_pallas)
+    need, lower, phase = {
+        "lgbm_split_search": (split_pallas.vmem_bytes, _search_at, "search"),
+        "lgbm_hist_state": (hist_state_pallas.vmem_bytes, _hist_state_at,
+                            "hist_state")}[kernel]
+    assert (need(width, 255) <= VMEM_LIMIT_BYTES) == builds
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _compile_cache_off():
+        lowered = lower(width, sds)
+        if builds:
+            _assert_only_kernel(lowered.compile().as_text(), kernel, phase)
+        else:
+            with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+                lowered.compile()
+
+
+def test_default_step_at_137_features_compiles_for_v5e(one_chip,
+                                                       monkeypatch):
+    """The defaults at 137 features under 2^24 rows named a mega-kernel
+    the v5e's compiler refused (ISSUE 35).  The plan of (1M, 137, 255) is
+    reached at toy rows (3000 take the same 4096-row chunk, and nothing
+    else in it follows the rows under 2^24), and the whole fused step
+    compiles with the four kernels it names."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.models import plan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X, y = _toy(n=3000, f=137)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                       "verbosity": -1}, lgb.Dataset(X, label=y))
+    g = bst._gbdt
+    lr = g.learner
+    options = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
+    at_size = plan.resolve(plan.PlanFacts(
+        backend="tpu", rows=1_000_000, F=137, G=137, B=255, num_leaves=255,
+        **options))
+    assert at_size.kernel_plan() == {
+        "partition": "pallas", "hist": "pallas", "search": "pallas",
+        "hist_state": "flat", "mega": "off", "frontier_k": 1}
+    assert "lgbm_split_mega would hold" in at_size.why["mega"]
+    assert lr.plan.kernel_plan() == at_size.kernel_plan() and lr.B == 255
+    assert (lr.plan.row_chunk, lr.plan.pass_rows) \
+        == (at_size.row_chunk, at_size.pass_rows) == (4096, 160)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _compile_cache_off():
+        text = g._fused_phys.lower(
+            sds((lr._pb_rows, lr.N_pad), jnp.uint8),
+            sds((8, lr.N_pad), jnp.float32), sds((lr.F,), jnp.bool_), 1,
+            sds((lr.F,), jnp.bool_)).compile().as_text()
+    table = scopes.phase_of(text)
+    for kernel, phase in (("lgbm_partition", "partition"),
+                          ("lgbm_histogram", "histogram"),
+                          ("lgbm_split_search", "search"),
+                          ("lgbm_hist_state", "hist_state")):
+        found = [n for n in table if n.startswith(kernel)]
+        assert found and {table[n] for n in found} == {phase}, kernel
+    assert "lgbm_split_mega" not in text
+
+
 def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
         one_chip, monkeypatch):
     """What the CPU cannot show: XLA:TPU's copy insertion.  The frontier

@@ -137,7 +137,7 @@ def _operands(args):
     from lightgbm_tpu.ops.partition_pallas import N_SCALARS, sc_rows_for
     Np = 64 * args.chunk
     return [((args.g32, Np), jnp.uint8), ((8, Np), jnp.float32),
-            ((sc_rows_for(args.g32), Np), jnp.int32),
+            ((sc_rows_for(args.pass_rows or args.g32), Np), jnp.int32),
             ((N_SCALARS,), jnp.int32)]
 
 
@@ -147,7 +147,7 @@ def _partition(args):
     def fn(pb, pg, sp, s):
         return partition_leaf_pallas(
             pb, pg, sp, s, row_chunk=args.chunk, ghi_live=args.ghi_live,
-            pack_rowid=args.pack_rowid)
+            pack_rowid=args.pack_rowid, pass_rows=args.pass_rows)
     return "lgbm_partition", fn, _operands(args)
 
 
@@ -240,6 +240,9 @@ def main(argv=None):
     ap.add_argument("kernel", choices=sorted(KERNELS))
     ap.add_argument("--g32", type=int, default=32,
                     help="sublanes of the u8 bin matrix (multiple of 32)")
+    ap.add_argument("--pass-rows", type=int, default=None,
+                    help="partition: u8 sublanes a pass moves (a divisor "
+                         "of --g32; default: all, one pass)")
     ap.add_argument("--chunk", type=int, default=4096, help="row_chunk")
     ap.add_argument("--ghi-live", type=int, default=3)
     ap.add_argument("--pack-rowid", action="store_true")
