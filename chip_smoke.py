@@ -44,7 +44,7 @@ TOY = {"rows": 20_000, "holdout": 5_000, "leaves": 31, "timed": 3,
 
 # what `auto` must resolve to for this shape (all-numerical, u8 bins, serial)
 TPU_PLAN = {"partition": "pallas", "hist": "pallas", "search": "pallas",
-            "mega": "pallas", "frontier_k": 4, "fused": "on",
+            "mega": "pallas", "frontier_k": 1, "fused": "on",
             "tree_learner": "serial"}
 CPU_PLAN = {"partition": "xla", "hist": "xla", "search": "xla",
             "mega": "off", "frontier_k": 1, "fused": "on",

@@ -426,15 +426,18 @@ _PARAMS: List[_Param] = [
     # any backend); "off" disables
     _p("tpu_megakernel", "auto", str),
     # frontier-batched tree growth: grow the top-K gain leaves of the
-    # current frontier per while-loop step instead of 1, amortizing the
-    # per-split fixed bookkeeping cost ~K-fold (models/learner.py; the
-    # oracle-order replay keeps trained trees BIT-identical to the K=1
-    # learner, including at the num_leaves budget boundary).  "auto"
-    # engages K=4 on TPU backends when the plain serial path is active
-    # and stays at 1 elsewhere; an explicit integer K forces batching on
-    # any backend (falls back to 1 with a warning when forced splits,
-    # monotone constraints, CEGB, extra_trees, feature_fraction_bynode,
-    # interaction constraints or a parallel tree learner are active)
+    # current frontier per while-loop step instead of 1 (models/learner.py;
+    # the oracle-order replay keeps trained trees BIT-identical to the K=1
+    # learner, including at the num_leaves budget boundary).  "auto" is 1
+    # on every backend and shape: on the v5e the one-leaf body was no
+    # slower than K=4 at any shape measured and compiles in two thirds
+    # of the time (models/plan.py:AUTO_FRONTIER_K; PERF.md section 6,
+    # PR 36).
+    # An explicit integer K builds the batched body on any backend (falls
+    # back to 1 with a warning when forced splits, monotone constraints,
+    # CEGB, extra_trees, feature_fraction_bynode, interaction constraints,
+    # a parallel tree learner, the pair search without the mega-kernel or
+    # a histogram state over 64 MiB are active)
     _p("tpu_frontier_k", "auto", str),
     # run the Pallas kernels through the interpreter on any backend
     # (testing/debug: enables the kernel paths off-TPU; SLOW)

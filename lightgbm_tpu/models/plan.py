@@ -38,20 +38,29 @@ from ..ops import (VMEM_LIMIT_BYTES, chunkpolicy, hist_state_pallas,
 # the f32 count cumsum of the op-packed fast search is exact below this
 FAST_SEARCH_MAX_ROWS = 1 << 24
 
-# The batched body reads its K parents by one gather over the
-# (L + K, G, B, 2) histogram state and writes their children by one
-# scatter; the K=1 body slices one slot and updates two in place.  On the
-# v5e with this gate lifted (PR 35, PERF.md section 6; s/iter K=4 / K=1
-# at 255 bins and leaves, one run each): 16.8M x 128 features (a state of
-# 68 MB, the general search) 0.6242 / 0.6144; under 2^24 rows, 1M x 256
-# (135 MB) 0.1039 / 0.0964, x 384 (203 MB) 0.1499 / 0.1340, x 512 (271
-# MB) 0.2463 / 0.1803, 600k x 2000 (1.06 GB) 1.035 / 0.459.  From 500
-# features XLA:TPU lays the whole state out anew for the gather at every
-# step (compiled for the v5e: none at 300 and 384); narrower, the body
-# loses by its replay and its undo snapshot.  So K=1 was ahead at every
-# width measured, by 1.6% at the narrowest: 64 MiB keeps the batched
-# body to states under that one (the cells' is 14.8 MB, where PR 27
-# read K=1 1.6% ahead too: ROADMAP C3 queues the body's removal).
+# What ``tpu_frontier_k=auto`` says in ``why``.  auto is the one-leaf body
+# (K=1) on every backend and shape: on the v5e the batched body (K=4) was
+# ahead of it at no shape measured: 42M x 28 at 255 and 63 bins, 10.5M x
+# 28 under the mega plan, 16.8M x 128, 1M x 256 to 512, 600k x 2000 (PERF.md
+# section 6, PRs 27, 35 and 36: 0.5306 / 0.5105 and 0.4342 / 0.4134 s an
+# iteration in the two one-chip HIGGS cells, 1.4966 / 1.4883 under the mega
+# plan); its undo snapshot insured an undo that ran in 0 of 15 trees, and
+# its step compiles in 32 s at the cells' shape where K=1's takes 21.
+AUTO_FRONTIER_K = ("tpu_frontier_k=auto: the one-leaf body was no slower than "
+                   "the batched one at any shape measured on the v5e "
+                   "(PERF_LEDGER.jsonl, PR 36)")
+
+# For an explicit tpu_frontier_k > 1 alone.  The batched body reads its K
+# parents by one gather over the (L + K, G, B, 2) histogram state and
+# writes their children by one scatter; the K=1 body slices one slot and
+# updates two in place.  On the v5e with this gate lifted (PR 35, PERF.md
+# section 6; s/iter K=4 / K=1 at 255 bins and leaves, one run each): 16.8M
+# x 128 features (a state of 68 MB, the general search) 0.6242 / 0.6144;
+# under 2^24 rows, 1M x 256 (135 MB) 0.1039 / 0.0964, x 384 (203 MB)
+# 0.1499 / 0.1340, x 512 (271 MB) 0.2463 / 0.1803, 600k x 2000 (1.06 GB)
+# 1.035 / 0.459.  From 500 features XLA:TPU lays the whole state out anew
+# for the gather at every step (compiled for the v5e: none at 300 and
+# 384), which an explicit request is refused rather than handed.
 FRONTIER_STATE_MAX_BYTES = 64 << 20
 
 
@@ -323,35 +332,12 @@ def resolve(f: PlanFacts) -> SplitPlan:
             unmet.append(_refused(f"tpu_megakernel={mode}", block,
                                   "using the current split path"))
 
-    # ---- frontier-batched growth: order-dependent machinery stays on
-    # the K=1 body ----
-    state_bytes = (f.num_leaves + 4) * f.G * f.B * 2 * 4
-    block = _given(
-        (bool(parallel), "parallel tree learners"),
-        (f.forced, "forced splits"),
-        (linear_gain, "linear_tree_mode=leafwise_gain"),
-        (f.use_mc, "monotone constraints"),
-        (f.has_cegb, "CEGB penalties"),
-        (f.extra_trees, "extra_trees"),
-        (f.has_bynode, "feature_fraction_bynode"),
-        (f.interaction_constraints, "interaction constraints"),
-        (search == "pallas" and mega == "off",
-         "search=pallas with mega=off: the batched body has the pair "
-         "search only on the mega path"),
-        (mega == "off" and state_bytes > FRONTIER_STATE_MAX_BYTES,
-         f"a histogram state of {state_bytes:,} B, over "
-         f"{FRONTIER_STATE_MAX_BYTES:,}: the K=1 body was the faster at "
-         "every state that large measured, and from 500 features the "
-         "batched body's gather copies the whole state every step"),
-    ) + no_features
+    # ---- frontier-batched growth (K leaves a loop step): auto is the
+    # one-leaf body everywhere (AUTO_FRONTIER_K); an explicit K > 1 is
+    # honoured unless order-dependent machinery needs the K=1 body ----
     spec = str(f.tpu_frontier_k or "auto").strip().lower()
     if spec == "auto":
-        # off the TPU auto stays at 1: the larger traced program taxes
-        # every fresh compile, which test-sized trainings pay
-        if f.backend != "tpu":
-            block = block + [f"tpu_frontier_k=auto is 1 on backend "
-                             f"{f.backend}"]
-        k_req = 1 if block else 4
+        k_req = 1
     else:
         try:
             k_req = int(spec)
@@ -360,17 +346,36 @@ def resolve(f: PlanFacts) -> SplitPlan:
                              f"positive integer, got {spec!r}")
         if k_req < 1:
             raise ValueError("tpu_frontier_k must be >= 1")
-        if k_req > 1 and block:
-            unmet.append(_refused(f"tpu_frontier_k={k_req}", block,
+    refused: List[str] = []
+    if k_req > 1:
+        state_bytes = (f.num_leaves + 4) * f.G * f.B * 2 * 4
+        refused = _given(
+            (bool(parallel), "parallel tree learners"),
+            (f.forced, "forced splits"),
+            (linear_gain, "linear_tree_mode=leafwise_gain"),
+            (f.use_mc, "monotone constraints"),
+            (f.has_cegb, "CEGB penalties"),
+            (f.extra_trees, "extra_trees"),
+            (f.has_bynode, "feature_fraction_bynode"),
+            (f.interaction_constraints, "interaction constraints"),
+            (search == "pallas" and mega == "off",
+             "search=pallas with mega=off: the batched body has the pair "
+             "search only on the mega path"),
+            (mega == "off" and state_bytes > FRONTIER_STATE_MAX_BYTES,
+             f"a histogram state of {state_bytes:,} B, over "
+             f"{FRONTIER_STATE_MAX_BYTES:,}: the K=1 body was the faster at "
+             "every state that large measured, and from 500 features the "
+             "batched body's gather copies the whole state every step"),
+        ) + no_features
+        if refused:
+            unmet.append(_refused(f"tpu_frontier_k={k_req}", refused,
                                   "using 1"))
-            k_req = 1
-        elif k_req == 1:
-            block = ["tpu_frontier_k=1"]
-    frontier_k = max(1, min(k_req, f.num_leaves - 1))
-    if frontier_k == 1 and k_req > 1:
-        block = [f"num_leaves={f.num_leaves}"]
+    frontier_k = 1 if refused else max(1, min(k_req, f.num_leaves - 1))
     if frontier_k == 1:
-        why["frontier_k"] = _said(1, block)
+        why["frontier_k"] = _said(1, refused or [
+            AUTO_FRONTIER_K if spec == "auto"
+            else "tpu_frontier_k=1" if k_req == 1
+            else f"num_leaves={f.num_leaves}"])
 
     # ---- flat histogram state + Pallas RMW (ops/hist_state_pallas.py):
     # the mega path holds no state, the batched body moves its rows

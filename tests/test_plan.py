@@ -19,7 +19,7 @@ CELL = dict(backend="tpu", rows=42_000_000, F=28, G=28, B=255,
             num_leaves=255)
 CELL_PLAN = dict(partition="pallas", hist="pallas", fast_search=False,
                  search="xla",
-                 mega="off", frontier_k=4, hist_state="xla",
+                 mega="off", frontier_k=1, hist_state="xla",
                  row_chunk=4096, pass_rows=32, chunk_adaptive=False,
                  pack_rowid=False, scatter_groups=False, linear_gain=False)
 SMOKE = dict(rows=10_500_000)          # chip_smoke.py's shape (PR 21)
@@ -39,24 +39,44 @@ def facts(**kw):
     return plan.PlanFacts(**{**OPTIONS, **CELL, **kw})
 
 
+# tpu_frontier_k=auto is the one-leaf body on every shape (PR 36)
+AUTO_K = "1 (tpu_frontier_k=auto: the one-leaf body was no slower"
+K4 = dict(tpu_frontier_k="4")
+K4_REFUSED = "tpu_frontier_k=4 cannot be honoured "
+
 CASES = {
-    "cell_b255": ({}, CELL_PLAN, ("mega", "off (rows 42,000,000 >= 2^24: "
-                                  "the f32 count cumsum of the fast search "
-                                  "is exact only below it)"), None),
+    "cell_b255": ({}, CELL_PLAN, ("frontier_k", AUTO_K), None),
+    "cell_b255_mega": ({}, CELL_PLAN,
+                       ("mega", "off (rows 42,000,000 >= 2^24: the f32 "
+                                "count cumsum of the fast search is exact "
+                                "only below it)"), None),
     "cell_b63": (dict(B=63), CELL_PLAN,
-                 ("chunk_adaptive", "partition=pallas"), None),
+                 ("frontier_k", "(PERF_LEDGER.jsonl, PR 36)"), None),
+    "cell_b63_chunks": (dict(B=63), CELL_PLAN,
+                        ("chunk_adaptive", "partition=pallas"), None),
+    # an explicit request still builds the batched body at the cells' shape
+    "cell_b255_k4": (K4, dict(CELL_PLAN, frontier_k=4),
+                     ("hist_state", "search=xla"), None),
     # PR 21's smoke line
     "smoke_10m5": (SMOKE, dict(fast_search=True, search="pallas",
-                               mega="pallas", frontier_k=4,
+                               mega="pallas", frontier_k=1,
                                hist_state="xla"),
                    ("hist_state", "mega=pallas"), None),
+    "smoke_10m5_k4": (dict(SMOKE, **K4),
+                      dict(fast_search=True, search="pallas", mega="pallas",
+                           frontier_k=4, hist_state="xla"),
+                      ("hist_state", "mega=pallas"), None),
     # PR 21's 0.42 s arm: the plan no default and no cell reaches
     "smoke_10m5_mega_off": (dict(SMOKE, tpu_megakernel="off"),
                             dict(partition="pallas", search="pallas",
                                  mega="off", frontier_k=1,
                                  hist_state="flat"),
-                            ("frontier_k", "search=pallas with mega=off"),
-                            None),
+                            ("mega", "tpu_megakernel=off"), None),
+    "smoke_10m5_mega_off_k4": (dict(SMOKE, tpu_megakernel="off", **K4),
+                               dict(search="pallas", mega="off",
+                                    frontier_k=1, hist_state="flat"),
+                               ("frontier_k", "search=pallas with mega=off"),
+                               K4_REFUSED + "(search=pallas with mega=off"),
     # width: a kernel whose VMEM at the shape is over the limit is not
     # named, and the partition moves the bins a few tiles a pass
     "wide_2000": (WIDE, WIDE_PLAN,
@@ -64,9 +84,10 @@ CASES = {
     "wide_2000_search": (WIDE, WIDE_PLAN,
                          ("search", "lgbm_split_search would hold 143,"),
                          None),
-    "wide_2000_frontier": (WIDE, WIDE_PLAN,
-                           ("frontier_k", "a histogram state of 1,056,"),
-                           None),
+    "wide_2000_frontier": (WIDE, WIDE_PLAN, ("frontier_k", AUTO_K), None),
+    "wide_2000_frontier_k4": (dict(WIDE, **K4), WIDE_PLAN,
+                              ("frontier_k", "a histogram state of 1,056,"),
+                              K4_REFUSED + "(a histogram state of 1,056,"),
     "wide_2000_mega_off": (dict(WIDE, tpu_megakernel="off"), WIDE_PLAN,
                            ("mega", "tpu_megakernel=off"), None),
     "wide_137": (F137, dict(partition="pallas", hist="pallas",
@@ -74,7 +95,7 @@ CASES = {
                             hist_state="flat", pass_rows=160),
                  ("mega", "lgbm_split_mega would hold 18,"), None),
     "wide_124": (dict(F137, F=124, G=124),
-                 dict(search="pallas", mega="pallas", frontier_k=4,
+                 dict(search="pallas", mega="pallas", frontier_k=1,
                       pass_rows=128), ("hist_state", "mega=pallas"), None),
     "wide_pack_rowid": (dict(WIDE, tpu_pack_rowid=True),
                         dict(WIDE_PLAN, pack_rowid=False),
@@ -82,12 +103,20 @@ CASES = {
                         None),
     "rows_2p24_less_1": (dict(rows=(1 << 24) - 1),
                          dict(fast_search=True, search="pallas",
-                              mega="pallas", frontier_k=4),
-                         ("hist_state", "frontier_k=4"), None),
+                              mega="pallas", frontier_k=1),
+                         ("hist_state", "mega=pallas"), None),
+    "rows_2p24_less_1_k4": (dict(rows=(1 << 24) - 1, **K4),
+                            dict(fast_search=True, search="pallas",
+                                 mega="pallas", frontier_k=4),
+                            ("hist_state", "frontier_k=4"), None),
     "rows_2p24": (dict(rows=1 << 24),
                   dict(fast_search=False, search="xla", mega="off",
-                       frontier_k=4),
+                       frontier_k=1),
                   ("fast_search", "rows 16,777,216 >= 2^24"), None),
+    "rows_2p24_k4": (dict(rows=1 << 24, **K4),
+                     dict(fast_search=False, search="xla", mega="off",
+                          frontier_k=4),
+                     ("fast_search", "rows 16,777,216 >= 2^24"), None),
     # the leaf-histogram kernel goes where the partition kernel goes, f32
     # gradients only
     "hist_cpu": (TOY_CPU, dict(partition="xla", hist="xla"),
@@ -104,7 +133,7 @@ CASES = {
                         ("hist_state", "mega=pallas"), None),
     "hist_interpret": (dict(TOY_CPU, interpret=True),
                        dict(partition="pallas", hist="pallas"),
-                       ("frontier_k", "auto is 1 on backend cpu"), None),
+                       ("frontier_k", AUTO_K), None),
     "cpu": (TOY_CPU, dict(partition="xla", fast_search=True, search="xla",
                           mega="off", frontier_k=1, hist_state="xla",
                           row_chunk=2048, chunk_adaptive=True),
@@ -114,11 +143,15 @@ CASES = {
                       dict(partition="pallas", search="pallas",
                            mega="pallas", frontier_k=1, hist_state="xla",
                            chunk_adaptive=False),
-                      ("frontier_k", "auto is 1 on backend cpu"), None),
+                      ("frontier_k", AUTO_K), None),
     "categorical": (dict(SMOKE, has_categorical=True),
                     dict(partition="xla", fast_search=False, search="xla",
-                         mega="off", frontier_k=4),
+                         mega="off", frontier_k=1),
                     ("partition", "categorical features"), None),
+    "categorical_k4": (dict(SMOKE, has_categorical=True, **K4),
+                       dict(partition="xla", fast_search=False,
+                            search="xla", mega="off", frontier_k=4),
+                       ("partition", "categorical features"), None),
     "u16_bins": (dict(SMOKE, host_bin_dtype="uint16", B=300),
                  dict(partition="xla", search="xla", mega="off"),
                  ("partition", "uint16 bins"), None),
@@ -129,7 +162,10 @@ CASES = {
     "monotone": (dict(SMOKE, use_mc=True),
                  dict(partition="pallas", fast_search=False, search="xla",
                       mega="off", frontier_k=1),
-                 ("frontier_k", "monotone constraints"), None),
+                 ("fast_search", "monotone constraints"), None),
+    "monotone_k4": (dict(SMOKE, use_mc=True, **K4), dict(frontier_k=1),
+                    ("frontier_k", "monotone constraints"),
+                    K4_REFUSED + "(monotone constraints)"),
     "cegb_lazy": (dict(SMOKE, has_cegb=True, cegb_lazy=True),
                   dict(partition="xla", fast_search=False, frontier_k=1),
                   ("partition", "cegb_penalty_feature_lazy"), None),
@@ -164,10 +200,15 @@ CASES = {
                  dict(CELL_PLAN, frontier_k=1),
                  ("scatter_groups", "the histogram sync is the plain psum"),
                  None),
+    "dp4_cell_auto_why": (dict(DP4_CHIP, rows=21_000_000,
+                               global_rows=84_000_000),
+                          dict(mega="off", frontier_k=1),
+                          ("frontier_k", AUTO_K), None),
     "dp4_cell_k1_why": (dict(DP4_CHIP, rows=21_000_000,
-                             global_rows=84_000_000),
+                             global_rows=84_000_000, **K4),
                         dict(mega="off", frontier_k=1),
-                        ("frontier_k", "parallel tree learners"), None),
+                        ("frontier_k", "parallel tree learners"),
+                        K4_REFUSED + "(parallel tree learners)"),
     "dp4_small": (dict(DP4_CHIP, rows=500_000, global_rows=2_000_000),
                   dict(partition="pallas", hist="pallas", fast_search=True,
                        search="xla", mega="off", frontier_k=1,
@@ -287,6 +328,25 @@ def test_no_plan_names_a_kernel_over_its_vmem(shape):
         assert off.kernel_plan() == p.kernel_plan()
         assert {k: v for k, v in vars(off).items() if k != "why"} \
             == {k: v for k, v in vars(p).items() if k != "why"}
+
+
+GRID = [(rows, width, bins, mega)
+        for rows in (1_000_000, 10_500_000, 42_000_000)
+        for width in (28, 128, 2000) for bins in (63, 255)
+        for mega in ("auto", "off")]
+
+
+@pytest.mark.parametrize("rows, width, bins, mega", GRID)
+def test_auto_is_the_one_leaf_body_on_the_chip(rows, width, bins, mega):
+    """``tpu_frontier_k=auto`` on a TPU gives the plan of ``=1`` at every
+    shape, field for field; only the reason given differs."""
+    shape = dict(rows=rows, F=width, G=width, B=bins, tpu_megakernel=mega)
+    auto = plan.resolve(facts(**shape))
+    one = plan.resolve(facts(**shape, tpu_frontier_k="1"))
+    assert auto.frontier_k == 1 and auto.unmet == ()
+    assert auto.why.pop("frontier_k") == f"1 ({plan.AUTO_FRONTIER_K})"
+    assert one.why.pop("frontier_k") == "1 (tpu_frontier_k=1)"
+    assert auto == one
 
 
 @pytest.mark.parametrize("spec", ["0", "-3", "bogus"])

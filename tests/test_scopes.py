@@ -512,6 +512,29 @@ def _assert_only_kernel(text, name, phase):
     assert calls and set(calls) == set(kernels)
 
 
+def _assert_kernel_phases(text, kernels, unnamed=None):
+    """Every launch of each of ``kernels`` ({name: phase}) in the compiled
+    text is there and under its phase."""
+    table = scopes.phase_of(text, unnamed)
+    for kernel, phase in kernels.items():
+        found = [n for n in table if n.startswith(kernel)]
+        assert found and {table[n] for n in found} == {phase}, kernel
+
+
+def _lower_step(g, one_chip):
+    """A booster's fused step lowered for one described chip at the
+    learner's own shapes (nothing is placed on the chip)."""
+    lr = g.learner
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return g._fused_phys.lower(
+        sds((lr._pb_rows, lr.N_pad), jnp.uint8),
+        sds((8, lr.N_pad), jnp.float32), sds((lr.F,), jnp.bool_), 1,
+        sds((lr.F,), jnp.bool_))
+
+
 def test_partition_kernel_compiles_for_v5e_under_its_name(one_chip):
     from lightgbm_tpu.ops.partition_pallas import (make_scalars,
                                                    partition_leaf_pallas,
@@ -705,22 +728,78 @@ def test_default_step_at_137_features_compiles_for_v5e(one_chip,
     assert (lr.plan.row_chunk, lr.plan.pass_rows) \
         == (at_size.row_chunk, at_size.pass_rows) == (4096, 160)
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     with _compile_cache_off():
-        text = g._fused_phys.lower(
-            sds((lr._pb_rows, lr.N_pad), jnp.uint8),
-            sds((8, lr.N_pad), jnp.float32), sds((lr.F,), jnp.bool_), 1,
-            sds((lr.F,), jnp.bool_)).compile().as_text()
-    table = scopes.phase_of(text)
-    for kernel, phase in (("lgbm_partition", "partition"),
-                          ("lgbm_histogram", "histogram"),
-                          ("lgbm_split_search", "search"),
-                          ("lgbm_hist_state", "hist_state")):
-        found = [n for n in table if n.startswith(kernel)]
-        assert found and {table[n] for n in found} == {phase}, kernel
+        text = _lower_step(g, one_chip).compile().as_text()
+    _assert_kernel_phases(text, {"lgbm_partition": "partition",
+                                 "lgbm_histogram": "histogram",
+                                 "lgbm_split_search": "search",
+                                 "lgbm_hist_state": "hist_state"})
     assert "lgbm_split_mega" not in text
+
+
+def _split_loop_copies(text):
+    """(the lines of the split loop's body, the ``copy`` instructions the
+    loop runs) of a compiled fused step.  The split loop is the one that
+    launches the partition kernel, with whatever it calls (outside it the
+    compiler may stage a toy buffer into faster memory once a step)."""
+    computations = _computations(text)
+    body = [c for c, lines in computations.items()
+            if any("lgbm_partition" in ln and "custom-call(" in ln
+                   for ln in lines)]
+    assert len(body) == 1
+    return computations[body[0]], [
+        line for name in _reachable(computations, body)
+        for line in computations[name]
+        if re.search(r"[\])}] copy(-start)?\(", line)]
+
+
+def test_default_step_at_the_cells_facts_compiles_for_v5e_without_a_copy(
+        one_chip, monkeypatch):
+    """The step every one-chip HIGGS cell runs since ``tpu_frontier_k=auto``
+    is 1 (PR 36): the plan of (42M, 28, 255), reached at toy rows through
+    path_smooth (the general XLA search, as over 2^24 rows), compiled for
+    the described v5e.  Its split loop launches both row kernels and copies
+    neither the (8, N) payload, nor the bins, nor the (L + 1, G, B, 2)
+    histogram state (the read of the parent's slot, fused into a child's
+    write, once kept the old state alive: two copies a split, PR 35)."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.models import plan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X, y = _toy(n=3000, f=28)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                       "verbosity": -1, "path_smooth": 1.0},
+                      lgb.Dataset(X, label=y))
+    g = bst._gbdt
+    lr = g.learner
+    options = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
+    at_size = plan.resolve(plan.PlanFacts(
+        backend="tpu", rows=42_000_000, F=28, G=28, B=255, num_leaves=255,
+        **options))
+    assert at_size.kernel_plan() == {
+        "partition": "pallas", "hist": "pallas", "search": "xla",
+        "hist_state": "xla", "mega": "off", "frontier_k": 1}
+    assert plan.AUTO_FRONTIER_K in at_size.why["frontier_k"]
+    assert lr.plan.kernel_plan() == at_size.kernel_plan() and lr.B == 255
+    assert (lr.plan.row_chunk, lr.plan.pass_rows, lr.plan.fast_search) \
+        == (at_size.row_chunk, at_size.pass_rows, at_size.fast_search) \
+        == (4096, 32, False)
+    with _compile_cache_off():
+        text = _lower_step(g, one_chip).compile().as_text()
+    state = f"f32[{lr.L + 1},{lr.G},{lr.B},2]"
+    assert state in text
+    loop, copies = _split_loop_copies(text)
+    held = re.compile(
+        rf"(f32\[8,{lr.N_pad}\]|u8\[{lr._pb_rows},{lr.N_pad}\]|"
+        + re.escape(state) + ")")
+    for line in copies:
+        assert not held.search(line.split(" = ")[1]), line[:200]
+    assert any("lgbm_histogram" in ln for ln in loop)
+    _assert_kernel_phases(text, {"lgbm_partition": "partition",
+                                 "lgbm_histogram": "histogram"})
+    calls = {ln.split(" = ")[0].split("%")[-1].split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert calls == {"lgbm_partition", "lgbm_histogram"}
 
 
 def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
@@ -744,15 +823,8 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
     assert (kp["partition"], kp["search"], kp["mega"], kp["frontier_k"]) \
         == ("pallas", "xla", "off", 4)
     Np = lr.N_pad
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     with _compile_cache_off():
-        text = g._fused_phys.lower(
-            sds((lr._pb_rows, Np), jnp.uint8), sds((8, Np), jnp.float32),
-            sds((lr.F,), jnp.bool_), 1,
-            sds((lr.F,), jnp.bool_)).compile().as_text()
+        text = _lower_step(g, one_chip).compile().as_text()
     _, computations, ops = _frontier_loop(text)
     wide_bookkeeping = 0
     for line, phase, typ, dims in ops:
@@ -774,6 +846,76 @@ def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
     assert len(hist) >= 2 and {table[n] for n in hist} == {"histogram"}
 
 
+def _sharded_step(v5e_2x2, monkeypatch, extra=None):
+    """(the booster's GBDT, its fused sharded step lowered for the
+    described four chips) of a toy tree_learner=data training that reaches
+    the four-chip cell's plan through path_smooth.  Nothing is placed on
+    the chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    cpus = jax.devices()[:4]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: cpus)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X, y = _toy(n=1600)                     # 400 rows a chip: an even cut
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 15,
+                       "verbosity": -1, "tree_learner": "data",
+                       "path_smooth": 1.0, **(extra or {})},
+                      lgb.Dataset(X, label=y))
+    g = bst._gbdt
+    sb, lr = g.sharded_builder, g.learner
+    sb.mesh = mesh = Mesh(np.asarray(v5e_2x2.devices), ("data",))
+    g._setup_fused_sharded()
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    return g, g._fused_phys.lower(
+        sds((lr._pb_rows, 4 * lr.N_pad), jnp.uint8, P(None, "data")),
+        sds((8, 4 * lr.N_pad), jnp.float32, P(None, "data")),
+        sds((lr.F,), jnp.bool_, P()), 1, sds((lr.F,), jnp.bool_, P()))
+
+
+def _wide_step(one_chip, monkeypatch, extra=None):
+    """The same for one chip at 300 features, 255 leaves and bins: a
+    histogram state of 158 MB, over plan.FRONTIER_STATE_MAX_BYTES like the
+    wide cell's (whose 2000 features only lengthen the lowering)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    X, y = _toy(n=3000, f=300)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                       "verbosity": -1, **(extra or {})},
+                      lgb.Dataset(X, label=y))
+    return bst._gbdt, _lower_step(bst._gbdt, one_chip)
+
+
+@pytest.mark.parametrize("cell", ["data_parallel", "over_the_state_gate"])
+def test_bypass_cells_lower_the_same_step_with_frontier_k_unset_and_1(
+        cell, v5e_2x2, one_chip, monkeypatch):
+    """The two cells that ran the one-leaf body before ``auto`` became 1
+    (PR 36) lower, for the described v5e, byte for byte the step that an
+    explicit ``tpu_frontier_k=1`` lowers: a data-parallel learner (an
+    explicit K > 1 is refused for its collectives) and a learner whose
+    histogram state is over the gate of the batched body."""
+    from lightgbm_tpu.models import plan
+    def build(extra):
+        if cell == "data_parallel":
+            return _sharded_step(v5e_2x2, monkeypatch, extra)
+        return _wide_step(one_chip, monkeypatch, extra)
+
+    g_auto, auto = build(None)
+    g_one, one = build({"tpu_frontier_k": 1})
+    lr = g_auto.learner
+    assert g_auto.kernel_plan() == g_one.kernel_plan()
+    assert (lr.plan.partition, lr.plan.hist, lr.plan.search, lr.plan.mega,
+            lr.plan.frontier_k) == ("pallas", "pallas", "xla", "off", 1)
+    assert lr.plan.why["frontier_k"] == f"1 ({plan.AUTO_FRONTIER_K})"
+    assert g_one.learner.plan.why["frontier_k"] == "1 (tpu_frontier_k=1)"
+    if cell == "over_the_state_gate":
+        assert (lr.L + 4) * lr.G * lr.B * 8 > plan.FRONTIER_STATE_MAX_BYTES
+    text = auto.as_text()
+    assert "lgbm_partition" in text and "lgbm_histogram" in text
+    assert text == one.as_text()
+
+
 def test_sharded_step_compiles_for_v5e_2x2_with_both_kernels_in_place(
         v5e_2x2, monkeypatch):
     """tree_learner=data on the described four chips: the fused sharded
@@ -784,23 +926,13 @@ def test_sharded_step_compiles_for_v5e_2x2_with_both_kernels_in_place(
     pads to 128 lanes), copies neither the payload nor the bins beside the
     partition's in-place write, and gives its collectives the phase
     hist_sync."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    cpus = jax.devices()[:4]
-    monkeypatch.setattr(jax, "devices", lambda *a, **k: cpus)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    X, y = _toy(n=1600)                     # 400 rows a chip: an even cut
-    bst = lgb.Booster({"objective": "binary", "num_leaves": 15,
-                       "verbosity": -1, "tree_learner": "data",
-                       "path_smooth": 1.0}, lgb.Dataset(X, label=y))
-    g = bst._gbdt
-    sb, lr = g.sharded_builder, g.learner
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    g, lowered = _sharded_step(v5e_2x2, monkeypatch)
+    sb, lr, mesh = g.sharded_builder, g.learner, g.sharded_builder.mesh
     kp = g.kernel_plan()
     assert (kp["partition"], kp["hist"], kp["search"], kp["frontier_k"],
             kp["tree_learner"]) == ("pallas", "pallas", "xla", 1, "data")
     assert not sb.interpreted_kernels
-    # the same step over the described chips (nothing is placed on them)
-    sb.mesh = mesh = Mesh(np.asarray(v5e_2x2.devices), ("data",))
-    g._setup_fused_sharded()
     Np = lr.N_pad
 
     def sds(shape, dtype, spec):
@@ -808,40 +940,20 @@ def test_sharded_step_compiles_for_v5e_2x2_with_both_kernels_in_place(
                                     sharding=NamedSharding(mesh, spec))
 
     with _compile_cache_off():
-        text = g._fused_phys.lower(
-            sds((lr._pb_rows, 4 * Np), jnp.uint8, P(None, "data")),
-            sds((8, 4 * Np), jnp.float32, P(None, "data")),
-            sds((lr.F,), jnp.bool_, P()), 1,
-            sds((lr.F,), jnp.bool_, P())).compile().as_text()
+        text = lowered.compile().as_text()
         # the read of an even cut: each chip folds its own rows
         read = g._scores_read_sharded.lower(
             sds((8, 4 * Np), jnp.float32, P(None, "data"))
         ).compile().as_text()
     assert "all-" not in read and f"f32[{sb.local_n}]" in read \
         and f"[{4 * sb.local_n}]" not in read
-    table = scopes.phase_of(text, "hist_sync")
-    for kernel, phase in (("lgbm_partition", "partition"),
-                          ("lgbm_histogram", "histogram")):
-        found = [n for n in table if n.startswith(kernel)]
-        assert found and {table[n] for n in found} == {phase}, kernel
-    sync = [n for n in table if n.startswith("all-reduce")]
-    assert sync and {table[n] for n in sync} == {"hist_sync"}
+    _assert_kernel_phases(text, {"lgbm_partition": "partition",
+                                 "lgbm_histogram": "histogram",
+                                 "all-reduce": "hist_sync"}, "hist_sync")
     for d0, d1 in re.findall(r"u8\[(\d+),(\d+)\]", text):
         assert int(d0) * int(d1) < sb.local_n or int(d1) == Np, (d0, d1)
-    # the split loop: the one that launches the partition kernel, and
-    # whatever it calls (outside it the compiler may stage a toy buffer
-    # into faster memory once a step)
-    computations = _computations(text)
-    body = [c for c, lines in computations.items()
-            if any("lgbm_partition" in ln and "custom-call(" in ln
-                   for ln in lines)]
-    assert len(body) == 1
-    copies = 0
-    for name in _reachable(computations, body):
-        for line in computations[name]:
-            if re.search(r"[\])}] copy(-start)?\(", line):
-                copies += 1
-                assert not re.search(
-                    rf"(f32\[8|u8\[{lr._pb_rows}),{Np}\]",
-                    line.split(" = ")[1]), line[:200]
-    assert any("lgbm_histogram" in ln for ln in computations[body[0]])
+    loop, copies = _split_loop_copies(text)
+    for line in copies:
+        assert not re.search(rf"(f32\[8|u8\[{lr._pb_rows}),{Np}\]",
+                             line.split(" = ")[1]), line[:200]
+    assert any("lgbm_histogram" in ln for ln in loop)
