@@ -424,7 +424,8 @@ def check_telemetry_off() -> Dict[str, int]:
         g = bst._gbdt
         assert g._fused_phys is not None, \
             "telemetry.off budget needs the fused physical step"
-        pb, ghi = g._init_phys(g.learner._part0, g.scores)
+        pb, ghi = g._init_phys(g.learner._part0, g.scores,
+                                 *g._phys_payloads)
         fmask = jnp.ones((g.learner.F,), dtype=bool)
         feat_used = jnp.zeros((g.learner.F,), dtype=bool)
         lowered = g._fused_phys.lower(pb, ghi, fmask, jnp.int32(1),
@@ -501,7 +502,8 @@ def check_health_off() -> Dict[str, int]:
         g = bst._gbdt
         assert g._fused_phys is not None, \
             "health.off budget needs the fused physical step"
-        pb, ghi = g._init_phys(g.learner._part0, g.scores)
+        pb, ghi = g._init_phys(g.learner._part0, g.scores,
+                                 *g._phys_payloads)
         fmask = jnp.ones((g.learner.F,), dtype=bool)
         feat_used = jnp.zeros((g.learner.F,), dtype=bool)
         lowered = g._fused_phys.lower(pb, ghi, fmask, jnp.int32(1),
@@ -559,7 +561,8 @@ def check_perfwatch_off() -> Dict[str, int]:
         g = bst._gbdt
         assert g._fused_phys is not None, \
             "perfwatch.off budget needs the fused physical step"
-        pb, ghi = g._init_phys(g.learner._part0, g.scores)
+        pb, ghi = g._init_phys(g.learner._part0, g.scores,
+                                 *g._phys_payloads)
         fmask = jnp.ones((g.learner.F,), dtype=bool)
         feat_used = jnp.zeros((g.learner.F,), dtype=bool)
         lowered = g._fused_phys.lower(pb, ghi, fmask, jnp.int32(1),

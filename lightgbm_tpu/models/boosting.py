@@ -373,6 +373,8 @@ class GBDT:
         self._init_phys_fn = None
         self._init_phys_adopt = None
         self._init_phys_perm = None
+        # the objective's payload rows, the layout programs' last arguments
+        self._phys_payloads = ()
         self._scores_arr = None
         self._scores_read_sharded = None
         # programs whose executable obs/scopes.py already holds for this
@@ -877,23 +879,26 @@ class GBDT:
         tg_row = 4 + len(names)
         th_row = tg_row + 1
         lr_._ghi_live = 4 + len(names) + q_renew_rows
-        payload_arrs = [jnp.asarray(getattr(obj, n), jnp.float32)
-                        for n in names]
+        # the objective's rows reach both layout programs as arguments:
+        # closed over, each would lower as an N-element constant that the
+        # compiler digests and the cache key holds (168 MB at 42M rows)
+        self._phys_payloads = tuple(jnp.asarray(getattr(obj, n), jnp.float32)
+                                    for n in names)
 
-        def ghi0(scores):
+        def ghi0(scores, payloads):
             iota = jax.lax.iota(jnp.int32, Npad)
             rowid = jnp.where((iota >= C) & (iota < C + N), iota - C, N)
             rows = [jnp.zeros((Npad,), jnp.float32),
                     jnp.zeros((Npad,), jnp.float32),
                     jax.lax.bitcast_convert_type(rowid, jnp.float32),
                     jnp.pad(scores, (C, Npad - C - N))]
-            rows += [jnp.pad(a, (C, Npad - C - N)) for a in payload_arrs]
+            rows += [jnp.pad(a, (C, Npad - C - N)) for a in payloads]
             rows += [jnp.zeros((Npad,), jnp.float32)
                      for _ in range(lr_._ghi_rows - len(rows))]
             return jnp.stack(rows)
 
         @scopes.phase("layout_init")
-        def lgbm_layout_init(part_bins, scores):
+        def lgbm_layout_init(part_bins, scores, *payloads):
             # the bins pass through UNTOUCHED; with the bins argument
             # DONATED, XLA aliases the output onto the input buffer, so
             # the physical carrier ADOPTS the learner's master buffer
@@ -901,16 +906,16 @@ class GBDT:
             # _adopt_master_buffer retires every other reference right
             # after.  The non-donating jit keeps the pre-adoption
             # semantics for lowering-only probes (jaxlint).
-            return part_bins, ghi0(scores)
+            return part_bins, ghi0(scores, payloads)
 
         @scopes.phase("layout_init")
-        def lgbm_layout_resume(part_bins, rowid_bits, scores):
+        def lgbm_layout_resume(part_bins, rowid_bits, scores, *payloads):
             # resume from a RETIRED carrier (scores were read between
             # iterations): unpermute the bins back to the identity
             # layout, so the rebuilt state — and every tree after it —
             # is bit-identical to an init from the pristine buffer
             bins = _unpermute_bins(part_bins, rowid_bits, N, C, Npad)
-            return bins, ghi0(scores)
+            return bins, ghi0(scores, payloads)
 
         self._init_phys = jax.jit(lgbm_layout_init)
         self._init_phys_adopt = jax.jit(lgbm_layout_init, donate_argnums=(0,))
@@ -1144,10 +1149,13 @@ class GBDT:
         lr_._ghi_live = need
         lbl_row = 3 + K
         w_row = lbl_row + 1
-        label_arr = jnp.asarray(obj.label, jnp.float32)
-        weight_arr = obj.weight
+        # label [and weight] rows: arguments of the layout programs, as
+        # in _setup_fused_phys
+        self._phys_payloads = tuple(
+            jnp.asarray(a, jnp.float32)
+            for a in ((obj.label, obj.weight) if has_w else (obj.label,)))
 
-        def ghi0(scores):
+        def ghi0(scores, payloads):
             iota = jax.lax.iota(jnp.int32, Npad)
             rowid = jnp.where((iota >= C) & (iota < C + N), iota - C, N)
             ghi = jnp.zeros((lr_._ghi_rows, Npad), jnp.float32)
@@ -1156,24 +1164,22 @@ class GBDT:
             for k in range(K):
                 ghi = ghi.at[3 + k].set(
                     jnp.pad(scores[:, k], (C, Npad - C - N)))
-            ghi = ghi.at[lbl_row].set(jnp.pad(label_arr, (C, Npad - C - N)))
-            if has_w:
-                ghi = ghi.at[w_row].set(
-                    jnp.pad(weight_arr, (C, Npad - C - N)))
+            for i, a in enumerate(payloads):
+                ghi = ghi.at[lbl_row + i].set(jnp.pad(a, (C, Npad - C - N)))
             return ghi
 
         @scopes.phase("layout_init")
-        def lgbm_layout_init(part_bins, scores):
+        def lgbm_layout_init(part_bins, scores, *payloads):
             # bins pass through untouched; donated in the _adopt
             # variant so the carrier adopts the master buffer (see
             # _setup_fused_phys / single-copy residency);
             # _adopt_master_buffer retires the other refs
-            return part_bins, ghi0(scores)
+            return part_bins, ghi0(scores, payloads)
 
         @scopes.phase("layout_init")
-        def lgbm_layout_resume(part_bins, rowid_bits, scores):
+        def lgbm_layout_resume(part_bins, rowid_bits, scores, *payloads):
             bins = _unpermute_bins(part_bins, rowid_bits, N, C, Npad)
-            return bins, ghi0(scores)
+            return bins, ghi0(scores, payloads)
 
         self._init_phys = jax.jit(lgbm_layout_init)
         self._init_phys_adopt = jax.jit(lgbm_layout_init, donate_argnums=(0,))
@@ -1476,14 +1482,16 @@ class GBDT:
             pb, rowid_bits = self._phys_carrier
             self._phys_carrier = None
             self._phys = tuple(self._init_phys_perm(
-                pb, rowid_bits, self._scores_arr))
+                pb, rowid_bits, self._scores_arr, *self._phys_payloads))
             self._register_once("train.layout_init", self._init_phys_perm,
-                                self._phys[0], rowid_bits, self._scores_arr)
+                                self._phys[0], rowid_bits, self._scores_arr,
+                                *self._phys_payloads)
         else:
             self._phys = tuple(self._init_phys_adopt(
-                self.learner._part0, self._scores_arr))
+                self.learner._part0, self._scores_arr, *self._phys_payloads))
             self._register_once("train.layout_init", self._init_phys_adopt,
-                                self._phys[0], self._scores_arr)
+                                self._phys[0], self._scores_arr,
+                                *self._phys_payloads)
             # the donating identity init aliased the master
             # buffer into the carrier; retire the (now stale)
             # learner/ingest references

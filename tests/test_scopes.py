@@ -11,7 +11,9 @@ device_phase.py).
   the search and the partition;
 * the benchmark's reader on a synthetic ``op_seconds``;
 * the Pallas partition and histogram kernels compile for ``v5e:2x2`` under
-  their own names, and the toy step's table gives them their phases.
+  their own names, and the toy step's table gives them their phases;
+* the one-chip layout programs compile for ``v5e:2x2`` with no row folded
+  into a constant.
 """
 
 import contextlib
@@ -135,7 +137,7 @@ def _step_text(bst):
     """StableHLO of the booster's fused step without locations: named
     scopes live in locations only."""
     g = bst._gbdt
-    pb, ghi = g._init_phys(g.learner._part0, g.scores)
+    pb, ghi = g._init_phys(g.learner._part0, g.scores, *g._phys_payloads)
     feat_used = jnp.zeros((g.learner.F,), bool)
     return g._fused_phys.lower(pb, ghi, g._feature_mask(0), 1,
                                feat_used).as_text()
@@ -327,7 +329,7 @@ def test_frontier_loop_carries_one_snapshot_row(monkeypatch):
     g = bst._gbdt
     Np = g.learner.N_pad
     assert g.learner.plan.frontier_k == K and Np > 4 * 128
-    pb, ghi = g._init_phys(g.learner._part0, g.scores)
+    pb, ghi = g._init_phys(g.learner._part0, g.scores, *g._phys_payloads)
     text = g._fused_phys.lower(
         pb, ghi, g._feature_mask(0), 1,
         jnp.zeros((g.learner.F,), bool)).compile().as_text()
@@ -735,6 +737,37 @@ def test_default_step_at_137_features_compiles_for_v5e(one_chip,
                                  "lgbm_split_search": "search",
                                  "lgbm_hist_state": "hist_state"})
     assert "lgbm_split_mega" not in text
+
+
+_CONSTANT = re.compile(r"= [a-z]+[0-9]*\[([0-9,]*)\]\S* constant\(")
+
+
+def test_layout_programs_compile_for_v5e_without_a_row_constant(one_chip):
+    """The one-chip layout programs take the objective's payload rows as
+    arguments, and XLA:TPU folds none of their rows into a constant: not
+    the row ids (an iota masked by the static row bounds), not the padded
+    payload.  A row closed over compiled in as an ``N_pad``-element
+    constant (168 MB a program at 42M rows)."""
+    X, y = _toy(n=3000, f=28)
+    g = lgb.Booster({"objective": "binary", "num_leaves": 15,
+                     "verbosity": -1}, lgb.Dataset(X, label=y))._gbdt
+    lr = g.learner
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bins = sds((lr._pb_rows, lr.N_pad), jnp.uint8)
+    rows = [sds((3000,), jnp.float32) for _ in range(1 + len(
+        g._phys_payloads))]
+    with _compile_cache_off():
+        texts = [g._init_phys_adopt.lower(bins, *rows).compile().as_text(),
+                 g._init_phys_perm.lower(
+                     bins, sds((lr.N_pad,), jnp.float32), *rows)
+                 .compile().as_text()]
+    for text in texts:
+        assert " iota(" in text
+        sizes = [_elements(d) for d in _CONSTANT.findall(text)]
+        assert sizes and max(sizes) <= 256, max(sizes)
 
 
 def _split_loop_copies(text):
