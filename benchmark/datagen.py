@@ -1,5 +1,9 @@
 """The benchmark's own data: a binary-label table made from `--seed`.
 
+The default generator of a configuration: one that names another
+(`"generator": "<stem>"`) brings `generators/<stem>.py` with the same
+`make` (run.py).
+
 `features` standard-normal f32 columns, a linear logit (x . w) * 0.5 plus
 unit normal noise, label = logit > 0 (the shape of `bench.py`'s generator).
 Rows are made in chunks straight into one f32 array, so no f64 copy of the
@@ -48,3 +52,11 @@ def make_table(seed, rows, features, threads=None):
     with ThreadPoolExecutor(threads) as pool:
         list(pool.map(fill, chunks))
     return X, y
+
+
+def make(seed, rows, holdout, cfg):
+    """(X, y, dataset_kwargs): `rows` training rows then `holdout` held-out
+    ones, and the keyword arguments of `lgb.Dataset` beyond the label (none
+    for a table of plain numerical columns)."""
+    X, y = make_table(seed, rows + holdout, cfg["features"])
+    return X, y, {}

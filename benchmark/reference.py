@@ -17,6 +17,11 @@ against it computes itself, from the raw f32 table and the labels:
 * the training scores the program ends with are compared row by row with the
   reference's.
 
+`check` does all of it for run.py.  This is the default reference of a
+configuration, for the binary objective on numerical splits: one that names
+another (`"reference": "<stem>"`) brings `references/<stem>.py` with the same
+`check`, and may import this module's pieces and replace what differs.
+
 Routing is one matrix product per block of rows: with d[a] = +1/-1 for the
 test at internal node a, and P[a, l] = +1/-1/0 for leaf l lying left of,
 right of, or not under a, a row is in leaf l exactly when (d @ P)[l] equals
@@ -238,6 +243,46 @@ def follow(leaf_of, y, trees, params, recompute, sum_dtype=np.float64):
     numbers = {"leaf_count_gap": count_gap, "leaf_value_gap": value_gap,
                "leaf_value_median_gap": value_mgap}
     return numbers, scores, values_out, notes
+
+
+def check(model_text, X, y, rows, prog_scores, params, wl, seed, iterations):
+    """What the window produced, against the plain reference.
+
+    `X`, `y`: the table as generated, the `rows` training rows first, then
+    the held-out ones; `prog_scores`: the program's training scores after
+    the window; `wl`: the workload's file; `iterations`: the trees the
+    model should hold.  Returns (numbers, heldout, trees, notes): the
+    numbers the workload's limits may name, the held-out loss in nats at
+    `heldout_trees` trees, the parsed trees and a line per recomputed
+    tree."""
+    trees = parse_model(model_text)
+    Xt, yt, yh = X[:rows], y[:rows], y[rows:]
+    # the first `recompute_trees` trees: every row, training and held-out,
+    # is routed, and the reference follows the boosting loop through them
+    head = max(wl["recompute_trees"], wl["heldout_trees"])
+    leaf_of = route(X, trees[:head])
+    numbers, ref_scores, _, notes = follow(
+        leaf_of[:, :rows], yt, trees[:head], params, wl["recompute_trees"])
+    # the later trees: a sample of rows drawn from the seed is routed on
+    # through them, and the program's final scores are held to the
+    # reference's on those rows
+    sample = sample_rows(seed, rows, wl["score_sample_rows"])
+    ref_final = ref_scores[sample]
+    if len(trees) > head:
+        tail = route(Xt[sample], trees[head:])
+        ref_final = ref_final + predict_raw(tail, trees[head:])
+    numbers["leaf_count_sum_gap"] = max(
+        abs(int(t.leaf_count.sum()) - rows) for t in trees)
+    numbers["trees_missing"] = abs(iterations - len(trees))
+    whole = len(prog_scores) == rows and numbers["trees_missing"] == 0
+    diff = (np.abs(prog_scores[sample] - ref_final) if whole
+            else np.array([1e30]))
+    numbers["train_score_gap"] = float(diff.max())
+    numbers["train_score_median_gap"] = float(np.median(diff))
+    cut = wl["heldout_trees"]
+    heldout = (logloss(yh, predict_raw(leaf_of[:cut, rows:], trees[:cut]))
+               if len(trees) >= cut else 1e30)
+    return numbers, heldout, trees, notes
 
 
 def _leaf_sums(leaf, v, L, dtype):

@@ -7,15 +7,19 @@ tests, shrinks the rows and allows `cpu`; its line says `platform: cpu`).
 Makes the table from `--seed`, constructs the dataset, builds the booster,
 warms up, measures a window of `Booster.update()` calls closed by one
 barrier, then holds what the window produced against the plain reference
-(reference.py) and prints one JSON object as the last line of standard
-output, after checking it against the contract (contract.py).
+and prints one JSON object as the last line of standard output, after
+checking it against the contract (contract.py).
 
 The cell is found by name: `workloads/<name>.json` beside this file names
 its configuration (`configs/<name>.json`), rows, chips and tree learner;
 the metrics it reports are those `BENCHMARK.json` lists for it, each
 per-layer one read by the reader its `metrics/<name>.json` names
-(`readers/<reader>.py`).  Nothing here names a cell, a configuration or a
-metric.
+(`readers/<reader>.py`).  The configuration's table comes from
+`generators/<stem>.py`'s `make` where its file names a `"generator"`, else
+from datagen.py's; what the window produced is held by
+`references/<stem>.py`'s `check` where it names a `"reference"`, else by
+reference.py's.  Nothing here names a cell, a configuration, a metric, an
+objective or a table.
 """
 
 import time
@@ -51,6 +55,13 @@ def say(msg):
 def load_json(*parts):
     with open(os.path.join(*parts)) as f:
         return json.load(f)
+
+
+def load_module(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class CompileLog:
@@ -93,6 +104,20 @@ class Cell:
             if self.workload[key] != self.entry[key]:
                 sys.exit(f"benchmark: {name}: {key} differs between "
                          "BENCHMARK.json and the workload's file")
+        self.generator = self.own("generator", "generators", datagen)
+        self.reference = self.own("reference", "references", reference)
+
+    def own(self, key, folder, default):
+        """The module the configuration's `key` names under `folder`, or
+        `default` where it names none."""
+        if key not in self.config:
+            return default
+        stem = self.config[key]
+        path = os.path.join(self.data, folder, stem + ".py")
+        if not os.path.isfile(path):
+            sys.exit(f"benchmark: configuration {self.entry['config']!r} "
+                     f"names {key} {stem!r}, and there is no {path}")
+        return load_module(path, f"{key}_{stem}")
 
     def metrics(self, group):
         """{name: entry} of the `end_to_end` or `per_layer` metrics that
@@ -107,11 +132,9 @@ class Cell:
     def read(self, name, ctx):
         """A per-layer metric through the reader its own file names."""
         spec = load_json(self.data, "metrics", name + ".json")
-        path = os.path.join(self.data, "readers", spec["reader"] + ".py")
-        mod_spec = importlib.util.spec_from_file_location(
-            "reader_" + spec["reader"], path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
+        mod = load_module(
+            os.path.join(self.data, "readers", spec["reader"] + ".py"),
+            "reader_" + spec["reader"])
         return mod.read(ctx, **spec.get("args", {}))
 
 
@@ -241,12 +264,11 @@ def main(argv=None):
         say(f"{name}_s={spans[name]:.3f}")
         return out
 
-    X, y = spanned("generate", lambda: datagen.make_table(
-        args.seed, rows + holdout, cfg["features"]))
-    Xt, yt, yh = X[:rows], y[:rows], y[rows:]
+    X, y, dataset_kwargs = spanned("generate", lambda: cell.generator.make(
+        args.seed, rows, holdout, cfg))
 
     def construct():
-        ds = lgb.Dataset(Xt, label=yt)
+        ds = lgb.Dataset(X[:rows], label=y[:rows], **dataset_kwargs)
         ds.construct(params)
         return ds
 
@@ -295,40 +317,16 @@ def main(argv=None):
 
     # what the window produced, against the plain reference
     t_check = time.time()
-    trees = reference.parse_model(model_text)
-    # the first `recompute_trees` trees: every row, training and held-out,
-    # is routed, and the reference follows the boosting loop through them
-    head = max(wl["recompute_trees"], wl["heldout_trees"])
-    leaf_of = reference.route(X, trees[:head])
-    numbers, ref_scores, _, notes = reference.follow(
-        leaf_of[:, :rows], yt, trees[:head], params, wl["recompute_trees"])
+    numbers, heldout, trees, notes = cell.reference.check(
+        model_text, X, y, rows, prog_scores, params, wl, args.seed,
+        warm + n - failed)
     for note in notes:
         say(note)
-    # the later trees: a sample of rows drawn from the seed is routed on
-    # through them, and the program's final scores are held to the
-    # reference's on those rows
-    sample = reference.sample_rows(args.seed, rows, wl["score_sample_rows"])
-    ref_final = ref_scores[sample]
-    if len(trees) > head:
-        tail = reference.route(Xt[sample], trees[head:])
-        ref_final = ref_final + reference.predict_raw(tail, trees[head:])
-    numbers["leaf_count_sum_gap"] = max(
-        abs(int(t.leaf_count.sum()) - rows) for t in trees)
-    numbers["trees_missing"] = abs(warm + n - failed - len(trees))
-    whole = len(prog_scores) == rows and numbers["trees_missing"] == 0
-    diff = (np.abs(prog_scores[sample] - ref_final) if whole
-            else np.array([1e30]))
-    numbers["train_score_gap"] = float(diff.max())
-    numbers["train_score_median_gap"] = float(np.median(diff))
     say("numbers: " + " ".join(f"{k}={v}" for k, v in numbers.items()))
     limits = wl["limits"]
     compared = {k: {"value": numbers[k], "limit": limits[k]}
                 for k in limits}
     correct = bool(all(v["value"] <= v["limit"] for v in compared.values()))
-    cut = wl["heldout_trees"]
-    heldout = (reference.logloss(
-        yh, reference.predict_raw(leaf_of[:cut, rows:], trees[:cut]))
-        if len(trees) >= cut else 1e30)
     say(f"check_s={time.time() - t_check:.1f}")
 
     line = {"correct": correct, "attempted": n, "failed": failed}

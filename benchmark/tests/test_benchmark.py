@@ -254,7 +254,10 @@ def test_reference_passes_itself_and_the_bf16_control_fails():
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("workload", CELLS)
 def test_rehearsal_ends_in_a_line_the_validator_accepts(workload, trace):
-    rc, last, out = rehearse(workload, trace, extra=("--rehearse",))
+    chips = json.load(open(os.path.join(
+        BENCH, "workloads", workload + ".json")))["chips"]
+    rc, last, out = rehearse(workload, trace, devices=chips,
+                             extra=("--rehearse",))
     assert rc == 0, out[-3000:]
     line = json.loads(last)
     assert contract.problems(
@@ -272,10 +275,42 @@ def test_without_an_accelerator_there_is_no_line():
     assert rc != 0 and not last.startswith("{"), out[-2000:]
 
 
-def grown_root(tmp_path, chips=1, learner="serial"):
+ADDED_TABLE = """\
+import datagen
+
+
+def make(seed, rows, holdout, cfg):
+    X, y = datagen.make_table(seed, rows + holdout, cfg["features"])
+    names = [f"added_col_{i}" for i in range(cfg["features"])]
+    return X, y, {"feature_name": names}
+"""
+
+ADDED_REFERENCE = """\
+import reference
+
+parse_model = reference.parse_model
+
+
+def check(model_text, X, y, rows, prog_scores, params, wl, seed, iterations):
+    numbers, heldout, trees, notes = reference.check(
+        model_text, X, y, rows, prog_scores, params, wl, seed, iterations)
+    line = [s for s in model_text.splitlines()
+            if s.startswith("feature_names=")]
+    given = line[0].partition("=")[2].split() if line else []
+    want = [f"added_col_{i}" for i in range(X.shape[1])]
+    numbers["feature_names_missing"] = len(set(want) - set(given))
+    return numbers, heldout, trees, notes
+"""
+
+
+def grown_root(tmp_path, chips=1, learner="serial", own=None):
     """A copy of the benchmark in which a cell, a configuration, a metric,
     a reader and a kernel file are ADDED as files, with their entries in
-    BENCHMARK.json, and nothing that was there is edited."""
+    BENCHMARK.json, and nothing that was there is edited.  With `own`
+    ({"generator": stem, "reference": stem}) the configuration names a
+    table generator and a reference of its own; a stem whose source is in
+    ADDED_TABLE or ADDED_REFERENCE is added as a file, any other names a
+    module that is not there."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmark", ignore=shutil.ignore_patterns(
         ".trace", "__pycache__", "tests"))
@@ -283,10 +318,20 @@ def grown_root(tmp_path, chips=1, learner="serial"):
     cfg = json.load(open(b / "configs" / "higgs-l255-b63.json"))
     cfg["name"] = "added-l31"
     cfg["params"].update(num_leaves=31, max_bin=31)
-    json.dump(cfg, open(b / "configs" / "added-l31.json", "w"))
     wl = json.load(open(b / "workloads" / (CELLS[0] + ".json")))
     wl.update(name="added-l31.rows", config="added-l31", chips=chips,
               tree_learner=learner, rehearse_rows=20000)
+    sources = {("generators", "added_table"): ADDED_TABLE,
+               ("references", "added_reference"): ADDED_REFERENCE}
+    for key, stem in (own or {}).items():
+        folder = key + "s"
+        cfg[key] = stem
+        if (folder, stem) in sources:
+            (b / folder).mkdir(exist_ok=True)
+            (b / folder / (stem + ".py")).write_text(sources[folder, stem])
+    if (own or {}).get("reference") == "added_reference":
+        wl["limits"]["feature_names_missing"] = 0
+    json.dump(cfg, open(b / "configs" / "added-l31.json", "w"))
     json.dump(wl, open(b / "workloads" / "added-l31.rows.json", "w"))
     json.dump({"match": ["dot|fusion"], "except": []},
               open(b / "kernels" / "added.json", "w"))
@@ -328,6 +373,33 @@ def test_added_files_are_found_without_an_edit(tmp_path, trace):
     assert set(line["metrics"]) == set(want)
     assert ("added_ms" in line["metrics"]) == bool(trace)
     assert line["correct"] is True, line["compared"]
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_configurations_own_table_and_reference_are_found(tmp_path, trace):
+    """The generator's Dataset argument reaches the program (the feature
+    names in its model text), and the reference's own number is compared."""
+    root, spec = grown_root(tmp_path, own={"generator": "added_table",
+                                           "reference": "added_reference"})
+    rc, last, out = rehearse("added-l31.rows", trace, root=root,
+                             extra=("--rehearse",))
+    assert rc == 0, out[-3000:]
+    line = json.loads(last)
+    assert contract.problems(
+        {k: v for k, v in line.items() if k != "compared"},
+        cell_metrics(spec, "added-l31.rows", trace), bool(trace)) == []
+    assert line["compared"]["feature_names_missing"] == {"value": 0,
+                                                         "limit": 0}
+    assert line["correct"] is True, line["compared"]
+
+
+@pytest.mark.parametrize("key", ["generator", "reference"])
+def test_a_module_that_is_not_there_gives_no_line(tmp_path, key):
+    root, _ = grown_root(tmp_path, own={key: "not_there"})
+    rc, last, out = rehearse("added-l31.rows", 0, root=root,
+                             extra=("--rehearse",))
+    assert rc != 0 and not last.startswith("{"), out[-2000:]
+    assert "not_there" in out
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -49,7 +49,8 @@ def test_the_cell_is_the_published_width_on_one_chip():
     assert listed == {"partition_s_per_iter", "partition_roofline",
                       "row_pass_s_per_iter", "histogram_s_per_iter",
                       "split_search_s_per_iter", "device_unattributed_share",
-                      "histogram_roofline"}
+                      "histogram_roofline", "step_build_s", "layout_build_s",
+                      "construct_mappers_s", "construct_binning_s"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
