@@ -1610,6 +1610,7 @@ class GBDT:
         self._health_record_tree(host_record, num_nodes)
         self._telemetry_chunk_waste(host_record, num_nodes)
         self._telemetry_hist_sync(num_nodes)
+        self._telemetry_categorical(host_record, num_nodes)
         self.models.append(tree)
         self.device_trees.append({
             "nodes": nodes, "leaf_value": delta_leaf,
@@ -1677,6 +1678,21 @@ class GBDT:
         obs.counter("train.parallel.hist_sync_bytes",
                     (num_nodes + 1) * lr.G * lr.B * 2 * 4)
         obs.gauge("train.parallel.shards", sb.ndev)
+
+    # -- categorical splits (obs/telemetry.py) --------------------------
+    def _telemetry_categorical(self, host_record, num_nodes: int) -> None:
+        """``train.split.categorical``: the tree's categorical splits;
+        ``train.split.categorical_members``: the sum of their category
+        sets' sizes (bins sent left).  Host arithmetic on the record the
+        trainer already transferred; a table with no categorical feature
+        carries no set and counts nothing."""
+        if obs.get().mode == "off" or "node_cat_set" not in host_record:
+            return
+        is_cat = np.asarray(host_record["node_is_cat"])[:num_nodes]
+        sets = np.asarray(host_record["node_cat_set"])[:num_nodes]
+        obs.counter("train.split.categorical", int(is_cat.sum()))
+        obs.counter("train.split.categorical_members",
+                    int(sets[is_cat].sum()))
 
     # -- chunk-policy padding-waste gauges (obs/telemetry.py) -----------
     def _telemetry_chunk_waste(self, host_record, num_nodes: int) -> None:
@@ -2268,6 +2284,7 @@ class GBDT:
             self._health_record_tree(host_record, num_nodes)
             self._telemetry_chunk_waste(host_record, num_nodes)
             self._telemetry_hist_sync(num_nodes)
+            self._telemetry_categorical(host_record, num_nodes)
             self.models.append(tree)
             self.device_trees.append({
                 "nodes": nodes, "leaf_value": delta_leaf,

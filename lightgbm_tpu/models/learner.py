@@ -966,20 +966,25 @@ class SerialTreeLearner:
 
     @scopes.phase("partition")
     def _partition_leaf_pallas(self, st, start, cnt, col, decision_scalars):
-        """Pallas-kernel leaf partition (see ops/partition_pallas.py):
-        bit-identical layout to the XLA path above at ~30x lower cost on
-        this stack."""
+        """Pallas-kernel leaf partition (see ops/partition_pallas.py) of
+        numerical and categorical splits: the same lefts as the XLA path
+        above, bit for bit, and the same rows on the right."""
         from ..ops.partition_pallas import (partition_leaf_pallas,
                                             make_scalars)
         bstart, isb, nb, dbin, mtype, thr, dl, is_cat, cat_set = \
             decision_scalars
+        # a categorical split's set rides as CAT_WORDS scalars: the
+        # kernel's categorical arm is compiled only where a feature is
+        # categorical, so numerical tables lower as before it existed
+        cat = self.has_categorical
         scalars = make_scalars(start, cnt, col, bstart, isb, nb, dbin,
-                               mtype, thr, dl)
+                               mtype, thr, dl, is_cat if cat else False,
+                               cat_set if cat else None)
         pb, pg, sp, nl = partition_leaf_pallas(
             st["part_bins"], st["part_ghi"], st["sc_packed"],
             scalars, row_chunk=self.row_chunk, ghi_live=self._ghi_live,
             pack_rowid=self.plan.pack_rowid, pass_rows=self.plan.pass_rows,
-            interpret=self._interp)
+            categorical=cat, interpret=self._interp)
         moved = {"part_bins": pb, "part_ghi": pg, "sc_packed": sp}
         return moved, nl[0, 0]
 

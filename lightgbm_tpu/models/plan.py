@@ -183,8 +183,10 @@ def resolve(f: PlanFacts) -> SplitPlan:
         f.tpu_chunk_policy, f.tpu_row_chunk, f.rows, f.num_leaves,
         eligible=False)
 
-    # ---- Pallas partition kernel (ops/partition_pallas.py): it moves
-    # the bins ``pass_rows`` sublanes a pass, as many as fit its VMEM ----
+    # ---- Pallas partition kernel (ops/partition_pallas.py): it decides
+    # numerical splits and category sets (its categorical arm compiled
+    # where a feature is categorical) and moves the bins ``pass_rows``
+    # sublanes a pass, as many as fit its VMEM ----
     pass_rows = partition_pallas.pass_rows_for(
         f.G, row_chunk, VMEM_LIMIT_BYTES)
     block = _given(
@@ -192,8 +194,6 @@ def resolve(f: PlanFacts) -> SplitPlan:
          f"tpu_partition_kernel={f.tpu_partition_kernel}"),
         (f.backend != "tpu" and not f.interpret,
          f"backend {f.backend} without tpu_kernel_interpret"),
-        (f.has_categorical,
-         "categorical features: the kernel decides numerical splits only"),
         (f.cegb_lazy, "cegb_penalty_feature_lazy: the kernel does not "
                       "carry its per-row bitset"),
         (f.parallel_mode in ("feature", "voting"),

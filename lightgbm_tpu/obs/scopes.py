@@ -37,6 +37,7 @@ __all__ = ["PHASES", "PROGRAMS", "phase", "scope", "phase_of", "register",
 PHASES = (
     "gradients", "sampling", "quantize",          # row passes before the tree
     "histogram", "hist_state", "search",          # per split
+    "search_cat",                                 # its categorical branch
     "partition", "split_mega", "bookkeeping",
     "hist_sync",                                  # collectives of the tree
     "leaf_renew", "score_update",                 # row passes after the tree

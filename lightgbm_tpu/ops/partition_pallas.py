@@ -80,7 +80,10 @@ S_DBIN = 7      # feature default bin
 S_MTYPE = 8     # missing type (0 none / 1 zero / 2 nan)
 S_THR = 9       # split threshold (bin)
 S_DL = 10       # default_left (0/1)
-N_SCALARS = 11
+S_ISCAT = 11    # categorical split (0/1)
+S_CAT0 = 12     # the category set over the feature's bins: bin b is bit
+CAT_WORDS = 8   # (b & 31) of word S_CAT0 + (b >> 5), 256 bits for u8 bins
+N_SCALARS = S_CAT0 + CAT_WORDS
 
 def sc_rows_for(g32: int) -> int:
     """Packed-scratch sublanes for a (g32, N) bin matrix: the packed
@@ -355,10 +358,16 @@ def _cdiv(a, c):
     return jax.lax.div(a + (c - 1), c)
 
 
-def _decide_left(colv, bstart, isb, nb, dbin, mtype, thr, dl):
-    """Numerical split decision on raw group-column values, all-i32
-    (bool vectors with Python-literal branches trip an i8->i1
-    truncation Mosaic can't lower).  The ONE copy of this arithmetic
+def _decide_left(colv, bstart, isb, nb, dbin, mtype, thr, dl, cat=None):
+    """Split decision on raw group-column values, all-i32 (bool vectors
+    with Python-literal branches trip an i8->i1 truncation Mosaic can't
+    lower): ``bin <= thr`` with the missing-value handling for a
+    numerical split and, where ``cat = (is_cat, words)`` is given,
+    membership of the feature bin in the category set for a categorical
+    one: bit ``fb & 31`` of ``words[fb >> 5]`` (``CAT_WORDS`` scalars,
+    picked by a select chain; bin 0, the NaN/other bin, is never in a
+    set, so missing and unkept categories go right).  ``cat=None``
+    compiles the numerical arm alone.  The ONE copy of this arithmetic
     shared by the partition kernel, the split mega-kernel and its XLA
     oracle (ops/split_megakernel_pallas.py) — the mega path's
     bit-exactness contract rides on all of them agreeing; the XLA
@@ -371,13 +380,23 @@ def _decide_left(colv, bstart, isb, nb, dbin, mtype, thr, dl):
         mtype == 1, (fb == dbin).astype(jnp.int32),
         jnp.where(mtype == 2, (fb == nb - 1).astype(jnp.int32), 0))
     nat_i = (fb <= thr).astype(jnp.int32)
-    return jnp.where(miss_i != 0, dl, nat_i)
+    num_i = jnp.where(miss_i != 0, dl, nat_i)
+    if cat is None:
+        return num_i
+    is_cat, words = cat
+    at = jax.lax.shift_right_logical(fb, 5)
+    word = jnp.broadcast_to(words[0], fb.shape)
+    for k in range(1, len(words)):
+        word = jnp.where(at == k, words[k], word)
+    bit = jax.lax.shift_right_logical(word, fb & 31) & 1
+    return jnp.where(is_cat != 0, bit, num_i)
 
 
 def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                           row_chunk: int, ghi_live: int = 3,
                           pack_rowid: bool = False,
                           pass_rows: int | None = None,
+                          categorical: bool = False,
                           interpret: bool = False):
     """Two-way stable partition of the leaf range described by
     ``scalars`` (see the S_* layout above), in place.
@@ -410,6 +429,10 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
         pays where it saves a tile).  Kernel-internal only: the HBM
         layout of part_ghi is unchanged and the pad bin rows come back
         zeroed.
+      categorical: compile the decision's categorical arm (the split's
+        ``S_ISCAT`` flag and ``S_CAT0`` set words); without it the kernel
+        decides numerical splits alone and lowers as it did before the
+        arm existed.
     Returns (part_bins', part_ghi', sc_packed', nl) with the first three
     aliased in place; nl is an (8, 128) i32 tile whose [0, 0] element is
     the left count.
@@ -526,16 +549,20 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
                 rg[slot], jnp.int32)[0:ghi_live]
             payload = make_payload(packed, ghi_i)             # (P, C)
 
-            # --- decision (numerical splits; see ops/partition.py
-            # split_decision and models/learner.py _goes_left) ---
+            # --- decision (see ops/partition.py split_decision and
+            # models/learner.py _goes_left) ---
             col_words = packed if NP == 1 else pack_bins(rc[slot])
             word = jnp.sum(col_words * word_oh, axis=0,
                            keepdims=True)                     # (1, C)
             colv = jax.lax.shift_right_logical(
                 word, jnp.broadcast_to(col_sh, word.shape)) & 255
+            cat = ((s_ref[S_ISCAT],
+                    [s_ref[S_CAT0 + k] for k in range(CAT_WORDS)])
+                   if categorical else None)
             gl_i = _decide_left(colv, s_ref[S_BSTART], s_ref[S_ISB],
                                 s_ref[S_NB], s_ref[S_DBIN],
-                                s_ref[S_MTYPE], s_ref[S_THR], s_ref[S_DL])
+                                s_ref[S_MTYPE], s_ref[S_THR], s_ref[S_DL],
+                                cat)
 
             pos = ci * C + lane                 # cover-relative position
             before_i = (pos < rem).astype(jnp.int32)
@@ -748,10 +775,29 @@ def partition_leaf_pallas(part_bins, part_ghi, sc_packed, scalars, *,
     return out
 
 
-def make_scalars(start, cnt, col, bstart, isb, nb, dbin, mtype, thr, dl):
-    """Pack the kernel's scalar operand (all traced i32)."""
+def cat_words(cat_set):
+    """A (BF,) bool category set over a feature's bins (BF <= 256) as the
+    ``CAT_WORDS`` i32 words of the scalar layout: bin b is bit b & 31 of
+    word b >> 5."""
+    bits = jnp.pad(jnp.asarray(cat_set, jnp.bool_),
+                   (0, 32 * CAT_WORDS - cat_set.shape[0]))
+    shifted = (bits.reshape(CAT_WORDS, 32).astype(jnp.uint32)
+               << jnp.arange(32, dtype=jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(shifted, axis=1, dtype=jnp.uint32), jnp.int32)
+
+
+def make_scalars(start, cnt, col, bstart, isb, nb, dbin, mtype, thr, dl,
+                 is_cat=False, cat_set=None):
+    """Pack the kernel's scalar operand (all traced i32); ``cat_set`` is
+    the (BF,) bool category set of a categorical split."""
     start = jnp.asarray(start, jnp.int32)
     a0b = jax.lax.shift_right_logical(start, 7)
     rem = start - a0b * 128
-    vals = [a0b, rem, cnt, col, bstart, isb, nb, dbin, mtype, thr, dl]
-    return jnp.stack([jnp.asarray(v).astype(jnp.int32) for v in vals])
+    vals = [a0b, rem, cnt, col, bstart, isb, nb, dbin, mtype, thr, dl,
+            is_cat]
+    words = (jnp.zeros((CAT_WORDS,), jnp.int32) if cat_set is None
+             else cat_words(cat_set))
+    return jnp.concatenate(
+        [jnp.stack([jnp.asarray(v).astype(jnp.int32) for v in vals]),
+         words])

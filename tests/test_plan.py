@@ -121,9 +121,10 @@ CASES = {
     # gradients only
     "hist_cpu": (TOY_CPU, dict(partition="xla", hist="xla"),
                  ("hist", "partition=xla"), None),
-    "hist_categorical": (dict(has_categorical=True),
-                         dict(partition="xla", hist="xla"),
-                         ("hist", "partition=xla"), None),
+    # the partition kernel decides category sets: a categorical
+    # table keeps both row kernels; the searches stay numerical only
+    "hist_categorical": (dict(has_categorical=True), CELL_PLAN,
+                         ("fast_search", "categorical features"), None),
     "hist_data_parallel": (DATA4, dict(partition="xla", hist="xla"),
                            ("hist", "partition=xla"), None),
     "hist_quantized": (dict(quantized=True),
@@ -145,13 +146,31 @@ CASES = {
                            chunk_adaptive=False),
                       ("frontier_k", AUTO_K), None),
     "categorical": (dict(SMOKE, has_categorical=True),
-                    dict(partition="xla", fast_search=False, search="xla",
-                         mega="off", frontier_k=1),
-                    ("partition", "categorical features"), None),
+                    dict(partition="pallas", hist="pallas",
+                         fast_search=False, search="xla", mega="off",
+                         frontier_k=1),
+                    ("mega", "categorical features"), None),
     "categorical_k4": (dict(SMOKE, has_categorical=True, **K4),
-                       dict(partition="xla", fast_search=False,
-                            search="xla", mega="off", frontier_k=4),
-                       ("partition", "categorical features"), None),
+                       dict(partition="pallas", hist="pallas",
+                            fast_search=False, search="xla", mega="off",
+                            frontier_k=4),
+                       ("search", "categorical features"), None),
+    # benchmark/configs/expo-cat-l255-b255.json at rows44m: 6 categorical
+    # + 2 numerical columns, the HIGGS cells' plan
+    "expo_cell": (dict(rows=44_000_000, F=8, G=8, has_categorical=True),
+                  CELL_PLAN, ("search", "categorical features"), None),
+    "categorical_interpret": (dict(TOY_CPU, interpret=True,
+                                   has_categorical=True),
+                              dict(partition="pallas", hist="pallas",
+                                   fast_search=False, search="xla",
+                                   mega="off", hist_state="xla"),
+                              ("mega", "categorical features"), None),
+    "categorical_mega_xla": (dict(SMOKE, has_categorical=True,
+                                  tpu_megakernel="xla"),
+                             dict(partition="pallas", mega="off"),
+                             ("mega", "categorical features"),
+                             "tpu_megakernel=xla cannot be honoured "
+                             "(categorical features"),
     "u16_bins": (dict(SMOKE, host_bin_dtype="uint16", B=300),
                  dict(partition="xla", search="xla", mega="off"),
                  ("partition", "uint16 bins"), None),

@@ -835,6 +835,43 @@ def test_default_step_at_the_cells_facts_compiles_for_v5e_without_a_copy(
     assert calls == {"lgbm_partition", "lgbm_histogram"}
 
 
+def test_categorical_step_compiles_for_v5e_with_both_kernels(one_chip,
+                                                              monkeypatch):
+    """The Expo cell's step (benchmark/configs/expo-cat-l255-b255.json: 6
+    categorical + 2 numerical columns, 255 leaves and bins) at toy rows,
+    compiled for the described v5e: the plan at toy rows is the one at
+    44M (categorical features keep the fast search and the mega-kernel
+    off at any row count), ``lgbm_partition`` compiles with its
+    categorical arm beside ``lgbm_histogram``, and the categorical branch
+    of the search is its own phase."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.models import plan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = importlib.util.spec_from_file_location(
+        "generator_expo", os.path.join(ROOT, "benchmark", "generators",
+                                       "expo.py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    X, y, kw = gen.make(2**31 + 3, 6000, 0, {})
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                       "max_bin": 255, "verbosity": -1},
+                      lgb.Dataset(X, label=y, **kw))
+    lr = bst._gbdt.learner
+    options = {k: getattr(Config({}), k) for k in plan.OPTION_FIELDS}
+    at_size = plan.resolve(plan.PlanFacts(
+        backend="tpu", rows=44_000_000, F=8, G=8, B=255, num_leaves=255,
+        has_categorical=True, **options))
+    assert lr.has_categorical and lr.B == 255
+    assert lr.plan.kernel_plan() == at_size.kernel_plan() == {
+        "partition": "pallas", "hist": "pallas", "search": "xla",
+        "hist_state": "xla", "mega": "off", "frontier_k": 1}
+    with _compile_cache_off():
+        text = _lower_step(bst._gbdt, one_chip).compile().as_text()
+    _assert_kernel_phases(text, {"lgbm_partition": "partition",
+                                 "lgbm_histogram": "histogram"})
+    assert "search_cat" in set(scopes.phase_of(text).values())
+
+
 def test_frontier_step_compiles_for_v5e_without_a_payload_copy(
         one_chip, monkeypatch):
     """What the CPU cannot show: XLA:TPU's copy insertion.  The frontier

@@ -20,6 +20,7 @@ so each count is a process of its own:
     python tools/kernel_ops.py partition                 # the cells' geometry
     python tools/kernel_ops.py partition --chunk 2048 --pack-rowid
     python tools/kernel_ops.py partition --bundles
+    python tools/kernel_ops.py partition --bundles --categorical
     python tools/kernel_ops.py split_mega --json
 
 Body 0 of ``partition`` is pass 1 (a chunk: decide, the two-way
@@ -147,7 +148,8 @@ def _partition(args):
     def fn(pb, pg, sp, s):
         return partition_leaf_pallas(
             pb, pg, sp, s, row_chunk=args.chunk, ghi_live=args.ghi_live,
-            pack_rowid=args.pack_rowid, pass_rows=args.pass_rows)
+            pack_rowid=args.pack_rowid, pass_rows=args.pass_rows,
+            categorical=args.categorical)
     return "lgbm_partition", fn, _operands(args)
 
 
@@ -246,6 +248,8 @@ def main(argv=None):
     ap.add_argument("--chunk", type=int, default=4096, help="row_chunk")
     ap.add_argument("--ghi-live", type=int, default=3)
     ap.add_argument("--pack-rowid", action="store_true")
+    ap.add_argument("--categorical", action="store_true",
+                    help="partition: with the decision's category-set arm")
     ap.add_argument("--bins", type=int, default=255,
                     help="split_mega, histogram: histogram bins")
     ap.add_argument("--bundles", action="store_true",
